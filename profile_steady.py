@@ -1,14 +1,15 @@
-"""Profile the steady-state cycle (10k running pods, 100-pod waves) on CPU.
+"""Profile the steady-state cycle (10k running pods, 100-pod waves).
 
-Scratch tool for the round-4 host-path work; not part of the suite.
-Run: JAX_PLATFORMS=cpu python profile_steady.py [--cprofile]
+Scratch tool for the round-4 host-path work; not part of the suite. It
+runs on whatever platform JAX finds where it is started (the chip, or the
+CPU with JAX_PLATFORMS=cpu).
+Run: python profile_steady.py [--cprofile]
 """
 
 import os
 import sys
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.join(os.path.dirname(
     os.path.abspath(__file__)), "tests"))
 

@@ -365,17 +365,31 @@ class TestBenchFaultIsolation:
         bench = _import_bench()
         calls = {"n": 0}
 
-        JaxRuntimeError = type("JaxRuntimeError", (RuntimeError,), {})
-
         def flaky():
             calls["n"] += 1
-            raise JaxRuntimeError(
-                "INTERNAL: remote_compile: read body: closed")
+            raise ConnectionError("store wire: connection reset by peer")
 
         rec = bench._run_config("x", flaky)
         assert calls["n"] == 2          # one transient retry
         assert rec["attempts"] == 2
-        assert "remote_compile" in rec["error"]
+        assert "connection reset" in rec["error"]
+
+    def test_run_config_does_not_retry_device_runtime_error(self):
+        """A runtime error of the local chip (OOM, Mosaic failure) is
+        a real fault: recorded at once, never re-sent."""
+        bench = _import_bench()
+        calls = {"n": 0}
+
+        JaxRuntimeError = type("JaxRuntimeError", (RuntimeError,), {})
+
+        def oom():
+            calls["n"] += 1
+            raise JaxRuntimeError("RESOURCE_EXHAUSTED: out of memory")
+
+        rec = bench._run_config("x", oom)
+        assert calls["n"] == 1
+        assert rec["attempts"] == 1
+        assert "RESOURCE_EXHAUSTED" in rec["error"]
 
     def test_run_config_recovers_on_transient_retry(self):
         bench = _import_bench()
@@ -433,6 +447,23 @@ class TestTransientRetry:
 
         with pytest.raises(ValueError):
             retry_transient(fatal, delay_s=0.0)
+
+    def test_device_runtime_error_is_not_retried(self):
+        from volcano_tpu.resilience.transient import (
+            is_transient, retry_transient,
+        )
+
+        JaxRuntimeError = type("JaxRuntimeError", (RuntimeError,), {})
+        calls = {"n": 0}
+
+        def mosaic_failure():
+            calls["n"] += 1
+            raise JaxRuntimeError("INTERNAL: Mosaic failed to compile")
+
+        assert not is_transient(JaxRuntimeError("INTERNAL: Mosaic"))
+        with pytest.raises(JaxRuntimeError):
+            retry_transient(mosaic_failure, delay_s=0.0)
+        assert calls["n"] == 1
 
     def test_final_transient_failure_propagates(self):
         from volcano_tpu.resilience.transient import retry_transient

@@ -313,21 +313,44 @@ class TestPersistentCacheConfig:
             pc._configured_dir = prev_cfg
             jax.config.update("jax_compilation_cache_dir", prev_dir)
 
-    def test_env_fallback(self, monkeypatch, tmp_path):
-        prev_cfg = pc._configured_dir
+    def test_env_dir_is_left_to_jax(self, monkeypatch, tmp_path):
+        """$JAX_COMPILATION_CACHE_DIR set: the directory in effect is
+        the variable's, and no directory is set in code — not even the
+        entry points' fixed default."""
         import jax
 
+        prev_cfg = pc._configured_dir
         prev_dir = jax.config.jax_compilation_cache_dir
         try:
             pc._configured_dir = None
             monkeypatch.setenv(pc.CACHE_DIR_ENV, str(tmp_path / "envcache"))
-            assert pc.configure_compilation_cache() \
-                == str(tmp_path / "envcache")
+            got = pc.configure_compilation_cache(
+                default_dir=str(tmp_path / "fixed"))
+            assert got == str(tmp_path / "envcache")
+            assert jax.config.jax_compilation_cache_dir == prev_dir
+            assert not (tmp_path / "fixed").exists()
+        finally:
+            pc._configured_dir = prev_cfg
+            jax.config.update("jax_compilation_cache_dir", prev_dir)
+
+    def test_explicit_dir_wins_over_env(self, monkeypatch, tmp_path):
+        import jax
+
+        prev_cfg = pc._configured_dir
+        prev_dir = jax.config.jax_compilation_cache_dir
+        try:
+            pc._configured_dir = None
+            monkeypatch.setenv(pc.CACHE_DIR_ENV, str(tmp_path / "envcache"))
+            flag = str(tmp_path / "flag")
+            assert pc.configure_compilation_cache(flag) == flag
+            assert jax.config.jax_compilation_cache_dir == flag
         finally:
             pc._configured_dir = prev_cfg
             jax.config.update("jax_compilation_cache_dir", prev_dir)
 
     def test_disabled_without_dir(self, monkeypatch):
+        """The library (no flag, no variable) keeps the cache off, so
+        tests never write into the checkout."""
         prev_cfg = pc._configured_dir
         try:
             pc._configured_dir = None
@@ -335,3 +358,28 @@ class TestPersistentCacheConfig:
             assert pc.configure_compilation_cache() is None
         finally:
             pc._configured_dir = prev_cfg
+
+    def test_entry_point_default_is_fixed_in_checkout(self, monkeypatch,
+                                                      tmp_path):
+        """No flag, no variable: an entry point passes the fixed
+        in-checkout path, which .gitignore lists."""
+        import os
+
+        import jax
+
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert pc.ENTRY_POINT_CACHE_DIR == os.path.join(root, ".jax_cache")
+        with open(os.path.join(root, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+        prev_cfg = pc._configured_dir
+        prev_dir = jax.config.jax_compilation_cache_dir
+        try:
+            pc._configured_dir = None
+            monkeypatch.delenv(pc.CACHE_DIR_ENV, raising=False)
+            fixed = str(tmp_path / "fixed")
+            assert pc.configure_compilation_cache(default_dir=fixed) \
+                == fixed
+            assert jax.config.jax_compilation_cache_dir == fixed
+        finally:
+            pc._configured_dir = prev_cfg
+            jax.config.update("jax_compilation_cache_dir", prev_dir)

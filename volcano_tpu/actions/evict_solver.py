@@ -10,6 +10,7 @@ deviations listed in ops/evict.py.
 from __future__ import annotations
 
 import logging
+import time
 from typing import Dict, List, Tuple
 
 import numpy as np
@@ -229,6 +230,8 @@ def run_evict_solver(ssn, mode: str, skip_jobs=()):
          varrays["job_count"]) = uniform
     vnp = {k: np.asarray(v) for k, v in varrays.items()}
     sidecar = getattr(ssn, "sidecar", None)
+    timing = ssn.solver_options.setdefault("timing", {})
+    t0 = time.perf_counter()
     try:
         # breaker scope: a throwing evict dispatch/collect (or an injected
         # fault) counts one consecutive device failure; the caller's host
@@ -258,7 +261,7 @@ def run_evict_solver(ssn, mode: str, skip_jobs=()):
                     allow_revert=preempt, stop_at_need=preempt)
             from ..ops.evict import decode_evict_compact
             try:
-                # one int16 readback carries both outputs (remote wire)
+                # one int16 readback carries both outputs
                 assigned, evicted_by = decode_evict_compact(
                     res.compact, arr.task_init_req.shape[0])
             except ValueError:  # >32k nodes/jobs: indices overflow packing
@@ -269,9 +272,12 @@ def run_evict_solver(ssn, mode: str, skip_jobs=()):
                       "loop for this cycle", mode)
         if breaker is not None:
             breaker.record_failure()
+        timing["host_fallback"] = 1.0
         return None
     if breaker is not None:
         breaker.record_success()
+    # dispatch + readback of the evict solve, present only when it ran
+    timing[f"{mode}_solve_ms"] = (time.perf_counter() - t0) * 1e3
     by_job = _evictions_by_job(evicted_by)
 
     from ..metrics import metrics

@@ -43,59 +43,58 @@ import numpy as np
 
 log = logging.getLogger(__name__)
 
-#: env override consumed when no explicit dir is configured
-CACHE_DIR_ENV = "VOLCANO_COMPILE_CACHE_DIR"
+#: the variable JAX itself reads its cache directory from; where it is
+#: set, this module sets no directory in code
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+#: where the entry points (``python -m volcano_tpu.standalone``,
+#: ``chip_smoke.py``, ``bench.py``) keep the cache when neither a flag
+#: nor the variable names one: fixed and inside the checkout, because the
+#: directory is part of the cache key's lookup path (a moving directory
+#: never hits). Listed in .gitignore.
+ENTRY_POINT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _configured_dir: Optional[str] = None
 
 
 def configure_compilation_cache(cache_dir: Optional[str] = None,
-                                min_compile_secs: float = 0.0) -> Optional[str]:
-    """Enable JAX's persistent on-disk compilation cache.
+                                default_dir: Optional[str] = None,
+                                min_compile_secs: float = 0.0
+                                ) -> Optional[str]:
+    """Turn on JAX's persistent on-disk compilation cache.
 
-    ``cache_dir`` falls back to $VOLCANO_COMPILE_CACHE_DIR; returns the
-    directory in effect (None = left disabled). Idempotent — repeated
-    calls with the same dir are no-ops; a different dir re-points the
-    cache. Failures (ancient jax, read-only fs) log and disable rather
-    than take down the scheduler: the cache is an optimization, not a
-    correctness dependency.
+    Precedence: an explicit ``cache_dir`` (``--compile-cache-dir``) wins;
+    else, where $JAX_COMPILATION_CACHE_DIR is set, JAX already reads it
+    and no directory is set here; else ``default_dir`` (the entry points
+    pass ``ENTRY_POINT_CACHE_DIR``; the library passes nothing and the
+    cache stays off). Returns the directory in effect (None = off).
+    Idempotent — repeated calls with the same dir are no-ops; a
+    different dir re-points the cache.
     """
     global _configured_dir
-    cache_dir = cache_dir or os.environ.get(CACHE_DIR_ENV) or None
-    if not cache_dir:
-        return _configured_dir
-    if _configured_dir == cache_dir:
-        return _configured_dir
-    try:
-        import jax
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
 
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        # default thresholds skip exactly the small recompiles a restart
-        # re-pays; the solver variants this repo cares about all clear
-        # them, but pinning to 0/-1 makes the cache deterministic in tests
-        for knob, val in (
-                ("jax_persistent_cache_min_compile_time_secs",
-                 min_compile_secs),
-                ("jax_persistent_cache_min_entry_size_bytes", -1)):
-            try:
-                jax.config.update(knob, val)
-            except Exception:  # noqa: BLE001 — knob absent on this jax
-                pass
-        try:
-            # the cache backend latches on first use: a process that
-            # compiled anything before this call (warmup, another
-            # scheduler) must drop the initialized-with-no-dir instance
-            # or the new dir silently never receives entries
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except Exception:  # noqa: BLE001 — private API drifted
-            pass
-        _configured_dir = cache_dir
-    except Exception:  # noqa: BLE001
-        log.exception("persistent compilation cache unavailable")
-        return None
+    env_dir = os.environ.get(CACHE_DIR_ENV) or None
+    target = cache_dir or env_dir or default_dir
+    if not target or _configured_dir == target:
+        return _configured_dir
+    if cache_dir or not env_dir:
+        os.makedirs(target, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", target)
+    # the default thresholds skip exactly the small recompiles a restart
+    # re-pays; pinning them to 0/-1 caches every solver variant
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      min_compile_secs)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    # the cache backend latches on first use: a process that compiled
+    # anything before this call (warmup, another scheduler) must drop the
+    # initialized-with-no-dir instance or the new dir never receives
+    # entries
+    cc.reset_cache()
+    _configured_dir = target
     return _configured_dir
 
 

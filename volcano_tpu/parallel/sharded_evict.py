@@ -75,8 +75,6 @@ def _solve_sharded(arrays, victims, score_params, mesh,
     N = a["node_idle"].shape[0]
     J = a["job_min"].shape[0]
     D = mesh.devices.size
-    thr = a["thresholds"]
-    sm = a["scalar_dim_mask"]
 
     in_specs_a = {
         "task_init_req": P(), "task_req": P(), "task_job": P(),
@@ -101,6 +99,8 @@ def _solve_sharded(arrays, victims, score_params, mesh,
     D1 = D == 1
 
     def kernel(a, v, sp):
+        thr = a["thresholds"]
+        sm = a["scalar_dim_mask"]
         n_loc = a["node_idle"].shape[0]
         my_base = jnp.int32(0) if D1 \
             else jax.lax.axis_index("n") * n_loc

@@ -24,12 +24,6 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from jax import shard_map as _shard_map
-    _REP_KWARG = "check_vma"
-except ImportError:  # older jax: experimental API, check_rep spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _REP_KWARG = "check_rep"
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.solver import (
@@ -38,10 +32,12 @@ from ..ops.solver import (
 )
 
 
-def shard_map(*args, **kwargs):
-    """shard_map with replication checking off, spelled for either jax API."""
-    kwargs[_REP_KWARG] = False
-    return _shard_map(*args, **kwargs)
+def shard_map(f, **kwargs):
+    """``jax.shard_map`` with replication checking off. The body must read
+    every array from its own arguments: under jit, JAX refuses to capture
+    an auto-sharded tracer from the enclosing scope into the manual mesh
+    context."""
+    return jax.shard_map(f, check_vma=False, **kwargs)
 
 
 def make_mesh(devices=None, axis: str = "n") -> Mesh:
@@ -97,17 +93,13 @@ def solve_allocate_sharded(arrays: Dict[str, jnp.ndarray],
     J = a["job_min"].shape[0]
     D = mesh.devices.size
     assert N % D == 0, f"node axis {N} must divide device count {D}"
-    thr = a["thresholds"]
-    scalar_mask = a["scalar_dim_mask"]
-    counts_ready = a["task_counts_ready"].astype(jnp.int32)
-    rank = a["task_rank"]
     # fused pallas choice kernel PER SHARD (ops/pallas_kernels.py): each
     # device's [T, N/D] feasibility/score/argmax pass runs in one VMEM
     # kernel; only the [T]/[N/D] reductions cross the ICI. Same gate as
     # the single-device solver, applied to the SHARD's node width.
-    from ..ops.pallas_kernels import fused_choice_auto
+    from ..ops.pallas_kernels import fused_choice_auto, use_interpret
     use_fused = fused == "on" or (
-        fused == "auto" and jax.default_backend() == "tpu"
+        fused == "auto" and not use_interpret()
         and fused_choice_auto(T, N // D)
         and herd_mode in ("pack", "spread"))
 
@@ -153,6 +145,10 @@ def solve_allocate_sharded(arrays: Dict[str, jnp.ndarray],
     D1 = D == 1
 
     def kernel(a, sp):
+        thr = a["thresholds"]
+        scalar_mask = a["scalar_dim_mask"]
+        counts_ready = a["task_counts_ready"].astype(jnp.int32)
+        rank = a["task_rank"]
         n_loc = a["node_idle"].shape[0]
         my_base = jnp.int32(0) if D1 \
             else jax.lax.axis_index("n") * n_loc

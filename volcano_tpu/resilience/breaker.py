@@ -1,7 +1,7 @@
 """Device-path circuit breaker: closed -> open -> half-open -> closed.
 
-The device solver is one shared dependency (the chip, its runtime, the
-tunnel to it) sitting under every allocate/preempt/reclaim dispatch. When
+The device solver is one shared dependency (the chip and its runtime)
+sitting under every allocate/preempt/reclaim dispatch. When
 that dependency is sick, each cycle paying a dispatch-and-fail (XLA
 runtime error, OOM, garbage readback) before falling back to the host
 oracle turns a degraded chip into a degraded *scheduler*. The breaker
@@ -55,6 +55,9 @@ class CircuitBreaker:
         self._state = CLOSED
         self._consecutive_failures = 0
         self._opened_at = 0.0
+        #: every failure ever recorded (the consecutive count resets on
+        #: success; this one does not)
+        self.failures_total = 0
         #: (timestamp, from_state, to_state), bounded
         self.transitions: List[Tuple[float, str, str]] = []
         #: cycles served by the fallback path while not closed
@@ -108,6 +111,7 @@ class CircuitBreaker:
 
     def record_failure(self) -> None:
         with self._lock:
+            self.failures_total += 1
             if self._state == HALF_OPEN:
                 # failed probe: straight back to a fresh cool-down
                 self._opened_at = self.clock()

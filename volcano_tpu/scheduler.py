@@ -102,7 +102,9 @@ class Scheduler:
             self.load_conf()  # re-apply: the first load ran pre-flag
         # compile-and-dispatch pipeline (ops.precompile): persistent
         # on-disk XLA executable cache (explicit dir or
-        # $VOLCANO_COMPILE_CACHE_DIR), background next-bucket pre-warm,
+        # $JAX_COMPILATION_CACHE_DIR; off otherwise — the entry points
+        # configure their fixed default before building a Scheduler),
+        # background next-bucket pre-warm,
         # and the allocate action's dispatch/collect overlap. All three
         # are pure-latency features — scheduling decisions are identical
         # with them on or off (tests/test_precompile.py parity).

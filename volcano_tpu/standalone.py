@@ -609,8 +609,9 @@ def main(argv=None) -> int:
                          "holder runs control-plane turns")
     ap.add_argument("--compile-cache-dir", metavar="DIR",
                     help="persistent XLA compilation cache directory "
-                         "(default $VOLCANO_COMPILE_CACHE_DIR): restarts "
-                         "and repeated bucket shapes skip recompiles")
+                         "(default $JAX_COMPILATION_CACHE_DIR, else "
+                         "<checkout>/.jax_cache): restarts and repeated "
+                         "bucket shapes skip recompiles")
     ap.add_argument("--prewarm", action="store_true",
                     help="compile the next compile-bucket's solver "
                          "variants on a background thread when occupancy "
@@ -701,6 +702,9 @@ def main(argv=None) -> int:
     if args.conf:
         with open(args.conf) as f:
             conf = f.read()
+    from .ops import precompile
+    precompile.configure_compilation_cache(
+        args.compile_cache_dir, default_dir=precompile.ENTRY_POINT_CACHE_DIR)
     sa = Standalone(scheduler_conf=conf, period=args.period,
                     serve_webhooks_tls=args.serve_webhooks,
                     sidecar_path=args.sidecar,
