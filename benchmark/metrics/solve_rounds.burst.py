@@ -1,0 +1,9 @@
+"""Allocate solve rounds (per 1,000 traffic pods bound in the window):
+the rounds the device solve ran (``solve_rounds``, read back with the
+compact result)."""
+
+from lib.program import count_per_kpod
+
+
+def read(run):
+    return count_per_kpod(run, "solve_rounds")

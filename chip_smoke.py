@@ -340,6 +340,7 @@ def phase_exactness(n_nodes: int = 50, n_jobs: int = 100, tpj: int = 5,
     from volcano_tpu.client import ClusterStore
     from volcano_tpu.conf import Configuration, PluginOption, Tier
     from volcano_tpu.framework import close_session, get_action, open_session
+    from volcano_tpu.metrics import spans
     from volcano_tpu.models import (
         Node, Pod, PodGroup, PodGroupPhase, PodGroupSpec, PodGroupStatus,
     )
@@ -380,8 +381,10 @@ def phase_exactness(n_nodes: int = 50, n_jobs: int = 100, tpj: int = 5,
                         "memory": f"{job_mem[j]}Gi"}}]))
         ssn = open_session(cache, tiers,
                            [Configuration("allocate", {"mode": mode})])
-        get_action("allocate").execute(ssn)
-        timing = dict(ssn.solver_options.get("timing") or {})
+        with spans.span("volcano.action.allocate", "allocate_ms",
+                        root=True) as act:
+            get_action("allocate").execute(ssn)
+        timing = {**act.record, **(ssn.solver_options.get("timing") or {})}
         close_session(ssn)
         return dict(cache.binder.binds), timing
 

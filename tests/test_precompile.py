@@ -124,6 +124,30 @@ class TestCompileWatcher:
         assert (c, s) == (1, 1.0)
         assert w.counts()[0] == 1
 
+    def test_trace_lower_and_cache_load_by_phase(self):
+        w = pc.CompileWatcher()
+        for key, secs in (("/jax/core/compile/jaxpr_trace_duration", 0.5),
+                          ("/jax/core/compile/jaxpr_to_mlir_module_duration",
+                           0.25),
+                          ("/jax/compilation_cache/cache_retrieval_time_sec",
+                           0.125),
+                          ("/jax/core/compile/jaxpr_trace_duration", 0.5),
+                          ("/jax/compilation_cache/compile_time_saved_sec",
+                           9.0)):
+            w._on_duration(key, secs, fun_name="f")
+
+        def bg():
+            w.register_background()
+            w._on_duration("/jax/core/compile/jaxpr_trace_duration", 4.0)
+
+        t = threading.Thread(target=bg)
+        t.start()
+        t.join(timeout=30)
+        assert not t.is_alive()
+        assert w.session_phase_totals() == {"trace": 1.0, "lower": 0.25,
+                                            "cache_load": 0.125}
+        assert w.session_totals() == (0, 0.0)
+
     def test_cache_hit_events_counted(self):
         w = pc.CompileWatcher()
         w._on_event("/jax/compilation_cache/cache_hits")

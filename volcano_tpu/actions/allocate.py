@@ -25,6 +25,7 @@ from ..api.unschedule_info import (
     ALL_NODES_UNAVAILABLE, FitError, FitErrors, NODE_RESOURCE_FIT_FAILED,
 )
 from ..framework import Action
+from ..metrics.spans import count, span
 from ..models import PodGroupPhase
 from ..utils import PriorityQueue
 
@@ -195,8 +196,6 @@ class AllocateAction(Action):
 
     def _execute_solver(self, ssn, sequential: bool = False,
                         sharded: bool = False) -> None:
-        import time as _time
-
         from ..ops import flatten_snapshot, solve_allocate, \
             solve_allocate_sequential
 
@@ -204,455 +203,452 @@ class AllocateAction(Action):
 
         timing = ssn.solver_options.setdefault("timing", {})
         breaker = getattr(ssn, "breaker", None)
-        t0 = _time.perf_counter()
-        host_only = ssn.solver_options.get("host_only_jobs") or ()
-        job_order = []
-        tasks_in_order = []
-        # the ordering pass: event-sourced when the OrderCache can serve
-        # this conf (O(changes since last cycle)), the live comparator
-        # walk otherwise — surfaced per cycle as order_{mode,ms,
-        # entries_patched,fallback_reason}
-        collected = self._collect(ssn)
-        order_ms = (_time.perf_counter() - t0) * 1e3
-        timing["order_ms"] = order_ms
-        oc = getattr(ssn, "order_cache", None)
-        if oc is not None:
-            timing["order_mode"] = oc.last_mode
-            timing["order_entries_patched"] = \
-                float(oc.last_entries_patched)
-            if oc.last_reason:
-                timing["order_fallback_reason"] = oc.last_reason
-        # host-only jobs (GPU sharing, required pod affinity, PVCs) that
-        # OUTRANK every device-path job run through the host loop BEFORE
-        # the solve, so per-job routing cannot invert priority (a
-        # top-priority GPU gang must not find its CPU eaten by
-        # lower-priority solver placements). Host-only jobs ranked mid
-        # -sequence still run after — an accepted coarsening of the
-        # reference's fully sequential order, noted in the contract.
-        pre_host, post_host = [], []
-        for job, tasks in collected:
-            if job.uid in host_only:
-                (post_host if job_order else pre_host).append(job.uid)
-                continue
-            if tasks:
-                job_order.append((job, tasks))
-                tasks_in_order.extend(tasks)
-        ssn.solver_options["_post_host_jobs"] = post_host
-        if pre_host:
-            self._execute_host(ssn, only_jobs=set(pre_host))
-        if not tasks_in_order:
-            return
+        with span("volcano.allocate.flatten", "flatten_ms") as flat:
+            host_only = ssn.solver_options.get("host_only_jobs") or ()
+            job_order = []
+            tasks_in_order = []
+            # the ordering pass: event-sourced when the OrderCache can serve
+            # this conf (O(changes since last cycle)), the live comparator
+            # walk otherwise — surfaced per cycle as order_{mode,ms,
+            # entries_patched,fallback_reason}
+            with span("volcano.allocate.order", "order_ms"):
+                collected = self._collect(ssn)
+            oc = getattr(ssn, "order_cache", None)
+            if oc is not None:
+                timing["order_mode"] = oc.last_mode
+                timing["order_entries_patched"] = \
+                    float(oc.last_entries_patched)
+                if oc.last_reason:
+                    timing["order_fallback_reason"] = oc.last_reason
+            # host-only jobs (GPU sharing, required pod affinity, PVCs) that
+            # OUTRANK every device-path job run through the host loop BEFORE
+            # the solve, so per-job routing cannot invert priority (a
+            # top-priority GPU gang must not find its CPU eaten by
+            # lower-priority solver placements). Host-only jobs ranked mid
+            # -sequence still run after — an accepted coarsening of the
+            # reference's fully sequential order, noted in the contract.
+            pre_host, post_host = [], []
+            for job, tasks in collected:
+                if job.uid in host_only:
+                    (post_host if job_order else pre_host).append(job.uid)
+                    continue
+                if tasks:
+                    job_order.append((job, tasks))
+                    tasks_in_order.extend(tasks)
+            ssn.solver_options["_post_host_jobs"] = post_host
+            if pre_host:
+                self._execute_host(ssn, only_jobs=set(pre_host))
+            if not tasks_in_order:
+                flat.discard()  # nothing to place: no flatten this cycle
+                return
 
-        fc = getattr(ssn, "flatten_cache", None)
-        if fc is not None and getattr(fc, "events_enabled", False) \
-                and getattr(ssn, "_mutation_ops", 0):
-            # an earlier action in this cycle already mutated the session's
-            # clones; those deltas never reached the event ledger, so the
-            # event-sourced fast path must re-diff this cycle
-            fc.suppress_event_path("session_mutations")
-        t_fs = _time.perf_counter()
-        arr = flatten_snapshot(
-            {j.uid: j for j, _ in job_order}, ssn.nodes, tasks_in_order,
-            queues=ssn.queues, cache=fc, grouped=job_order)
-        fs_ms = (_time.perf_counter() - t_fs) * 1e3
-        if fc is not None:
-            # the event -> incremental -> cold ladder made observable:
-            # which assembly path this cycle's flatten took, how many rows
-            # it patched, and the patch-vs-full-pass latency split
-            timing["flatten_mode"] = fc.last_flatten_mode
-            timing["flatten_rows_patched"] = float(fc.last_rows_patched)
-            timing["flatten_events_applied"] = \
-                float(fc.last_events_applied)
-            if fc.last_flatten_mode == "event":
-                timing["flatten_patch_ms"] = fs_ms
-            else:
-                timing["flatten_full_ms"] = fs_ms
-            if fc.last_fallback_reason:
-                timing["flatten_fallback_reason"] = fc.last_fallback_reason
+            fc = getattr(ssn, "flatten_cache", None)
+            if fc is not None and getattr(fc, "events_enabled", False) \
+                    and getattr(ssn, "_mutation_ops", 0):
+                # an earlier action in this cycle already mutated the session's
+                # clones; those deltas never reached the event ledger, so the
+                # event-sourced fast path must re-diff this cycle
+                fc.suppress_event_path("session_mutations")
+            with span("volcano.allocate.flatten.snapshot") as snap:
+                arr = flatten_snapshot(
+                    {j.uid: j for j, _ in job_order}, ssn.nodes,
+                    tasks_in_order, queues=ssn.queues, cache=fc,
+                    grouped=job_order)
+            fs_ms = snap.ms
+            if fc is not None:
+                # the event -> incremental -> cold ladder made observable:
+                # which assembly path this cycle's flatten took, how many rows
+                # it patched, and the patch-vs-full-pass latency split
+                timing["flatten_mode"] = fc.last_flatten_mode
+                timing["flatten_rows_patched"] = float(fc.last_rows_patched)
+                timing["flatten_events_applied"] = \
+                    float(fc.last_events_applied)
+                if fc.last_flatten_mode == "event":
+                    timing["flatten_patch_ms"] = fs_ms
+                else:
+                    timing["flatten_full_ms"] = fs_ms
+                if fc.last_fallback_reason:
+                    timing["flatten_fallback_reason"] = fc.last_fallback_reason
 
-        # queue fairness: when proportion is active its session-open attrs
-        # (allocated/request over ALL jobs, incl. running-only queues) feed
-        # the in-kernel water-fill + per-round deserved caps
-        queue_opts = ssn.solver_options.get("queue_opts")
-        use_queue_cap = bool(queue_opts)
-        work_conserving = bool(
-            ssn.solver_options.get("work_conserving", True))
-        if use_queue_cap:
-            self._fill_queue_arrays(arr, queue_opts, ssn)
+            # queue fairness: when proportion is active its session-open attrs
+            # (allocated/request over ALL jobs, incl. running-only queues) feed
+            # the in-kernel water-fill + per-round deserved caps
+            queue_opts = ssn.solver_options.get("queue_opts")
+            use_queue_cap = bool(queue_opts)
+            work_conserving = bool(
+                ssn.solver_options.get("work_conserving", True))
+            if use_queue_cap:
+                self._fill_queue_arrays(arr, queue_opts, ssn)
 
-        # live DRF ordering on device (drf plugin active): the kernel
-        # re-ranks jobs by dominant share every round. Job-order providers
-        # dispatched BEFORE drf in the tiers (priority, gang) compose as a
-        # static MAJOR rank (arr.job_drf_prerank) that live shares only
-        # tie-break — the reference's comparator chain returns on the
-        # first non-zero, so strict priorities dominate and equal
-        # priorities fall through to drf, which the kernel now mirrors
-        # instead of disabling the re-rank outright (a disabled re-rank
-        # froze the snapshot order and could starve later-created jobs
-        # under the default priority-before-drf conf). Falls back to the
-        # static order only when a preceding provider registered no sort
-        # key.
-        drf_opts = ssn.solver_options.get("drf_order")
-        use_drf_order = bool(drf_opts) and not sequential
-        if use_drf_order:
-            providers = [name for _, name, _
-                         in ssn._tier_fns("job_order_fns")]
-            if "drf" not in providers:
-                use_drf_order = False
-            else:
-                pre = providers[:providers.index("drf")]
-                keyfns = [ssn.order_key_fns.get(
-                    "job_order_fns", {}).get(p) for p in pre]
-                if any(kf is None for kf in keyfns):
+            # live DRF ordering on device (drf plugin active): the kernel
+            # re-ranks jobs by dominant share every round. Job-order providers
+            # dispatched BEFORE drf in the tiers (priority, gang) compose as a
+            # static MAJOR rank (arr.job_drf_prerank) that live shares only
+            # tie-break — the reference's comparator chain returns on the
+            # first non-zero, so strict priorities dominate and equal
+            # priorities fall through to drf, which the kernel now mirrors
+            # instead of disabling the re-rank outright (a disabled re-rank
+            # froze the snapshot order and could starve later-created jobs
+            # under the default priority-before-drf conf). Falls back to the
+            # static order only when a preceding provider registered no sort
+            # key.
+            drf_opts = ssn.solver_options.get("drf_order")
+            use_drf_order = bool(drf_opts) and not sequential
+            if use_drf_order:
+                providers = [name for _, name, _
+                             in ssn._tier_fns("job_order_fns")]
+                if "drf" not in providers:
                     use_drf_order = False
-                elif keyfns:
-                    keys = [tuple(kf(job) for kf in keyfns)
-                            for job in arr.jobs_list]
-                    order = sorted(range(len(keys)), key=keys.__getitem__)
-                    # dense rank; EQUAL key tuples share a rank so shares
-                    # can tie-break across them
-                    prev = None
-                    rank_val = -1
-                    for j in order:
-                        if keys[j] != prev:
-                            rank_val += 1
-                            prev = keys[j]
-                        arr.job_drf_prerank[j] = rank_val
-        use_hdrf_order = False
-        if use_drf_order:
-            attrs = drf_opts["job_attrs"]
-            for j, job in enumerate(arr.jobs_list):
-                attr = attrs.get(job.uid)
-                if attr is not None:
-                    arr.job_drf_allocated[j] = \
-                        attr.allocated.to_vector(arr.vocab)
-            arr.drf_total = drf_opts["total"].to_vector(arr.vocab)
-            if drf_opts.get("hierarchy"):
-                from ..ops.hdrf import build_hdrf
-                build_hdrf(arr, ssn.queues, attrs,
-                           drf_opts["total_allocated"])
-                use_hdrf_order = True
+                else:
+                    pre = providers[:providers.index("drf")]
+                    keyfns = [ssn.order_key_fns.get(
+                        "job_order_fns", {}).get(p) for p in pre]
+                    if any(kf is None for kf in keyfns):
+                        use_drf_order = False
+                    elif keyfns:
+                        keys = [tuple(kf(job) for kf in keyfns)
+                                for job in arr.jobs_list]
+                        order = sorted(range(len(keys)), key=keys.__getitem__)
+                        # dense rank; EQUAL key tuples share a rank so shares
+                        # can tie-break across them
+                        prev = None
+                        rank_val = -1
+                        for j in order:
+                            if keys[j] != prev:
+                                rank_val += 1
+                                prev = keys[j]
+                            arr.job_drf_prerank[j] = rank_val
+            use_hdrf_order = False
+            if use_drf_order:
+                attrs = drf_opts["job_attrs"]
+                for j, job in enumerate(arr.jobs_list):
+                    attr = attrs.get(job.uid)
+                    if attr is not None:
+                        arr.job_drf_allocated[j] = \
+                            attr.allocated.to_vector(arr.vocab)
+                arr.drf_total = drf_opts["total"].to_vector(arr.vocab)
+                if drf_opts.get("hierarchy"):
+                    from ..ops.hdrf import build_hdrf
+                    build_hdrf(arr, ssn.queues, attrs,
+                               drf_opts["total_allocated"])
+                    use_hdrf_order = True
 
-        timing["flatten_ms"] = (_time.perf_counter() - t0) * 1e3
-        t0 = _time.perf_counter()
-        params, families = build_score_inputs(ssn, arr)
-        herd = ssn.solver_options.get("herd_mode")
-        if herd is None:
-            herd = "pack" if params["binpack_weight"] > (
-                params["least_req_weight"]
-                + params["balanced_weight"]) else "spread"
+        with span("volcano.allocate.solve", "solve_ms") as solve:
+            params, families = build_score_inputs(ssn, arr)
+            herd = ssn.solver_options.get("herd_mode")
+            if herd is None:
+                herd = "pack" if params["binpack_weight"] > (
+                    params["least_req_weight"]
+                    + params["balanced_weight"]) else "spread"
 
-        dc = getattr(ssn, "device_cache", None)
-        sidecar = getattr(ssn, "sidecar", None)
-        # which arena a device fault must invalidate: the packed cache by
-        # default, the sharded arena when this session dispatched there
-        fault_dc = dc
-        try:
-            # device-path circuit-breaker scope: anything that throws out
-            # of the dispatch (XLA runtime error, OOM, dead sidecar, an
-            # injected fault) counts one consecutive device failure and
-            # this session finishes through the host oracle
-            faults.fire("solver_dispatch")
-            if sequential:
-                res = solve_allocate_sequential(
-                    arr.device_dict(), params, score_families=families,
-                    use_queue_cap=use_queue_cap,
-                    work_conserving=work_conserving)
-            elif sharded:
-                # mode: sharded — the node-axis shard_map solver over the
-                # SHARDED device-resident arena (ShardedDeviceCache):
-                # node-axis chunks live per mesh device, task/job chunks
-                # are replicated once per device, and a steady session
-                # ships dirty chunks only to the shard(s) owning them
-                # (a zero-dirty session dispatches straight off the
-                # resident shards, 0 bytes). At D=1 the mesh degrades to
-                # the packed arena's shape with a collective-free program;
-                # multi-chip deployments get the identical code path with
-                # a wider mesh. The dispatch keeps the packed path's whole
-                # protection ladder: one re-send on a transport-marked
-                # error (resilience.transient — a device runtime error is
-                # never one, so an OOM or Mosaic failure counts against
-                # the breaker at once), the circuit breaker + host-oracle
-                # fallback around this block, and the async-readback
-                # overlap below.
-                from ..parallel import (
-                    arena_mesh, solve_allocate_sharded_arena,
-                )
-                from ..resilience.transient import retry_transient
-                t1 = _time.perf_counter()
-                fbuf, ibuf, layout = arr.packed()
-                timing["pack_ms"] = (_time.perf_counter() - t1) * 1e3
-                sdc = getattr(ssn, "sharded_device_cache", None)
-                if sdc is None:
-                    from ..ops.device_cache import ShardedDeviceCache
-                    sdc = ShardedDeviceCache(arena_mesh())
-                    ssn.sharded_device_cache = sdc
-                    if getattr(ssn, "cache", None) is not None:
-                        # persist across sessions: an arena is only an
-                        # arena if it outlives the session that built it
-                        ssn.cache.sharded_device_cache = sdc
-                fault_dc = sdc
-                mesh = sdc.mesh
-                t1 = _time.perf_counter()
-                bufs = sdc.update(fbuf, ibuf, layout)
-                params = sdc.params_device(params)
-                timing["delta_plan_ms"] = (_time.perf_counter() - t1) * 1e3
-                timing["delta_chunks"] = float(sdc.last_shipped_chunks)
-                timing["arena_mode"] = "sharded"
-                timing["arena_bytes_shipped"] = \
-                    float(sdc.last_shipped_bytes)
-                timing["arena_full_ship"] = float(sdc.last_full_ship)
-                timing["arena_shard_bytes"] = \
-                    [float(b) for b in sdc.last_shard_bytes]
-                pw = getattr(ssn, "prewarmer", None)
-                if pw is not None and pw.mesh is None:
-                    # sharded sessions must pre-warm (and persistent-
-                    # cache) the sharded arena variants too, not just
-                    # packed2d
-                    pw.mesh = mesh
-                # flags snapshot so the bucket prewarmer can predict this
-                # mode's next-bucket variants
-                sdc.last_solve_flags = dict(
-                    layout=layout, herd_mode=herd,
-                    score_families=families,
-                    use_queue_cap=use_queue_cap,
-                    use_drf_order=use_drf_order,
-                    use_hdrf_order=use_hdrf_order,
-                    work_conserving=work_conserving)
-                t1 = _time.perf_counter()
-                r = retry_transient(
-                    lambda: solve_allocate_sharded_arena(
-                        *bufs, params, mesh, herd_mode=herd,
-                        score_families=families,
+            dc = getattr(ssn, "device_cache", None)
+            sidecar = getattr(ssn, "sidecar", None)
+            # which arena a device fault must invalidate: the packed cache by
+            # default, the sharded arena when this session dispatched there
+            fault_dc = dc
+            try:
+                # device-path circuit-breaker scope: anything that throws out
+                # of the dispatch (XLA runtime error, OOM, dead sidecar, an
+                # injected fault) counts one consecutive device failure and
+                # this session finishes through the host oracle
+                faults.fire("solver_dispatch")
+                if sequential:
+                    res = solve_allocate_sequential(
+                        arr.device_dict(), params, score_families=families,
                         use_queue_cap=use_queue_cap,
-                        use_drf_order=use_drf_order,
-                        use_hdrf_order=use_hdrf_order),
-                    what="sharded solver dispatch")
-                timing["dispatch_ms"] = (_time.perf_counter() - t1) * 1e3
-                # the sharded kernel produces no compact readback:
-                # assigned/kind stay DEVICE futures here and collect in
-                # the res-is-None branch below, after the overlap window
-                assigned = r.assigned
-                kind = r.kind
-                res = None
-            elif sidecar is not None:
-                # process boundary: ship the packed snapshot to the solver
-                # sidecar (which owns the TPU) and replay its assignments
-                fbuf, ibuf, layout = arr.packed()
-                assigned, kind, _info = sidecar.solve(
-                    fbuf, ibuf, layout, params, herd_mode=herd,
-                    score_families=families, use_queue_cap=use_queue_cap,
-                    use_drf_order=use_drf_order,
-                    use_hdrf_order=use_hdrf_order,
-                    work_conserving=work_conserving)
-                res = None
-            elif dc is not None:
-                # device-resident buffers, fused dispatch: the dirty-chunk
-                # scatter runs INSIDE the solve jit, so a session costs
-                # exactly one dispatch (scatter+solve) + one compact
-                # readback. Sessions dirtying more than FUSED_SLOTS chunks
-                # use the separate scatter + non-fused solve (3
-                # dispatches, but no extra solve compile variants)
-                from ..ops.solver import (
-                    solve_allocate_delta, solve_allocate_packed2d,
-                )
-                t1 = _time.perf_counter()
-                fbuf, ibuf, layout = arr.packed()
-                timing["pack_ms"] = (_time.perf_counter() - t1) * 1e3
-                params = dc.params_device(params)
-                # flags snapshot for diagnostics/benchmarks that
-                # re-dispatch the same solve variant against the
-                # committed buffers
-                dc.last_solve_flags = dict(
-                    layout=layout, herd_mode=herd, score_families=families,
-                    use_queue_cap=use_queue_cap,
-                    use_drf_order=use_drf_order,
-                    use_hdrf_order=use_hdrf_order,
-                    work_conserving=work_conserving)
-                dc.last_params = params
-                t1 = _time.perf_counter()
-                kind_, payload = dc.plan_delta(fbuf, ibuf, layout)
-                timing["delta_plan_ms"] = (_time.perf_counter() - t1) * 1e3
-                timing["delta_chunks"] = float(dc.last_shipped_chunks)
-                timing["delta_fused"] = float(kind_ == "fused")
-                timing["arena_mode"] = "packed"
-                timing["arena_bytes_shipped"] = float(dc.last_shipped_bytes)
-                timing["arena_full_ship"] = float(dc.last_full_ship)
-                t1 = _time.perf_counter()
-                if kind_ == "updated":
-                    f2d, i2d = payload
-                    res = solve_allocate_packed2d(
-                        f2d, i2d, layout, params, herd_mode=herd,
+                        work_conserving=work_conserving)
+                elif sharded:
+                    # mode: sharded — the node-axis shard_map solver over the
+                    # SHARDED device-resident arena (ShardedDeviceCache):
+                    # node-axis chunks live per mesh device, task/job chunks
+                    # are replicated once per device, and a steady session
+                    # ships dirty chunks only to the shard(s) owning them
+                    # (a zero-dirty session dispatches straight off the
+                    # resident shards, 0 bytes). At D=1 the mesh degrades to
+                    # the packed arena's shape with a collective-free program;
+                    # multi-chip deployments get the identical code path with
+                    # a wider mesh. The dispatch keeps the packed path's whole
+                    # protection ladder: one re-send on a transport-marked
+                    # error (resilience.transient — a device runtime error is
+                    # never one, so an OOM or Mosaic failure counts against
+                    # the breaker at once), the circuit breaker + host-oracle
+                    # fallback around this block, and the async-readback
+                    # overlap below.
+                    from ..parallel import (
+                        arena_mesh, solve_allocate_sharded_arena,
+                    )
+                    from ..resilience.transient import retry_transient
+                    with span("volcano.allocate.pack"):
+                        fbuf, ibuf, layout = arr.packed()
+                    sdc = getattr(ssn, "sharded_device_cache", None)
+                    if sdc is None:
+                        from ..ops.device_cache import ShardedDeviceCache
+                        sdc = ShardedDeviceCache(arena_mesh())
+                        ssn.sharded_device_cache = sdc
+                        if getattr(ssn, "cache", None) is not None:
+                            # persist across sessions: an arena is only an
+                            # arena if it outlives the session that built it
+                            ssn.cache.sharded_device_cache = sdc
+                    fault_dc = sdc
+                    mesh = sdc.mesh
+                    with span("volcano.allocate.delta_plan"):
+                        bufs = sdc.update(fbuf, ibuf, layout)
+                        params = sdc.params_device(params)
+                    timing["delta_chunks"] = float(sdc.last_shipped_chunks)
+                    timing["arena_mode"] = "sharded"
+                    timing["arena_bytes_shipped"] = \
+                        float(sdc.last_shipped_bytes)
+                    timing["arena_full_ship"] = float(sdc.last_full_ship)
+                    timing["arena_shard_bytes"] = \
+                        [float(b) for b in sdc.last_shard_bytes]
+                    pw = getattr(ssn, "prewarmer", None)
+                    if pw is not None and pw.mesh is None:
+                        # sharded sessions must pre-warm (and persistent-
+                        # cache) the sharded arena variants too, not just
+                        # packed2d
+                        pw.mesh = mesh
+                    # flags snapshot so the bucket prewarmer can predict this
+                    # mode's next-bucket variants
+                    sdc.last_solve_flags = dict(
+                        layout=layout, herd_mode=herd,
                         score_families=families,
                         use_queue_cap=use_queue_cap,
                         use_drf_order=use_drf_order,
                         use_hdrf_order=use_hdrf_order,
                         work_conserving=work_conserving)
+                    with span("volcano.allocate.dispatch", "dispatch_ms"):
+                        r = retry_transient(
+                            lambda: solve_allocate_sharded_arena(
+                                *bufs, params, mesh, herd_mode=herd,
+                                score_families=families,
+                                use_queue_cap=use_queue_cap,
+                                use_drf_order=use_drf_order,
+                                use_hdrf_order=use_hdrf_order),
+                            what="sharded solver dispatch")
+                    # the sharded kernel produces no compact readback:
+                    # assigned/kind stay DEVICE futures here and collect in
+                    # the res-is-None branch below, after the overlap window
+                    assigned = r.assigned
+                    kind = r.kind
+                    res = None
+                elif sidecar is not None:
+                    # process boundary: ship the packed snapshot to the solver
+                    # sidecar (which owns the TPU) and replay its assignments
+                    fbuf, ibuf, layout = arr.packed()
+                    assigned, kind, _info = sidecar.solve(
+                        fbuf, ibuf, layout, params, herd_mode=herd,
+                        score_families=families, use_queue_cap=use_queue_cap,
+                        use_drf_order=use_drf_order,
+                        use_hdrf_order=use_hdrf_order,
+                        work_conserving=work_conserving)
+                    res = None
+                elif dc is not None:
+                    # device-resident buffers, fused dispatch: the dirty-chunk
+                    # scatter runs INSIDE the solve jit, so a session costs
+                    # exactly one dispatch (scatter+solve) + one compact
+                    # readback. Sessions dirtying more than FUSED_SLOTS chunks
+                    # use the separate scatter + non-fused solve (3
+                    # dispatches, but no extra solve compile variants)
+                    from ..ops.solver import (
+                        solve_allocate_delta, solve_allocate_packed2d,
+                    )
+                    with span("volcano.allocate.pack"):
+                        fbuf, ibuf, layout = arr.packed()
+                    params = dc.params_device(params)
+                    # flags snapshot for diagnostics that re-dispatch the
+                    # same solve variant against the committed buffers
+                    dc.last_solve_flags = dict(
+                        layout=layout, herd_mode=herd, score_families=families,
+                        use_queue_cap=use_queue_cap,
+                        use_drf_order=use_drf_order,
+                        use_hdrf_order=use_hdrf_order,
+                        work_conserving=work_conserving)
+                    dc.last_params = params
+                    with span("volcano.allocate.delta_plan"):
+                        kind_, payload = dc.plan_delta(fbuf, ibuf, layout)
+                    timing["delta_chunks"] = float(dc.last_shipped_chunks)
+                    timing["arena_mode"] = "packed"
+                    timing["arena_bytes_shipped"] = \
+                        float(dc.last_shipped_bytes)
+                    timing["arena_full_ship"] = float(dc.last_full_ship)
+                    with span("volcano.allocate.dispatch", "dispatch_ms"):
+                        if kind_ == "updated":
+                            f2d, i2d = payload
+                            res = solve_allocate_packed2d(
+                                f2d, i2d, layout, params, herd_mode=herd,
+                                score_families=families,
+                                use_queue_cap=use_queue_cap,
+                                use_drf_order=use_drf_order,
+                                use_hdrf_order=use_hdrf_order,
+                                work_conserving=work_conserving)
+                        else:
+                            f2d, i2d, fi, fv, ii, iv = payload
+                            try:
+                                res, new_f, new_i = solve_allocate_delta(
+                                    f2d, i2d, fi, fv, ii, iv, layout, params,
+                                    herd_mode=herd, score_families=families,
+                                    use_queue_cap=use_queue_cap,
+                                    use_drf_order=use_drf_order,
+                                    use_hdrf_order=use_hdrf_order,
+                                    work_conserving=work_conserving)
+                            except Exception:
+                                # donation may have consumed the buffers — but
+                                # the host mirror and the (never-donated)
+                                # pinned params are fine: soft-invalidate so
+                                # the next session re-ships the chunked buffers
+                                # and re-validates the params in place instead
+                                # of rebuilding cold
+                                dc.invalidate()
+                                raise
+                            dc.commit(new_f, new_i)
                 else:
-                    f2d, i2d, fi, fv, ii, iv = payload
-                    try:
-                        res, new_f, new_i = solve_allocate_delta(
-                            f2d, i2d, fi, fv, ii, iv, layout, params,
-                            herd_mode=herd, score_families=families,
-                            use_queue_cap=use_queue_cap,
-                            use_drf_order=use_drf_order,
-                            use_hdrf_order=use_hdrf_order,
-                            work_conserving=work_conserving)
-                    except Exception:
-                        # donation may have consumed the buffers — but the
-                        # host mirror and the (never-donated) pinned params
-                        # are fine: soft-invalidate so the next session
-                        # re-ships the chunked buffers and re-validates the
-                        # params in place instead of rebuilding cold
-                        dc.invalidate()
-                        raise
-                    dc.commit(new_f, new_i)
-                timing["dispatch_ms"] = (_time.perf_counter() - t1) * 1e3
-            else:
-                res = solve_allocate(
-                    arr.device_dict(), params, herd_mode=herd,
-                    score_families=families, use_queue_cap=use_queue_cap,
-                    use_drf_order=use_drf_order,
-                    use_hdrf_order=use_hdrf_order,
-                    work_conserving=work_conserving)
-        except Exception:
-            log.exception("solver dispatch failed; resetting the device "
-                          "cache and falling back to the host loop")
-            self._device_fault_fallback(ssn, fault_dc, timing, breaker)
-            return
-        # ------------------------------------------------------------------
-        # dispatch/collect split: the jitted solve above is an ASYNC
-        # dispatch (res holds device futures), so the host is free until
-        # the compact readback below actually blocks. Spend that window on
-        # work that previously serialized after the device finished:
-        # replay preparation (the node-name table the Statement replay
-        # indexes), the bucket-prewarm occupancy check (ops.precompile),
-        # and a young-generation gc pass (collection is disabled during
-        # the cycle — see Scheduler.run_once — so this drains the nursery
-        # for free while the device solves). pipeline_solver=False keeps
-        # the strictly serial order for parity testing.
-        # ------------------------------------------------------------------
-        pipelined = bool(getattr(ssn, "pipeline_solver", True))
-        node_names = None
-        statements = None
-        prewarmed = False
-        if pipelined and (res is not None or sharded):
-            t1 = _time.perf_counter()
-            # previous-phase readback starts NOW: begin the device->host
-            # result transfer asynchronously so the wire RTT overlaps the
-            # solve tail and the replay-prep below instead of being paid
-            # serially when the collect blocks (ops.pipeline). The
-            # sharded kernel has no compact form; its assigned/kind
-            # futures prefetch the same way.
-            from ..ops.pipeline import start_readback
-            if res is not None:
-                start_readback(res.compact, res.assigned, res.kind)
-            else:
-                start_readback(assigned, kind)
-            node_names = [n.name for n in arr.nodes_list]
-            # Statement construction is pure (no session registration
-            # until ops are recorded), so the replay's per-job statements
-            # can be built before the results exist
-            statements = [ssn.statement(defer_events=True)
-                          for _ in job_order]
-            self._observe_prewarm(ssn, arr, fault_dc)
-            prewarmed = True
-            import jax
-            if jax.default_backend() != "cpu":
-                # young-gen GC only when the solve runs on a real
-                # accelerator: there the readback wait is genuine host
-                # idle, while on the CPU backend host and "device" share
-                # cores and the collection would just lengthen the cycle
-                import gc
-                gc.collect(0)
-            timing["overlap_ms"] = (_time.perf_counter() - t1) * 1e3
-        if res is not None:
-            # one int16 readback instead of two int32 ones: half the
-            # device->host bytes on the session's critical path (the
-            # sidecar path already returned host arrays)
-            from ..ops.solver import COMPACT_KIND_SHIFT, decode_compact
-            t1 = _time.perf_counter()
-            try:
-                if arr.N <= (1 << COMPACT_KIND_SHIFT):
-                    assigned, kind = decode_compact(res.compact)
-                else:  # >16k nodes: node index overflows int16 packing
-                    assigned = np.asarray(res.assigned)
-                    kind = np.asarray(res.kind)
-                self._check_solver_output(assigned, kind,
-                                          len(tasks_in_order),
-                                          len(arr.nodes_list))
+                    res = solve_allocate(
+                        arr.device_dict(), params, herd_mode=herd,
+                        score_families=families, use_queue_cap=use_queue_cap,
+                        use_drf_order=use_drf_order,
+                        use_hdrf_order=use_hdrf_order,
+                        work_conserving=work_conserving)
             except Exception:
-                # async-collect failure: the error surfaces HERE, after a
-                # donated-buffer dispatch already commit()ed what are now
-                # poisoned device buffers — drop the device cache so the
-                # next session re-ships in full instead of solving on (or
-                # scattering into) invalid buffers, and finish THIS
-                # session through the host oracle so a device fault costs
-                # one slow cycle, not a scheduling gap
-                log.exception("solver collect failed; resetting device "
+                log.exception("solver dispatch failed; resetting the device "
                               "cache and falling back to the host loop")
+                solve.discard()
                 self._device_fault_fallback(ssn, fault_dc, timing, breaker)
                 return
-            timing["readback_ms"] = (_time.perf_counter() - t1) * 1e3
-            if not pipelined:
-                # serial mode still pre-warms (after the readback), so
-                # turning the overlap off doesn't also disable the
-                # compile-stall protection
-                self._observe_prewarm(ssn, arr, dc)
-        else:
-            # sharded/sidecar path: block on the assigned/kind readback
-            # (the sidecar already returned host arrays; the sharded
-            # overlap window above began the async device->host transfer,
-            # so this collect pays only the remaining tail)
-            t1 = _time.perf_counter()
-            try:
-                assigned = np.asarray(assigned)
-                kind = np.asarray(kind)
-                self._check_solver_output(assigned, kind,
-                                          len(tasks_in_order),
-                                          len(arr.nodes_list))
-            except Exception:
-                log.exception("sharded/sidecar solver output failed "
-                              "validation; falling back to the host loop")
-                self._device_fault_fallback(ssn, fault_dc, timing, breaker)
-                return
-            timing["readback_ms"] = (_time.perf_counter() - t1) * 1e3
-            if not prewarmed:
-                # the sidecar (and serial sharded) path skipped the
-                # overlap window above, so the occupancy check runs here
-                # — a sharded session's bucket crossing must pre-warm its
-                # own (sharded) variants
-                self._observe_prewarm(ssn, arr, fault_dc)
-        if breaker is not None:
-            # a full dispatch+collect round-trip with sane output: the
-            # device path is healthy (closes a half-open breaker)
-            breaker.record_success()
-        timing["solve_ms"] = (_time.perf_counter() - t0) * 1e3
-        t0 = _time.perf_counter()
+            # -----------------------------------------------------------------
+            # dispatch/collect split: the jitted solve above is an ASYNC
+            # dispatch (res holds device futures), so the host is free until
+            # the compact readback below actually blocks. Spend that window on
+            # work that previously serialized after the device finished:
+            # replay preparation (the node-name table the Statement replay
+            # indexes), the bucket-prewarm occupancy check (ops.precompile),
+            # and a young-generation gc pass (collection is disabled during
+            # the cycle — see Scheduler.run_once — so this drains the nursery
+            # for free while the device solves). pipeline_solver=False keeps
+            # the strictly serial order for parity testing.
+            # -----------------------------------------------------------------
+            pipelined = bool(getattr(ssn, "pipeline_solver", True))
+            node_names = None
+            statements = None
+            prewarmed = False
+            if pipelined and (res is not None or sharded):
+                with span("volcano.allocate.overlap"):
+                    # previous-phase readback starts NOW: begin the
+                    # device->host result transfer asynchronously so the wire
+                    # RTT overlaps the solve tail and the replay-prep below
+                    # instead of being paid serially when the collect blocks
+                    # (ops.pipeline). The sharded kernel has no compact form;
+                    # its assigned/kind futures prefetch the same way.
+                    from ..ops.pipeline import start_readback
+                    if res is not None:
+                        start_readback(res.compact, res.assigned, res.kind,
+                                       res.rounds)
+                    else:
+                        start_readback(assigned, kind)
+                    node_names = [n.name for n in arr.nodes_list]
+                    # Statement construction is pure (no session registration
+                    # until ops are recorded), so the replay's per-job
+                    # statements can be built before the results exist
+                    statements = [ssn.statement(defer_events=True)
+                                  for _ in job_order]
+                    self._observe_prewarm(ssn, arr, fault_dc)
+                    prewarmed = True
+                    import jax
+                    if jax.default_backend() != "cpu":
+                        # young-gen GC only when the solve runs on a real
+                        # accelerator: there the readback wait is genuine host
+                        # idle, while on the CPU backend host and "device"
+                        # share cores and the collection would just lengthen
+                        # the cycle
+                        import gc
+                        gc.collect(0)
+            if res is not None:
+                # one int16 readback instead of two int32 ones: half the
+                # device->host bytes on the session's critical path (the
+                # sidecar path already returned host arrays)
+                from ..ops.solver import COMPACT_KIND_SHIFT, decode_compact
+                try:
+                    with span("volcano.allocate.readback", "readback_ms"):
+                        if arr.N <= (1 << COMPACT_KIND_SHIFT):
+                            assigned, kind = decode_compact(res.compact)
+                        else:  # >16k nodes: node index overflows int16 packing
+                            assigned = np.asarray(res.assigned)
+                            kind = np.asarray(res.kind)
+                        self._check_solver_output(assigned, kind,
+                                                  len(tasks_in_order),
+                                                  len(arr.nodes_list))
+                        # the compact readback's transfer carried the round
+                        # count
+                        count("solve_rounds", int(res.rounds))
+                except Exception:
+                    # async-collect failure: the error surfaces HERE, after a
+                    # donated-buffer dispatch already commit()ed what are now
+                    # poisoned device buffers — drop the device cache so the
+                    # next session re-ships in full instead of solving on (or
+                    # scattering into) invalid buffers, and finish THIS
+                    # session through the host oracle so a device fault costs
+                    # one slow cycle, not a scheduling gap
+                    log.exception("solver collect failed; resetting device "
+                                  "cache and falling back to the host loop")
+                    solve.discard()
+                    self._device_fault_fallback(ssn, fault_dc, timing, breaker)
+                    return
+                if not pipelined:
+                    # serial mode still pre-warms (after the readback), so
+                    # turning the overlap off doesn't also disable the
+                    # compile-stall protection
+                    self._observe_prewarm(ssn, arr, dc)
+            else:
+                # sharded/sidecar path: block on the assigned/kind readback
+                # (the sidecar already returned host arrays; the sharded
+                # overlap window above began the async device->host transfer,
+                # so this collect pays only the remaining tail)
+                try:
+                    with span("volcano.allocate.readback", "readback_ms"):
+                        assigned = np.asarray(assigned)
+                        kind = np.asarray(kind)
+                        self._check_solver_output(assigned, kind,
+                                                  len(tasks_in_order),
+                                                  len(arr.nodes_list))
+                except Exception:
+                    log.exception("sharded/sidecar solver output failed "
+                                  "validation; falling back to the host loop")
+                    solve.discard()
+                    self._device_fault_fallback(ssn, fault_dc, timing, breaker)
+                    return
+                if not prewarmed:
+                    # the sidecar (and serial sharded) path skipped the
+                    # overlap window above, so the occupancy check runs here
+                    # — a sharded session's bucket crossing must pre-warm its
+                    # own (sharded) variants
+                    self._observe_prewarm(ssn, arr, fault_dc)
+            if breaker is not None:
+                # a full dispatch+collect round-trip with sane output: the
+                # device path is healthy (closes a half-open breaker)
+                breaker.record_success()
 
-        # replay through the Statement boundary in job order; events fire
-        # as one batch per committed job and each job's accounting applies
-        # as one bulk Statement wave (identical final handler/session state
-        # — see Statement.allocate_bulk — at a fraction of the per-task
-        # cost; the per-task loop blew the 1 s period on a 10k burst)
-        assigned = assigned.tolist()  # plain ints: no np scalar per lookup
-        kind = kind.tolist()
-        # bulk-commit window: committed statements queue their cache-side
-        # binds + allocate events; ONE flush applies them with full-width
-        # node grouping (per-job commits degrade to 1-task node groups
-        # when gangs spread across nodes — see Statement.commit)
-        from ..framework.statement import begin_bulk_commit, \
-            flush_bulk_commit
-        acc = begin_bulk_commit(ssn)
-        try:
-            self._replay(ssn, arr, job_order, assigned, kind, node_names,
-                         statements)
-        finally:
-            # exception-safe: jobs already committed into the window MUST
-            # still get their cache binds + events even if a later job's
-            # replay blows up (per-statement commits applied them eagerly)
-            flush_bulk_commit(ssn, acc)
-        timing["replay_ms"] = (_time.perf_counter() - t0) * 1e3
+        with span("volcano.allocate.replay", "replay_ms"):
+            # replay through the Statement boundary in job order; events fire
+            # as one batch per committed job and each job's accounting applies
+            # as one bulk Statement wave (identical final handler/session state
+            # — see Statement.allocate_bulk — at a fraction of the per-task
+            # cost; the per-task loop blew the 1 s period on a 10k burst)
+            assigned = assigned.tolist()  # plain ints: no np scalar per lookup
+            kind = kind.tolist()
+            # bulk-commit window: committed statements queue their cache-side
+            # binds + allocate events; ONE flush applies them with full-width
+            # node grouping (per-job commits degrade to 1-task node groups
+            # when gangs spread across nodes — see Statement.commit)
+            from ..framework.statement import begin_bulk_commit, \
+                flush_bulk_commit
+            acc = begin_bulk_commit(ssn)
+            try:
+                self._replay(ssn, arr, job_order, assigned, kind, node_names,
+                             statements)
+            finally:
+                # exception-safe: jobs already committed into the window MUST
+                # still get their cache binds + events even if a later job's
+                # replay blows up (per-statement commits applied them eagerly)
+                flush_bulk_commit(ssn, acc)
 
     def _device_fault_fallback(self, ssn, dc, timing, breaker) -> None:
         """Shared device-failure containment: count the failure against

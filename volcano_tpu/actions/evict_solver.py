@@ -10,12 +10,12 @@ deviations listed in ops/evict.py.
 from __future__ import annotations
 
 import logging
-import time
 from typing import Dict, List, Tuple
 
 import numpy as np
 
 from ..api import TaskStatus
+from ..metrics.spans import count, span
 from ..models import PodGroupPhase
 from ..utils import PriorityQueue
 
@@ -205,68 +205,74 @@ def run_evict_solver(ssn, mode: str, skip_jobs=()):
         breaker.count_fallback()
         return None  # circuit open: host loop covers this cycle
     preempt = mode == "preempt"
-    job_order = collect_claimer_jobs(
-        ssn, require_not_pipelined=preempt, skip_overused=not preempt,
-        skip_jobs=skip_jobs)
+    with span(f"volcano.{mode}.collect"):
+        job_order = collect_claimer_jobs(
+            ssn, require_not_pipelined=preempt, skip_overused=not preempt,
+            skip_jobs=skip_jobs)
     if not job_order:
         return []
-    tasks_in_order = [t for _, tasks in job_order for t in tasks]
-    arr = flatten_snapshot(
-        {j.uid: j for j, _ in job_order}, ssn.nodes, tasks_in_order,
-        queues=ssn.queues,
-        cache=getattr(ssn, "evict_flatten_caches", {}).get(mode),
-        grouped=job_order)
-    victims = collect_victims(ssn, arr.nodes_list)
-    if not victims:
-        return [j for j, _ in job_order]
-    varrays = build_victim_arrays(ssn, arr, victims, job_order, mode)
-    params, families = build_score_inputs(ssn, arr)
+    with span(f"volcano.{mode}.flatten"):
+        tasks_in_order = [t for _, tasks in job_order for t in tasks]
+        arr = flatten_snapshot(
+            {j.uid: j for j, _ in job_order}, ssn.nodes, tasks_in_order,
+            queues=ssn.queues,
+            cache=getattr(ssn, "evict_flatten_caches", {}).get(mode),
+            grouped=job_order)
+    with span(f"volcano.{mode}.victims"):
+        victims = collect_victims(ssn, arr.nodes_list)
+        if not victims:
+            return [j for j, _ in job_order]
+        varrays = build_victim_arrays(ssn, arr, victims, job_order, mode)
+        params, families = build_score_inputs(ssn, arr)
 
-    # the closed-form kernel is preempt-only: reclaim's per-claimer victim
-    # coverage rule is not a per-node divisibility (see solve_evict_uniform)
-    uniform = _uniform_job_arrays(arr, job_order) if preempt else None
-    if uniform is not None:
-        (varrays["job_req"], varrays["job_acct"],
-         varrays["job_count"]) = uniform
-    vnp = {k: np.asarray(v) for k, v in varrays.items()}
+        # the closed-form kernel is preempt-only: reclaim's per-claimer
+        # victim coverage rule is not a per-node divisibility (see
+        # solve_evict_uniform)
+        uniform = _uniform_job_arrays(arr, job_order) if preempt else None
+        if uniform is not None:
+            (varrays["job_req"], varrays["job_acct"],
+             varrays["job_count"]) = uniform
+        vnp = {k: np.asarray(v) for k, v in varrays.items()}
     sidecar = getattr(ssn, "sidecar", None)
     timing = ssn.solver_options.setdefault("timing", {})
-    t0 = time.perf_counter()
     try:
         # breaker scope: a throwing evict dispatch/collect (or an injected
         # fault) counts one consecutive device failure; the caller's host
-        # loop covers this cycle
-        faults.fire("evict_dispatch")
-        if sidecar is not None:
-            # process boundary: evict solves ship to the solver process
-            # too (job_req in the victim dict selects the fast path)
-            assigned, evicted_by = sidecar.solve_evict(
-                arr.device_dict(), vnp, params, score_families=families,
-                require_freed_covers=not preempt,
-                allow_revert=preempt, stop_at_need=preempt)
-        else:
-            if uniform is not None:
-                # gang fast path: one solve step per JOB
-                # (solve_evict_uniform)
-                from ..ops.evict import solve_evict_uniform
-                res = solve_evict_uniform(
-                    arr.device_dict(), vnp, params,
-                    score_families=families,
-                    require_freed_covers=False, stop_at_need=True)
-            else:
-                res = solve_evict(
-                    arr.device_dict(), vnp, params,
-                    score_families=families,
+        # loop covers this cycle. The span's key (dispatch + readback of
+        # the evict solve) is present only when the solve ran.
+        with span(f"volcano.{mode}.solve",
+                  "preempt_solve_ms" if preempt else None):
+            faults.fire("evict_dispatch")
+            if sidecar is not None:
+                # process boundary: evict solves ship to the solver process
+                # too (job_req in the victim dict selects the fast path)
+                assigned, evicted_by = sidecar.solve_evict(
+                    arr.device_dict(), vnp, params, score_families=families,
                     require_freed_covers=not preempt,
                     allow_revert=preempt, stop_at_need=preempt)
-            from ..ops.evict import decode_evict_compact
-            try:
-                # one int16 readback carries both outputs
-                assigned, evicted_by = decode_evict_compact(
-                    res.compact, arr.task_init_req.shape[0])
-            except ValueError:  # >32k nodes/jobs: indices overflow packing
-                assigned = np.asarray(res.assigned)
-                evicted_by = np.asarray(res.evicted_by)
+            else:
+                if uniform is not None:
+                    # gang fast path: one solve step per JOB
+                    # (solve_evict_uniform)
+                    from ..ops.evict import solve_evict_uniform
+                    res = solve_evict_uniform(
+                        arr.device_dict(), vnp, params,
+                        score_families=families,
+                        require_freed_covers=False, stop_at_need=True)
+                else:
+                    res = solve_evict(
+                        arr.device_dict(), vnp, params,
+                        score_families=families,
+                        require_freed_covers=not preempt,
+                        allow_revert=preempt, stop_at_need=preempt)
+                from ..ops.evict import decode_evict_compact
+                try:
+                    # one int16 readback carries both outputs
+                    assigned, evicted_by = decode_evict_compact(
+                        res.compact, arr.task_init_req.shape[0])
+                except ValueError:  # >32k nodes/jobs: indices overflow
+                    assigned = np.asarray(res.assigned)
+                    evicted_by = np.asarray(res.evicted_by)
     except Exception:
         log.exception("%s device solve failed; degrading to the host "
                       "loop for this cycle", mode)
@@ -276,11 +282,27 @@ def run_evict_solver(ssn, mode: str, skip_jobs=()):
         return None
     if breaker is not None:
         breaker.record_success()
-    # dispatch + readback of the evict solve, present only when it ran
-    timing[f"{mode}_solve_ms"] = (time.perf_counter() - t0) * 1e3
-    by_job = _evictions_by_job(evicted_by)
+    # the scan's steps: one per claimer job (uniform) or per claimer task,
+    # padded to the bucket
+    if uniform is not None:
+        count("evict_scan_steps", arr.job_min.shape[0])
+        count("evict_claimers", len(job_order))
+    else:
+        count("evict_scan_steps", arr.task_init_req.shape[0])
+        count("evict_claimers", len(tasks_in_order))
+    with span(f"volcano.{mode}.replay"):
+        _replay(ssn, mode, job_order, victims, arr, assigned, evicted_by)
+    return [j for j, _ in job_order]
 
+
+def _replay(ssn, mode: str, job_order, victims, arr, assigned,
+            evicted_by) -> None:
+    """Apply the evict solve: per claimer job, re-check its victims
+    against the live plugin verdicts, evict them, pipeline its tasks."""
     from ..metrics import metrics
+
+    preempt = mode == "preempt"
+    by_job = _evictions_by_job(evicted_by)
     idx = 0
     for j, (job, tasks) in enumerate(job_order):
         stmt = ssn.statement() if preempt else None
@@ -342,4 +364,3 @@ def run_evict_solver(ssn, mode: str, skip_jobs=()):
                 stmt.commit()
             else:
                 stmt.discard()
-    return [j for j, _ in job_order]

@@ -14,6 +14,7 @@ from typing import Dict
 from ..api import TaskStatus
 from ..framework import Action
 from ..metrics import metrics
+from ..metrics.spans import span
 from ..models import PodGroupPhase
 from ..utils import PriorityQueue
 from ..utils.scheduler_helper import validate_victims
@@ -90,7 +91,8 @@ class PreemptAction(Action):
         # within one job's own tasks — preempt.go:137-156 second phase).
         # It runs on exactly the solver's claimer set (the host loop's
         # under_request: jobs that were not yet pipelined at collection).
-        self._intra_job(ssn, claimers)
+        with span("volcano.preempt.intra_job"):
+            self._intra_job(ssn, claimers)
 
     def _intra_job(self, ssn, jobs) -> None:
         oc = getattr(ssn, "order_cache", None)

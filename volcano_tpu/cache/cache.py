@@ -24,6 +24,7 @@ from ..models import (
 )
 from ..client.store import ClusterStore, ConflictError, NotFoundError
 from ..metrics import metrics
+from ..metrics.spans import count, span
 
 log = logging.getLogger(__name__)
 
@@ -261,6 +262,17 @@ class DefaultVolumeBinder:
             task.volume_ready = False
 
 
+def _count_binds(tasks, opened: float) -> None:
+    """Count the binds of ``tasks`` written, and the wait of each of their
+    pods from its creation to the open of the session that bound it."""
+    count("binds_written", len(tasks))
+    waits = [opened - t.pod.creation_timestamp for t in tasks
+             if opened and t.pod.creation_timestamp]
+    if waits:
+        count("pod_wait_ms_sum", sum(waits) * 1e3)
+        count("pod_wait_n", len(waits))
+
+
 class SchedulerCache:
     """Mirror of cluster state + effector plumbing."""
 
@@ -279,6 +291,9 @@ class SchedulerCache:
             ThreadPoolExecutor(max_workers=4, thread_name_prefix="effector")
             if async_effectors else None)
         self._pending_effects: List = []
+        #: wall time of the last snapshot, i.e. of the open of the session
+        #: deciding the binds now made: a bound pod's wait counts up to it
+        self.snapshot_at = 0.0
 
         self.jobs: Dict[str, JobInfo] = {}
         self.nodes: Dict[str, NodeInfo] = {}
@@ -742,6 +757,7 @@ class SchedulerCache:
         # that lock), so holding it here is the SchedulerCache.Mutex of the
         # reference (cache.go:72, Snapshot locks before cloning).
         with self.cluster.locked():
+            self.snapshot_at = time.time()
             self._finalize_expired_evictions()
             return self._snapshot_locked()
 
@@ -843,9 +859,12 @@ class SchedulerCache:
             raise
         start = (job.schedule_start_timestamp
                  or task.pod.creation_timestamp or 0.0)
+        opened = self.snapshot_at
 
         def effect():
-            self.binder.bind(task.pod, hostname)
+            with span("volcano.bind.write"):
+                self.binder.bind(task.pod, hostname)
+            _count_binds([task], opened)
             metrics.schedule_attempts.inc(labels={"result": "scheduled"})
             if start:
                 metrics.task_scheduling_latency.observe(
@@ -950,21 +969,26 @@ class SchedulerCache:
             except (KeyError, ValueError) as e:
                 failures.append((ti, e))
         if bound:
+            opened = self.snapshot_at
+
             def effect():
-                ok = 0
+                written = []
                 lat = []
-                for task, start in zip(bound, starts):
-                    try:
-                        self.binder.bind(task.pod, task.node_name)
-                    except Exception:
-                        log.exception("bind %s failed", task.key)
-                        metrics.schedule_attempts.inc(
-                            labels={"result": "error"})
-                        self.resync_task(task)
-                        continue
-                    ok += 1
-                    if start:
-                        lat.append((time.time() - start) * 1e3)
+                with span("volcano.bind.write"):
+                    for task, start in zip(bound, starts):
+                        try:
+                            self.binder.bind(task.pod, task.node_name)
+                        except Exception:
+                            log.exception("bind %s failed", task.key)
+                            metrics.schedule_attempts.inc(
+                                labels={"result": "error"})
+                            self.resync_task(task)
+                            continue
+                        written.append(task)
+                        if start:
+                            lat.append((time.time() - start) * 1e3)
+                _count_binds(written, opened)
+                ok = len(written)
                 if ok:
                     metrics.schedule_attempts.inc(
                         ok, labels={"result": "scheduled"})
@@ -987,9 +1011,13 @@ class SchedulerCache:
         except (ValueError, KeyError):
             job.update_task_status(task, original)
             raise
-        self._dispatch_effect(
-            lambda: self.evictor.evict(task.pod, reason),
-            lambda: self.resync_task(task), f"evict {task.key}")
+
+        def effect():
+            self.evictor.evict(task.pod, reason)
+            count("evictions_written")
+
+        self._dispatch_effect(effect, lambda: self.resync_task(task),
+                              f"evict {task.key}")
 
     def _dispatch_effect(self, effect, failed, what: str) -> None:
         """Run a side-effect against the control plane: inline by default,

@@ -4,6 +4,7 @@ ControllerManager wires every controller to a ClusterStore and drains them;
 the reference runs them under leader election in controller-manager.
 """
 
+from ..metrics.spans import span
 from .apis import JobInfo, Request  # noqa: F401
 from .framework import (  # noqa: F401
     Controller, ControllerOption, register_controller,
@@ -102,13 +103,15 @@ class ControllerManager:
             ctrl.run()
 
     def process_all(self, rounds: int = 4) -> None:
-        for _ in range(rounds):
-            for ctrl in self.controllers:
-                if self.shard_workers > 1 and isinstance(ctrl,
-                                                         JobController):
-                    ctrl.process_all(parallel=self.shard_workers)
-                else:
-                    ctrl.process_all()
+        with span("volcano.controllers"):
+            for _ in range(rounds):
+                for ctrl in self.controllers:
+                    with span(ctrl.span):
+                        if self.shard_workers > 1 and isinstance(
+                                ctrl, JobController):
+                            ctrl.process_all(parallel=self.shard_workers)
+                        else:
+                            ctrl.process_all()
 
     def run_with_leader_election(self, stop, lock_name: str = "vc-controller-manager",
                                  identity: str = None) -> None:

@@ -17,6 +17,10 @@ class ControllerOption:
 
 
 class Controller:
+    #: the span (metrics.spans) each drain of this controller's queue is
+    #: timed under: ``volcano.controllers.<short name>``
+    span: str
+
     def name(self) -> str:
         raise NotImplementedError
 

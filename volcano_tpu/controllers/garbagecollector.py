@@ -24,6 +24,8 @@ def _finish_time(job: Job) -> float:
 
 
 class GarbageCollector(Controller):
+    span = "volcano.controllers.gc"
+
     def __init__(self):
         self.cluster: Optional[ClusterStore] = None
         self.queue: List[str] = []

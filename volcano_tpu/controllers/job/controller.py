@@ -19,6 +19,7 @@ from ...api.types import POD_GROUP_ANNOTATION
 from ...client.store import (
     AdmissionError, ClusterStore, ConflictError, NotFoundError,
 )
+from ...metrics.spans import count
 from ...models import (
     Action, Event, Job, JobPhase, Pod, PodGroup, PodGroupPhase, PodGroupSpec,
 )
@@ -73,6 +74,8 @@ def apply_policies(job: Job, req: Request) -> Action:
 
 
 class JobController(Controller):
+    span = "volcano.controllers.job"
+
     def __init__(self):
         self.cluster: Optional[ClusterStore] = None
         self.scheduler_name = "volcano"
@@ -512,6 +515,7 @@ class JobController(Controller):
             # ROADMAP item-3 bulk ingest seam); per-item results keep
             # the old loop's containment — a rejected pod costs that
             # pod, not the wave
+            created = 0
             for pod, res in zip(to_create, self.cluster.bulk_apply(
                     [("pods", pod, "create") for pod in to_create])):
                 if isinstance(res, AdmissionError):
@@ -520,6 +524,9 @@ class JobController(Controller):
                 elif isinstance(res, Exception):
                     log.error("failed to create pod %s: %s",
                               pod.name, res)
+                else:
+                    created += 1
+            count("pods_created", created)
         for task_name, actual in list(ji.pods.items()):
             wanted = desired.get(task_name, {})
             for pod_name, pod in list(actual.items()):

@@ -25,6 +25,8 @@ class KubeletStandin(Controller):
     stand-off between a saturated queue and its claimant never converges —
     the same attrition dynamic a real cluster gets from kubelet timing."""
 
+    span = "volcano.controllers.kubelet"
+
     def __init__(self, grace_seconds: float = 30.0, clock=time.time):
         # clock is the kubelet's time source: wall clock in a live control
         # plane, the virtual clock in the trace-driven simulator

@@ -18,6 +18,8 @@ log = logging.getLogger(__name__)
 
 
 class PodGroupController(Controller):
+    span = "volcano.controllers.podgroup"
+
     def __init__(self):
         self.cluster: Optional[ClusterStore] = None
         self.scheduler_name = "volcano"

@@ -18,6 +18,8 @@ log = logging.getLogger(__name__)
 
 
 class QueueController(Controller):
+    span = "volcano.controllers.queue"
+
     def __init__(self):
         self.cluster: Optional[ClusterStore] = None
         self.queue: List[str] = []  # queue names to sync

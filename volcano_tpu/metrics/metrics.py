@@ -235,15 +235,17 @@ solver_compile_total = registry.register(Counter(
 solver_compile_seconds_total = registry.register(Counter(
     "volcano_solver_compile_seconds_total",
     "Seconds spent in XLA backend compiles, by thread class", ["thread"]))
+solver_compile_phase_seconds_total = registry.register(Counter(
+    "volcano_solver_compile_phase_seconds_total",
+    "Seconds a first dispatch spent tracing, lowering and loading from the "
+    "persistent compilation cache, by phase and thread class",
+    ["phase", "thread"]))
 compile_cache_hits_total = registry.register(Counter(
     "volcano_compile_cache_hits_total",
     "Persistent compilation cache hits"))
 prewarm_completions_total = registry.register(Counter(
     "volcano_prewarm_completions_total",
     "Background bucket pre-warm completions"))
-session_phase_ms = registry.register(Gauge(
-    "volcano_session_phase_milliseconds",
-    "Per-phase latency of the last scheduling cycle", ["phase"]))
 
 # -- device-resident arena metrics (ops.device_cache + ops.pipeline) --------
 

@@ -10,10 +10,12 @@ session thread:
   full-solve kernel).
 
 - **CompileWatcher**: a ``jax.monitoring`` tap recording per-thread
-  backend-compile counts/seconds and persistent-cache hits, feeding
-  ``volcano_tpu.metrics``. The scheduler surfaces the deltas in
-  ``last_cycle_timing`` so "a compile happened on the session thread"
-  is an observable regression, not a mystery 10 s spike.
+  backend-compile counts/seconds, the seconds a first dispatch spends
+  tracing, lowering and loading from the persistent cache, and
+  persistent-cache hits, feeding ``volcano_tpu.metrics``. The scheduler
+  surfaces the deltas in ``last_cycle_timing`` so "a compile happened on
+  the session thread" is an observable regression, not a mystery 10 s
+  spike.
 
 - **BucketPrewarmer**: the flatten pads to compile buckets
   (``ops.arrays.bucket`` quarter-steps), so the set of future jit
@@ -102,20 +104,33 @@ def configure_compilation_cache(cache_dir: Optional[str] = None,
 # compile observability
 # ---------------------------------------------------------------------------
 
+#: the ``jax.monitoring`` durations of a first dispatch besides the
+#: backend compile, by phase: tracing to a jaxpr, lowering it to an MLIR
+#: module, and reading the executable from the persistent cache (a cache
+#: load is timed inside the backend compile's duration as well)
+COMPILE_PHASE_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_load",
+}
+
+
 class CompileWatcher:
     """Per-thread XLA compile accounting via ``jax.monitoring``.
 
     ``install()`` registers two listeners (idempotent): backend-compile
-    durations keyed by ``threading.get_ident()`` and persistent-cache hit
-    events. Threads registered through ``register_background`` (the
-    prewarmer's workers) are labeled ``background`` in the exported
-    metrics; everything else counts as ``session`` — exactly the split
-    the <50 ms budget cares about.
+    and ``COMPILE_PHASE_EVENTS`` durations keyed by
+    ``threading.get_ident()``, and persistent-cache hit events. Threads
+    registered through ``register_background`` (the prewarmer's workers)
+    are labeled ``background`` in the exported metrics; everything else
+    counts as ``session`` — exactly the split the <50 ms budget cares
+    about.
     """
 
     def __init__(self):
         self._lock = threading.Lock()
         self._by_thread: Dict[int, list] = {}   # ident -> [count, seconds]
+        self._phases: Dict[int, Dict[str, float]] = {}  # ident -> s/phase
         self._background: set = set()
         self.cache_hits = 0
         self._installed = False
@@ -140,17 +155,26 @@ class CompileWatcher:
 
     def _on_duration(self, key: str, secs: float, **kw) -> None:
         try:
-            if "backend_compile" not in key:
+            phase = COMPILE_PHASE_EVENTS.get(key)
+            if phase is None and "backend_compile" not in key:
                 return
             ident = threading.get_ident()
             with self._lock:
-                ent = self._by_thread.setdefault(ident, [0, 0.0])
-                ent[0] += 1
-                ent[1] += secs
+                if phase is None:
+                    ent = self._by_thread.setdefault(ident, [0, 0.0])
+                    ent[0] += 1
+                    ent[1] += secs
+                else:
+                    ph = self._phases.setdefault(ident, {})
+                    ph[phase] = ph.get(phase, 0.0) + secs
                 label = ("background" if ident in self._background
                          else "session")
             from ..metrics import metrics
 
+            if phase is not None:
+                metrics.solver_compile_phase_seconds_total.inc(
+                    secs, labels={"phase": phase, "thread": label})
+                return
             metrics.solver_compile_total.inc(labels={"thread": label})
             metrics.solver_compile_seconds_total.inc(
                 secs, labels={"thread": label})
@@ -192,6 +216,18 @@ class CompileWatcher:
                     c += n
                     s += secs
             return c, s
+
+    def session_phase_totals(self) -> Dict[str, float]:
+        """Seconds per ``COMPILE_PHASE_EVENTS`` phase, summed over all
+        non-background threads."""
+        out: Dict[str, float] = {}
+        with self._lock:
+            for ident, phases in self._phases.items():
+                if ident in self._background:
+                    continue
+                for phase, secs in phases.items():
+                    out[phase] = out.get(phase, 0.0) + secs
+        return out
 
 
 #: process-wide watcher; ``install()`` is called by the scheduler wiring,

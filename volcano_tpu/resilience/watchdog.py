@@ -28,6 +28,7 @@ the watchdog costs nothing unless asked for.
 
 from __future__ import annotations
 
+import contextvars
 import faulthandler
 import logging
 import sys
@@ -59,7 +60,10 @@ class ActionWatchdog:
             except BaseException as e:  # noqa: BLE001 — relayed to caller
                 box["exc"] = e
 
-        t = threading.Thread(target=runner, name=f"action-{name}",
+        # the worker runs in a copy of the caller's context, so the
+        # action's spans (metrics.spans) still add to the open turn record
+        t = threading.Thread(target=contextvars.copy_context().run,
+                             args=(runner,), name=f"action-{name}",
                              daemon=True)
         t.start()
         t.join(self.deadline_s)

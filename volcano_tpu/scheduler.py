@@ -17,7 +17,7 @@ from . import plugins as _plugins  # noqa: F401  (registers plugins)
 from .cache import SchedulerCache
 from .conf import DEFAULT_SCHEDULER_CONF, load_scheduler_conf
 from .framework import close_session, get_action, open_session
-from .metrics import metrics
+from .metrics import metrics, spans
 from .resilience import ActionTimeout
 
 log = logging.getLogger(__name__)
@@ -117,6 +117,7 @@ class Scheduler:
         if prewarm or self.compile_cache_dir:
             _pc.watcher.install()
         self._compile_totals = _pc.watcher.session_totals()
+        self._phase_totals = _pc.watcher.session_phase_totals()
         # last-exported delta-watch counter snapshot (client/remote.py
         # delta_stats accumulates forever; the registry counters get the
         # per-export increment)
@@ -200,62 +201,62 @@ class Scheduler:
             gc.collect(1)
 
     def _run_once_inner(self) -> None:
-        t0 = time.perf_counter()
-        self.load_conf()
-        ssn = open_session(self.cache, self.tiers, self.configurations)
-        ssn.node_sampler = self.node_sampler
-        timing = {}
-        t_open = time.perf_counter()
-        timing["open_ms"] = (t_open - t0) * 1e3
-        try:
-            for epoch, action in enumerate(self.actions):
-                ta = time.perf_counter()
-                name = action.name()
-                ssn._action_epoch = epoch
-                try:
-                    self._execute_action(ssn, action)
-                except ActionTimeout:
-                    # deadline breach: the watchdog already dumped stacks;
-                    # roll the abandoned action's statements back, fence
-                    # its epoch so a zombie commit becomes a discard, and
-                    # run the REMAINING actions of this cycle
-                    ssn._contained_epochs.add(epoch)
-                    n = ssn.discard_open_statements()
-                    timing[f"{name}_timeout"] = 1.0
-                    metrics.action_timeouts_total.inc(
-                        labels={"action": name})
-                    log.error("action %s exceeded its deadline; contained "
-                              "(%d open statement(s) discarded), running "
-                              "the remaining actions", name, n)
-                except Exception:
-                    # a throwing action is contained the same way: its
-                    # uncommitted statements discard and the cycle goes on
-                    # (the reference contains per-cycle errors identically
-                    # — one bad action must not starve backfill forever)
-                    n = ssn.discard_open_statements()
-                    timing[f"{name}_error"] = 1.0
-                    metrics.action_failures_total.inc(
-                        labels={"action": name})
-                    log.exception("action %s failed; contained (%d open "
-                                  "statement(s) discarded), running the "
-                                  "remaining actions", name, n)
-                dt = time.perf_counter() - ta
-                timing[f"{name}_ms"] = dt * 1e3
-                metrics.action_scheduling_latency.observe(
-                    dt * 1e6, labels={"action": name})
-            # the allocate action's internal decomposition when it ran in
-            # solver mode (flatten/solve/replay)
-            for k, v in (ssn.solver_options.get("timing") or {}).items():
-                timing[k] = v
-        finally:
-            tc = time.perf_counter()
-            close_session(ssn)
-            timing["close_ms"] = (time.perf_counter() - tc) * 1e3
-        total = (time.perf_counter() - t0) * 1e3
-        timing["total_ms"] = total
+        with spans.span("volcano.scheduler", "total_ms", root=True) as cycle:
+            timing = cycle.record
+            with spans.span("volcano.session.open", "open_ms"):
+                self.load_conf()
+                ssn = open_session(self.cache, self.tiers,
+                                   self.configurations)
+                ssn.node_sampler = self.node_sampler
+            try:
+                for epoch, action in enumerate(self.actions):
+                    name = action.name()
+                    with spans.span(f"volcano.action.{name}",
+                                    f"{name}_ms") as act:
+                        ssn._action_epoch = epoch
+                        self._run_action(ssn, action, name, epoch, timing)
+                    metrics.action_scheduling_latency.observe(
+                        act.ms * 1e3, labels={"action": name})
+                # the actions' own per-cycle figures (modes, counts, arena
+                # bytes) beside the spans
+                for k, v in (ssn.solver_options.get("timing") or {}).items():
+                    timing[k] = v
+            finally:
+                with spans.span("volcano.session.close"):
+                    close_session(ssn)
         self._export_pipeline_metrics(timing)
         self.last_cycle_timing = timing
-        metrics.e2e_scheduling_latency.observe(total)
+        metrics.e2e_scheduling_latency.observe(cycle.ms)
+
+    def _run_action(self, ssn, action, name: str, epoch: int,
+                    timing: dict) -> None:
+        """One action, contained: a deadline breach or an exception rolls
+        its open statements back and the cycle goes on."""
+        try:
+            self._execute_action(ssn, action)
+        except ActionTimeout:
+            # deadline breach: the watchdog already dumped stacks; roll the
+            # abandoned action's statements back, fence its epoch so a
+            # zombie commit becomes a discard, and run the REMAINING
+            # actions of this cycle
+            ssn._contained_epochs.add(epoch)
+            n = ssn.discard_open_statements()
+            timing[f"{name}_timeout"] = 1.0
+            metrics.action_timeouts_total.inc(labels={"action": name})
+            log.error("action %s exceeded its deadline; contained (%d open "
+                      "statement(s) discarded), running the remaining "
+                      "actions", name, n)
+        except Exception:
+            # a throwing action is contained the same way: its uncommitted
+            # statements discard and the cycle goes on (the reference
+            # contains per-cycle errors identically — one bad action must
+            # not starve backfill forever)
+            n = ssn.discard_open_statements()
+            timing[f"{name}_error"] = 1.0
+            metrics.action_failures_total.inc(labels={"action": name})
+            log.exception("action %s failed; contained (%d open "
+                          "statement(s) discarded), running the remaining "
+                          "actions", name, n)
 
     def _execute_action(self, ssn, action) -> None:
         """Run one action, inline or under the deadline watchdog; the
@@ -271,28 +272,17 @@ class Scheduler:
         else:
             self._watchdog.run(action.name(), run)
 
-    #: timing keys exported per cycle as the volcano_session_phase_ms
-    #: gauge — the flatten/upload/solve/replay decomposition the compile
-    #: pipeline work optimizes (upload = pack + delta_plan host share)
-    _PHASE_KEYS = ("open_ms", "flatten_ms", "pack_ms", "delta_plan_ms",
-                   "dispatch_ms", "overlap_ms", "readback_ms", "solve_ms",
-                   "replay_ms", "close_ms", "total_ms")
-
     def _export_pipeline_metrics(self, timing: dict) -> None:
-        """Surface per-phase latency and the cycle's compile accounting in
-        both the metrics registry and last_cycle_timing: a full-solve XLA
-        compile landing on the session thread is THE tail-latency event
-        this scheduler exists to avoid, so it must be first-class
-        observable, not a mystery spike in total_ms."""
-        for key in self._PHASE_KEYS:
-            if key in timing:
-                metrics.session_phase_ms.set(
-                    timing[key], labels={"phase": key[:-3]})
+        """Surface the cycle's compile accounting and the caches' per-cycle
+        figures in both the metrics registry and last_cycle_timing: a
+        full-solve XLA compile landing on the session thread is THE
+        tail-latency event this scheduler exists to avoid, so it must be
+        first-class observable, not a mystery spike in total_ms."""
         # event-sourced flatten accounting (ops.arrays FlattenCache
         # ledger): which assembly path this cycle took, how many rows the
         # event patch touched, the patch-vs-full latency split, and the
         # fallback ladder's reason counters — exported alongside the
-        # per-phase gauges because a cycle silently degrading from
+        # spans because a cycle silently degrading from
         # O(events) to O(cluster) is exactly the regression these exist
         # to catch
         fc = getattr(self.cache, "flatten_cache", None)
@@ -373,6 +363,12 @@ class Scheduler:
         self._compile_totals = (c, s)
         timing["session_compiles"] = float(c - prev_c)
         timing["session_compile_s"] = s - prev_s
+        # the rest of a first dispatch: tracing, lowering, cache loads
+        phases = watcher.session_phase_totals()
+        for phase, secs in phases.items():
+            timing[f"session_{phase}_s"] = \
+                secs - self._phase_totals.get(phase, 0.0)
+        self._phase_totals = phases
         timing["compile_cache_hits"] = float(watcher.cache_hits)
         # device-resident arena accounting (ops.device_cache), exported
         # PER SOLVER MODE: a sharded session's wire bytes land on the
@@ -503,6 +499,7 @@ class Scheduler:
             # warm-up did its job (the failover bench's assertion)
             from .ops.precompile import watcher
             self._compile_totals = watcher.session_totals()
+            self._phase_totals = watcher.session_phase_totals()
 
     def run_with_leader_election(self, stop, lock_name: str = "volcano",
                                  identity: Optional[str] = None,

@@ -27,6 +27,8 @@ import threading
 import time
 from typing import Optional
 
+from .metrics import spans
+
 log = logging.getLogger(__name__)
 
 
@@ -352,11 +354,15 @@ class Standalone:
         rec = self.sim_recorder
         if rec is not None:
             rec.begin_cycle(self._turn)
-        self.controllers.process_all()
-        self.scheduler.run_once()
-        self.controllers.process_all()
-        if drain_effects:
-            self.cache.wait_for_effects()
+        # one turn record: the scheduler's cycle joins it, so
+        # last_cycle_timing ends up holding the control plane's spans too
+        with spans.span("volcano.turn", root=True, turn=self._turn):
+            self.controllers.process_all()
+            self.scheduler.run_once()
+            self.controllers.process_all()
+            if drain_effects:
+                with spans.span("volcano.effects"):
+                    self.cache.wait_for_effects()
         if rec is not None:
             rec.end_cycle(self.scheduler.last_cycle_timing)
         self._turn += 1
