@@ -1,5 +1,6 @@
 """Session/Statement/tier-dispatch tests."""
 
+import numpy as np
 import pytest
 
 from volcano_tpu.api import TaskStatus
@@ -210,6 +211,77 @@ class TestVictimDispatch:
         tasks = list(ssn.jobs["ns1/pg1"].tasks.values())
         victims = ssn.preemptable(tasks[0], tasks)
         assert [v.name for v in victims] == ["p2"]
+
+
+class _PickVictims(Plugin):
+    """Preemptable fn keeping the victims named in ``picks`` (None: no
+    fn), with its column form registered when ``masked``."""
+
+    def __init__(self, name, picks, masked):
+        self._name, self._picks, self._masked = name, picks, masked
+
+    def name(self):
+        return self._name
+
+    def on_session_open(self, ssn):
+        if self._picks is None:
+            return
+        picks = self._picks
+        ssn.add_preemptable_fn(self._name, lambda preemptor, preemptees: [
+            t for t in preemptees if t.name in picks])
+        if self._masked:
+            ssn.add_victim_mask_fn(
+                "preemptable_fns", self._name,
+                lambda claimers, victims: np.array(
+                    [[v.name in picks for v in victims]] * len(claimers),
+                    dtype=bool).reshape(len(claimers), len(victims)))
+
+    def on_session_close(self, ssn):
+        pass
+
+
+class TestVictimMasks:
+    """Session.victim_masks keeps _victims_dispatch's tier rules, each
+    case with one provider that has no mask form."""
+
+    CAND = np.array([[True, True, True],
+                     [False, True, True]])
+
+    def _masks_and_dispatch(self, tiers):
+        for tier in tiers:
+            for name, picks, masked in tier:
+                register_plugin_builder(
+                    name, lambda a, n=name, p=picks, m=masked:
+                    _PickVictims(n, p, m))
+        store, cache, ssn = make_session(
+            [Tier(plugins=[PluginOption(name=n) for n, _, _ in tier])
+             for tier in tiers], pods=3, min_member=1)
+        tasks = sorted(ssn.jobs["ns1/pg1"].tasks.values(),
+                       key=lambda t: t.name)
+        claimers = tasks[:2]
+        elig = ssn.victim_masks("preemptable_fns", claimers, tasks,
+                                self.CAND)
+        for j, claimer in enumerate(claimers):
+            cands = [t for t, c in zip(tasks, self.CAND[j]) if c]
+            allowed = {v.name for v in ssn.preemptable(claimer, cands)}
+            assert list(elig[j]) == [t.name in allowed for t in tasks]
+        return [[t.name for t, e in zip(tasks, row) if e] for row in elig]
+
+    def test_intersection_within_tier(self):
+        rows = self._masks_and_dispatch([[("m01", {"p0", "p1"}, True),
+                                          ("f1", {"p1"}, False)]])
+        assert rows == [["p1"], ["p1"]]
+
+    def test_empty_tier_result_poisons_later_tiers(self):
+        rows = self._masks_and_dispatch([[("mnone", set(), True)],
+                                         [("fp2", {"p2"}, False)]])
+        assert rows == [[], []]
+
+    def test_tier_without_fns_falls_through(self):
+        rows = self._masks_and_dispatch([[("msilent", None, True)],
+                                         [("m12", {"p1", "p2"}, True),
+                                          ("fp02", {"p0", "p2"}, False)]])
+        assert rows == [["p2"], ["p2"]]
 
 
 class TestStatement:

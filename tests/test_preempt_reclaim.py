@@ -531,3 +531,152 @@ class TestHierarchicalReclaim:
         # -> the comparator refuses; nothing is evicted
         assert cache.evictor.evicts == [], cache.evictor.evicts
         close_session(ssn)
+
+
+def _tiers(*groups):
+    return [Tier(plugins=[PluginOption(name=n) for n in g]) for g in groups]
+
+
+#: (mode, tiers): the e2e preempt conf, the default conf and conformance
+#: deciding alone (fully masked), drf in the deciding tier (mixed: drf's
+#: fn is called per claimer), and reclaim masked and mixed (proportion
+#: deciding)
+VICTIM_CONFS = {
+    "preempt_conf": ("preempt", _tiers(
+        ["priority", "gang", "conformance"],
+        ["predicates", "proportion", "nodeorder"])),
+    "default_conf": ("preempt", _tiers(
+        ["priority", "gang"],
+        ["drf", "predicates", "proportion", "nodeorder"])),
+    "conformance_first": ("preempt", _tiers(
+        ["conformance"], ["priority", "gang"])),
+    "drf_first": ("preempt", _tiers(
+        ["drf", "priority", "gang", "conformance"],
+        ["predicates", "nodeorder"])),
+    "reclaim": ("reclaim", _tiers(
+        ["priority", "gang", "conformance"],
+        ["drf", "predicates", "proportion", "nodeorder"])),
+    "reclaim_proportion_first": ("reclaim", _tiers(
+        ["proportion", "gang", "conformance"],
+        ["predicates", "nodeorder"])),
+}
+
+
+def _random_victim_session(seed, tiers):
+    """Four queues (q3 not reclaimable, q-lonely holding one claimer and
+    no running pod), jobs of three priorities with running pods spread
+    over six nodes, kube-system and system-node-critical victims, and
+    claimer jobs with pending pods; q1's "big" claimer holds more than any
+    low job, so drf refuses it the low jobs that priority allows, and a
+    gang of nine it is still a preempt claimer beside its own pods."""
+    import random
+
+    rng = random.Random(seed)
+    classes = [PriorityClass("low", 1), PriorityClass("mid", 10),
+               PriorityClass("high", 100)]
+    queues = [build_queue("q1"), build_queue("q2"),
+              build_queue("q3", reclaimable=False), build_queue("q-lonely")]
+    nodes = [build_node(f"n{i}", {"cpu": "16", "memory": "64Gi"})
+             for i in range(6)]
+    pgs, pods = [], []
+    slot = 0
+
+    def job(name, ns, queue, running, pending, pclass="", pod_class="",
+            min_member=None):
+        nonlocal slot
+        pg = build_pod_group(name, ns, queue=queue,
+                             min_member=min_member or rng.randint(1, 2))
+        pg.spec.priority_class_name = pclass
+        pgs.append(pg)
+        for k in range(running + pending):
+            node = ""
+            if k < running:
+                node, slot = f"n{slot % 6}", slot + 1
+            pod = build_pod(ns, f"{name}-{k}", node,
+                            "Running" if node else "Pending",
+                            {"cpu": "1", "memory": "1Gi"}, name)
+            pod.priority_class_name = pod_class
+            pods.append(pod)
+
+    for q in ("q1", "q2", "q3"):
+        for i in range(rng.randint(2, 3)):
+            job(f"{q}-j{i}", "c1", q, running=rng.randint(1, 4),
+                pending=rng.choice([0, 0, 1, 2]),
+                pclass=rng.choice(["low", "mid", "high"]))
+    job("big", "c1", "q1", running=8, pending=1, pclass="high",
+        min_member=9)
+    job("sys", "kube-system", "q1", running=2, pending=0, pclass="low")
+    job("crit", "c1", "q2", running=2, pending=0, pclass="low",
+        pod_class="system-node-critical")
+    job("lonely", "c1", "q-lonely", running=0, pending=2, pclass="high")
+    store, cache = make_cluster(nodes, pgs, pods, queues=queues,
+                                priority_classes=classes)
+    return cache, open_session(cache, tiers)
+
+
+def _reference_victim_arrays(ssn, victims, job_order, mode):
+    """Eligibility rows and needs from one ssn.preemptable /
+    ssn.reclaimable call per claimer job over its queue-scoped list."""
+    rows, need = [], []
+    for job, tasks in job_order:
+        if mode == "preempt":
+            cands = [t for t in victims
+                     if ssn.jobs[t.job].queue == job.queue
+                     and t.job != job.uid]
+            allowed = {v.uid for v in ssn.preemptable(tasks[0], cands)}
+            need.append(max(0, job.min_available
+                            - (job.ready_task_num()
+                               + job.waiting_task_num())))
+        else:
+            cands = []
+            for t in victims:
+                vq = ssn.queues.get(ssn.jobs[t.job].queue)
+                if (ssn.jobs[t.job].queue != job.queue
+                        and vq is not None and vq.reclaimable):
+                    cands.append(t)
+            allowed = {v.uid for v in ssn.reclaimable(tasks[0], cands)}
+            need.append(len(tasks))
+        rows.append([t.uid in allowed for t in victims])
+    return rows, need
+
+
+@pytest.mark.parametrize("seed", [3, 17, 2024])
+@pytest.mark.parametrize("conf", sorted(VICTIM_CONFS))
+def test_victim_arrays_match_per_claimer_verdicts(conf, seed):
+    """build_victim_arrays' column-mask eligibility equals, entry for
+    entry, the per-claimer plugin dispatch over the same candidates."""
+    import numpy as np
+
+    from volcano_tpu.actions.evict_solver import (
+        build_victim_arrays, collect_claimer_jobs, collect_victims)
+    from volcano_tpu.ops import flatten_snapshot
+
+    mode, tiers = VICTIM_CONFS[conf]
+    cache, ssn = _random_victim_session(seed, tiers)
+    preempt = mode == "preempt"
+    job_order = collect_claimer_jobs(ssn, require_not_pipelined=preempt,
+                                     skip_overused=not preempt)
+    assert job_order
+    arr = flatten_snapshot(
+        {j.uid: j for j, _ in job_order}, ssn.nodes,
+        [t for _, tasks in job_order for t in tasks], queues=ssn.queues,
+        grouped=job_order)
+    victims = collect_victims(ssn, arr.nodes_list)
+    assert any(t.pod.namespace == "kube-system" for t in victims)
+    assert any(t.pod.priority_class_name == "system-node-critical"
+               for t in victims)
+    out = build_victim_arrays(ssn, arr, victims, job_order, mode)
+    rows, need = _reference_victim_arrays(ssn, victims, job_order, mode)
+    nj, n = len(job_order), len(victims)
+    elig = out["elig"]
+    assert elig.dtype == bool and elig.shape == (arr.job_min.shape[0],
+                                                 out["v_valid"].shape[0])
+    np.testing.assert_array_equal(elig[:nj, :n], np.array(rows, dtype=bool))
+    assert not elig[nj:].any() and not elig[:, n:].any()
+    np.testing.assert_array_equal(out["job_need"][:nj], need)
+    assert not out["job_need"][nj:].any()
+    if preempt:
+        lonely = [j for j, (job, _) in enumerate(job_order)
+                  if job.queue == "q-lonely"]
+        assert lonely and not elig[lonely[0]].any()
+    close_session(ssn)

@@ -173,7 +173,8 @@ SPANS = (
                  "intra_job")])
 COUNTERS = ("pods_created", "binds_written", "evictions_written",
             "solve_rounds", "evict_scan_steps", "evict_claimers",
-            "pod_wait_ms_sum", "pod_wait_n")
+            "victim_rows", "victim_rows_masked", "pod_wait_ms_sum",
+            "pod_wait_n")
 LEGACY = ("open_ms", "order_ms", "flatten_ms", "dispatch_ms", "readback_ms",
           "replay_ms", "solve_ms", "preempt_ms", "preempt_solve_ms",
           "total_ms")
@@ -264,6 +265,49 @@ def test_counters_count_the_work(preemption):
     assert total("pod_wait_ms_sum") > 0
     assert total("solve_rounds") >= 1
     assert 0 < total("evict_claimers") <= total("evict_scan_steps")
+
+
+def test_every_victim_row_is_masked_under_the_preempt_conf(preemption):
+    rows = sum(rec.get("victim_rows", 0.0) for rec in preemption)
+    masked = sum(rec.get("victim_rows_masked", 0.0) for rec in preemption)
+    assert rows > 0 and masked == rows
+
+
+def test_no_victim_row_is_masked_with_drf_deciding():
+    """drf has no mask form: with it in the deciding tier each claimer's
+    row takes a per-claimer call."""
+    from helpers import build_node, build_pod, build_pod_group
+    from volcano_tpu.actions.preempt import PreemptAction
+    from volcano_tpu.cache import FakeBinder, FakeEvictor, SchedulerCache
+    from volcano_tpu.client import ClusterStore
+    from volcano_tpu.conf import PluginOption, Tier
+    from volcano_tpu.framework import close_session, open_session
+    from volcano_tpu.models import PriorityClass
+
+    store = ClusterStore()
+    cache = SchedulerCache(store)
+    cache.binder, cache.evictor = FakeBinder(), FakeEvictor()
+    cache.run()
+    store.create("priorityclasses", PriorityClass("high", 10))
+    store.create("nodes", build_node("n1", {"cpu": "2", "memory": "4Gi"}))
+    high = build_pod_group("high", "c1")
+    high.spec.priority_class_name = "high"
+    for pg in (build_pod_group("low", "c1"), high):
+        store.create("podgroups", pg)
+    for name, node, phase in (("low-0", "n1", "Running"),
+                              ("low-1", "n1", "Running"),
+                              ("high-0", "", "Pending")):
+        store.create("pods", build_pod("c1", name, node, phase, {
+            "cpu": "1", "memory": "1Gi"}, name.split("-")[0]))
+    ssn = open_session(cache, [Tier(plugins=[PluginOption(name=n) for n in (
+        "drf", "priority", "gang", "conformance")])])
+    try:
+        with spans.span("t.preempt", root=True) as sp:
+            PreemptAction().execute(ssn)
+    finally:
+        close_session(ssn)
+    assert sp.record["victim_rows"] == 1.0
+    assert sp.record["victim_rows_masked"] == 0.0
 
 
 def test_preempt_children_cover_the_action(preemption):

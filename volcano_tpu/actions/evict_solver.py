@@ -111,43 +111,60 @@ def build_victim_arrays(ssn, arr, victims, job_order, mode: str) -> Dict:
     Eligibility = queue scoping (same queue & different job for preempt;
     other reclaimable queues for reclaim) intersected with the session's
     tiered Preemptable/Reclaimable verdicts, evaluated once per claimer job
-    (the plugin fns read the claimer's job, not the individual task)."""
+    (the plugin fns read the claimer's job, not the individual task) and
+    built as column compares (Session.victim_masks)."""
     from ..ops.arrays import bucket
 
     node_index = {n.name: i for i, n in enumerate(arr.nodes_list)}
     R = arr.R
     J = arr.job_min.shape[0]
-    V = bucket(max(len(victims), 1))
+    n = len(victims)
+    V = bucket(max(n, 1))
     v_req = np.zeros((V, R), dtype=np.float32)
     v_node = np.zeros(V, dtype=np.int32)
     v_valid = np.zeros(V, dtype=bool)
     for i, t in enumerate(victims):
         v_req[i] = t.resreq.to_vector(arr.vocab)
-        v_node[i] = node_index[t.node_name]
-        v_valid[i] = True
+    v_node[:n] = [node_index[t.node_name] for t in victims]
+    v_valid[:n] = True
 
-    elig = np.zeros((J, V), dtype=bool)
+    # queue scoping as [claimer, victim] compares of interned codes
+    v_jobs = [ssn.jobs[t.job] for t in victims]
+    queue_code: Dict[str, int] = {}
+    v_queue = np.array([queue_code.setdefault(j.queue, len(queue_code))
+                        for j in v_jobs], dtype=np.int32)
+    c_queue = np.array([queue_code.get(job.queue, -1)
+                        for job, _ in job_order], dtype=np.int32)
+    same_queue = c_queue[:, None] == v_queue[None, :]
     need = np.zeros(J, dtype=np.int32)
-    for j, (job, tasks) in enumerate(job_order):
-        if mode == "preempt":
-            cands = [t for t in victims
-                     if ssn.jobs[t.job].queue == job.queue
-                     and t.job != job.uid]
-            allowed = {v.uid for v in ssn.preemptable(tasks[0], cands)}
+    if mode == "preempt":
+        job_code: Dict[str, int] = {}
+        v_job = np.array([job_code.setdefault(j.uid, len(job_code))
+                          for j in v_jobs], dtype=np.int32)
+        c_job = np.array([job_code.get(job.uid, -1)
+                          for job, _ in job_order], dtype=np.int32)
+        cand = same_queue & (c_job[:, None] != v_job[None, :])
+        registry = "preemptable_fns"
+        for j, (job, _tasks) in enumerate(job_order):
             # pipelines still needed for JobPipelined (job_info.go:373-377)
             need[j] = max(0, job.min_available
                           - (job.ready_task_num() + job.waiting_task_num()))
-        else:
-            cands = []
-            for t in victims:
-                vq = ssn.queues.get(ssn.jobs[t.job].queue)
-                if (ssn.jobs[t.job].queue != job.queue
-                        and vq is not None and vq.reclaimable):
-                    cands.append(t)
-            allowed = {v.uid for v in ssn.reclaimable(tasks[0], cands)}
+    else:
+        def reclaimable(name):
+            q = ssn.queues.get(name)
+            return q is not None and q.reclaimable
+
+        # queue_code's insertion order is its codes' order
+        q_reclaimable = np.array([reclaimable(name) for name in queue_code],
+                                 dtype=bool)
+        cand = ~same_queue & q_reclaimable[v_queue][None, :]
+        registry = "reclaimable_fns"
+        for j, (_job, tasks) in enumerate(job_order):
             need[j] = len(tasks)  # uncapped (reclaim has no gang stop)
-        for i, t in enumerate(victims):
-            elig[j, i] = t.uid in allowed
+
+    elig = np.zeros((J, V), dtype=bool)
+    elig[:len(job_order), :n] = ssn.victim_masks(
+        registry, [tasks[0] for _, tasks in job_order], victims, cand)
     return {"v_req": v_req, "v_node": v_node, "v_valid": v_valid,
             "elig": elig, "job_need": need}
 

@@ -17,10 +17,13 @@ import logging
 import uuid
 from typing import Callable, Dict, List, Optional
 
+import numpy as np
+
 from ..api import (
     ClusterInfo, JobInfo, NodeInfo, QueueInfo, Resource, TaskInfo,
     TaskStatus, allocated_status,
 )
+from ..metrics.spans import count
 from ..models import PodGroupPhase
 from .event import Event, EventHandler
 from .interface import ValidateResult
@@ -105,6 +108,11 @@ class Session:
         # cached keys of UNCHANGED items went stale (drf: cluster total;
         # priority: the priority-class table)
         self.order_key_context_fns: Dict[str, Dict[str, Callable]] = {}
+        # optional per-plugin column forms of the victim fns
+        # (add_victim_mask_fn): the solver-mode victim preparation builds
+        # its [claimer, victim] eligibility matrix from these instead of
+        # one plugin call per claimer
+        self.victim_mask_fns: Dict[str, Dict[str, Callable]] = {}
 
         # TPU seam: plugins contribute scalar weights for the on-device
         # scoring families here instead of per-(task,node) callbacks; the
@@ -186,6 +194,21 @@ class Session:
         whenever that state changes. The OrderCache compares contexts
         every cycle and falls back to the full sort when any moved."""
         self.order_key_context_fns.setdefault(registry, {})[name] = fn
+
+    def add_victim_mask_fn(self, registry: str, name: str,
+                           fn: Callable) -> None:
+        """Register the column form of plugin ``name``'s victim fn in
+        ``registry`` ("preemptable_fns" or "reclaimable_fns"):
+        fn(claimers, victims) -> bool numpy array [len(claimers),
+        len(victims)] whose row j equals, victim for victim, the plugin's
+        own fn(claimers[j], L) for any list L of those victims.
+
+        Only an ELEMENTWISE verdict has such a form: one in which a
+        victim's inclusion depends on the claimer and that victim alone,
+        never on the other members of the list. A verdict that
+        accumulates along the list (drf's shares, proportion's
+        reclaimable) registers none and is called per claimer."""
+        self.victim_mask_fns.setdefault(registry, {})[name] = fn
 
     def composite_order_key(self, registry: str) -> Optional[Callable]:
         """A key(item) -> tuple covering every active provider of
@@ -348,6 +371,44 @@ class Session:
 
     def reclaimable(self, reclaimer: TaskInfo, reclaimees: List[TaskInfo]):
         return self._victims_dispatch("reclaimable_fns", reclaimer, reclaimees)
+
+    def victim_masks(self, registry: str, claimers: List[TaskInfo],
+                     victims: List[TaskInfo], cand: np.ndarray) -> np.ndarray:
+        """``_victims_dispatch`` for many claimers at once, as a bool
+        [len(claimers), len(victims)] matrix: row j holds
+        _victims_dispatch(registry, claimers[j], L_j), where L_j lists the
+        victims whose ``cand[j]`` is True, in victim order.
+
+        The first tier with providers decides: an empty intersection
+        there stays empty through every later tier. Within it the mask of
+        each provider that registered one (add_victim_mask_fn) is ANDed
+        in; a provider without one is called per claimer on L_j. Counts
+        the rows built (``victim_rows``) and those that no per-claimer
+        call decided (``victim_rows_masked``)."""
+        elig = np.array(cand, dtype=bool)
+        tiers = _group_by_tier(self._tier_fns(registry))
+        maskless = []
+        if not tiers:
+            elig[:] = False
+        else:
+            masks = self.victim_mask_fns.get(registry, {})
+            for _, name, fn in tiers[0][1]:
+                mask_fn = masks.get(name)
+                if mask_fn is None:
+                    maskless.append(fn)
+                else:
+                    elig &= mask_fn(claimers, victims)
+        for j in range(len(claimers) if maskless else 0):
+            # elig[j] is already False outside L_j
+            idx = np.flatnonzero(cand[j])
+            cands = [victims[i] for i in idx]
+            for fn in maskless:
+                allowed = {v.uid for v in fn(claimers[j], cands)}
+                elig[j, idx] &= np.array([v.uid in allowed for v in cands],
+                                         dtype=bool)
+        count("victim_rows", len(claimers))
+        count("victim_rows_masked", 0 if maskless else len(claimers))
+        return elig
 
     def overused(self, queue: QueueInfo) -> bool:
         return any(fn(queue) for _, _, fn in self._tier_fns("overused_fns"))

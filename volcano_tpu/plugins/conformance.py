@@ -5,6 +5,8 @@ Never evict system-critical pods or anything in kube-system.
 
 from __future__ import annotations
 
+import numpy as np
+
 from ..framework import Plugin
 
 _CRITICAL_PRIORITY_CLASSES = ("system-cluster-critical", "system-node-critical")
@@ -32,6 +34,13 @@ class ConformancePlugin(Plugin):
 
         ssn.add_preemptable_fn(self.name(), evictable_fn)
         ssn.add_reclaimable_fn(self.name(), evictable_fn)
+
+        def victim_mask_fn(evictors, evictees):
+            col = np.array([_evictable(t) for t in evictees], dtype=bool)
+            return np.broadcast_to(col, (len(evictors), len(evictees)))
+
+        ssn.add_victim_mask_fn("preemptable_fns", self.name(), victim_mask_fn)
+        ssn.add_victim_mask_fn("reclaimable_fns", self.name(), victim_mask_fn)
 
     def on_session_close(self, ssn) -> None:
         pass

@@ -11,6 +11,7 @@ from ..models import (
     POD_GROUP_UNSCHEDULABLE_TYPE, PodGroupCondition,
 )
 from ..api.unschedule_info import FitErrors
+from .priority import lower_priority_mask
 
 
 class GangPlugin(Plugin):
@@ -45,6 +46,12 @@ class GangPlugin(Plugin):
 
         ssn.add_preemptable_fn(self.name(), preemptable_fn)
         ssn.add_reclaimable_fn(self.name(), preemptable_fn)
+
+        def victim_mask_fn(claimers, victims):
+            return lower_priority_mask(ssn, claimers, victims)
+
+        ssn.add_victim_mask_fn("preemptable_fns", self.name(), victim_mask_fn)
+        ssn.add_victim_mask_fn("reclaimable_fns", self.name(), victim_mask_fn)
 
         def job_order_fn(l, r):
             """Unready jobs sort first."""

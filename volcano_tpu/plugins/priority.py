@@ -2,7 +2,22 @@
 
 from __future__ import annotations
 
+import numpy as np
+
 from ..framework import Plugin
+
+
+def lower_priority_mask(ssn, claimers, victims) -> np.ndarray:
+    """[claimer, victim] bool: the victim's job has a strictly lower
+    priority than the claimer's job, False where either job is unknown to
+    the session (the column form of the priority and gang victim fns)."""
+    def job_priority(tasks):
+        jobs = [ssn.jobs.get(t.job) for t in tasks]
+        return np.array([np.nan if j is None else j.priority for j in jobs],
+                        dtype=np.float64)
+
+    # a NaN (unknown job) compares False either way
+    return job_priority(claimers)[:, None] > job_priority(victims)[None, :]
 
 
 class PriorityPlugin(Plugin):
@@ -60,6 +75,10 @@ class PriorityPlugin(Plugin):
             return victims
 
         ssn.add_preemptable_fn(self.name(), preemptable_fn)
+        ssn.add_victim_mask_fn(
+            "preemptable_fns", self.name(),
+            lambda claimers, victims: lower_priority_mask(
+                ssn, claimers, victims))
 
     def on_session_close(self, ssn) -> None:
         pass
