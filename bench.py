@@ -708,8 +708,8 @@ def full_cycle():
 
 def dict_timing(sched):
     t = getattr(sched, "last_cycle_timing", None)
-    # timing carries non-numeric diagnostics too (arena_mode str,
-    # arena_shard_bytes list) — round only the scalars
+    # timing carries non-numeric diagnostics too (arena_mode str) —
+    # round only the scalars
     return {k: (round(v, 2) if isinstance(v, (int, float)) else v)
             for k, v in (t or {}).items()}
 

@@ -628,6 +628,34 @@ class TestShardedArenaEntry:
         got = [d for d, b in enumerate(sdc.last_shard_bytes) if b]
         assert got == [victim_shard], sdc.last_shard_bytes
 
+    def test_full_ship_warms_every_delta_scatter(self, mesh, monkeypatch):
+        """After a full ship, a delta of any dirty-chunk count compiles
+        nothing on the session's thread, and the warm leaves the resident
+        buffers as the host mirror has them."""
+        from volcano_tpu.ops import ShardedDeviceCache, device_cache
+        from volcano_tpu.ops.precompile import watcher
+
+        monkeypatch.setattr(device_cache, "_APPLY_KEEP", None)  # cold jit
+        watcher.install()
+        arr = self._problem()
+        fbuf, ibuf, layout = arr.packed()
+        sdc = ShardedDeviceCache(mesh)
+        sdc.update(fbuf, ibuf, layout)
+        before = watcher.counts()[0]
+        rng = np.random.default_rng(0)
+        for m in (1, 5, fbuf.size):
+            f2, i2 = fbuf.copy(), ibuf.copy()
+            f2[rng.choice(fbuf.size, m)] += 1.0
+            i2[rng.choice(ibuf.size, min(m, ibuf.size))] += 1
+            sdc.update(f2, i2, layout)
+            assert not sdc.last_full_ship and sdc.last_shipped_bytes
+        assert watcher.counts()[0] == before
+        np.testing.assert_array_equal(
+            np.asarray(sdc._dev_rep_f).ravel(), sdc._host_rep_f)
+        for d in range(sdc.D):
+            np.testing.assert_array_equal(
+                np.asarray(sdc._dev_node_i[d]).ravel(), sdc._host_node_i[d])
+
     def test_invalidate_keeps_params_then_full_reships(self, mesh):
         from volcano_tpu.ops import ShardedDeviceCache
 

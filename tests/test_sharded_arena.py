@@ -236,8 +236,9 @@ class TestPerModeArenaAccounting:
         t = sched.last_cycle_timing
         assert t.get("arena_mode") == "sharded"
         assert "arena_bytes_shipped" in t
-        assert "arena_shard_bytes" in t \
-            and len(t["arena_shard_bytes"]) == 8
+        assert t["mesh_devices"] == 8
+        assert t["shard_bytes_max"] == max(
+            cache.sharded_device_cache.last_shard_bytes)
         # packed arena untouched by sharded cycles
         assert cache.device_cache.sessions == 0
         sdc = cache.sharded_device_cache

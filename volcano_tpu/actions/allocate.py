@@ -346,6 +346,7 @@ class AllocateAction(Action):
             # which arena a device fault must invalidate: the packed cache by
             # default, the sharded arena when this session dispatched there
             fault_dc = dc
+            rounds = None  # the sharded solve's round count, read back
             try:
                 # device-path circuit-breaker scope: anything that throws out
                 # of the dispatch (XLA runtime error, OOM, dead sidecar, an
@@ -399,8 +400,9 @@ class AllocateAction(Action):
                     timing["arena_bytes_shipped"] = \
                         float(sdc.last_shipped_bytes)
                     timing["arena_full_ship"] = float(sdc.last_full_ship)
-                    timing["arena_shard_bytes"] = \
-                        [float(b) for b in sdc.last_shard_bytes]
+                    count("mesh_devices", sdc.D)
+                    count("shard_bytes_max", max(sdc.last_shard_bytes))
+                    count("shard_bytes_total", sum(sdc.last_shard_bytes))
                     pw = getattr(ssn, "prewarmer", None)
                     if pw is not None and pw.mesh is None:
                         # sharded sessions must pre-warm (and persistent-
@@ -423,13 +425,16 @@ class AllocateAction(Action):
                                 score_families=families,
                                 use_queue_cap=use_queue_cap,
                                 use_drf_order=use_drf_order,
-                                use_hdrf_order=use_hdrf_order),
+                                use_hdrf_order=use_hdrf_order,
+                                work_conserving=work_conserving),
                             what="sharded solver dispatch")
                     # the sharded kernel produces no compact readback:
-                    # assigned/kind stay DEVICE futures here and collect in
-                    # the res-is-None branch below, after the overlap window
+                    # assigned/kind/rounds stay DEVICE futures here and
+                    # collect in the res-is-None branch below, after the
+                    # overlap window
                     assigned = r.assigned
                     kind = r.kind
+                    rounds = r.rounds
                     res = None
                 elif sidecar is not None:
                     # process boundary: ship the packed snapshot to the solver
@@ -543,7 +548,7 @@ class AllocateAction(Action):
                         start_readback(res.compact, res.assigned, res.kind,
                                        res.rounds)
                     else:
-                        start_readback(assigned, kind)
+                        start_readback(assigned, kind, rounds)
                     node_names = [n.name for n in arr.nodes_list]
                     # Statement construction is pure (no session registration
                     # until ops are recorded), so the replay's per-job
@@ -609,6 +614,8 @@ class AllocateAction(Action):
                         self._check_solver_output(assigned, kind,
                                                   len(tasks_in_order),
                                                   len(arr.nodes_list))
+                        if rounds is not None:
+                            count("solve_rounds", int(rounds))
                 except Exception:
                     log.exception("sharded/sidecar solver output failed "
                                   "validation; falling back to the host loop")

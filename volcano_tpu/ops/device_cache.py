@@ -32,9 +32,12 @@ bench's bytes-shipped-per-session artifact fields.
 
 from __future__ import annotations
 
+import logging
 from typing import Optional, Tuple
 
 import numpy as np
+
+log = logging.getLogger(__name__)
 
 
 def _pow2_bucket(n: int) -> int:
@@ -63,12 +66,18 @@ def _scatter_keep(dev, idx, vals):
     """Non-donating chunk scatter: the sharded arena's per-device shard
     buffers are aliased by the previously assembled global array (an
     in-flight pipelined solve may still read it), so donation would
-    poison a live session's inputs."""
+    poison a live session's inputs. ``dev`` is a replicated [C, chunk]
+    buffer or a per-device [1, C, chunk] slab; ``idx`` indexes its chunk
+    axis and ``vals`` is [k, chunk] either way."""
+    return _keep_fn()(dev, idx, vals)
+
+
+def _keep_fn():
     global _APPLY_KEEP
     if _APPLY_KEEP is None:
         import jax
-        _APPLY_KEEP = jax.jit(lambda d, i, v: d.at[i].set(v))
-    return _APPLY_KEEP(dev, idx, vals)
+        _APPLY_KEEP = jax.jit(lambda d, i, v: d.at[..., i, :].set(v))
+    return _APPLY_KEEP
 
 
 class PackedDeviceCache:
@@ -510,7 +519,10 @@ class ShardedDeviceCache(PackedDeviceCache):
       host ships each dirty chunk once and the runtime fans it out;
     - **score params** are pinned with the solver's shardings
       (node_static split along 'n', scalars replicated), re-validated in
-      place after a collect failure exactly like the packed arena.
+      place after a collect failure exactly like the packed arena;
+    - **the delta scatter** is compiled for every chunk-count bucket of
+      every resident buffer on a background thread after each full ship,
+      so no delta session compiles one.
 
     ``update(fbuf, ibuf, layout)`` -> ``(f_rep, i_rep, f_node, i_node,
     rep_layout, node_layout)``: the six dispatch inputs of
@@ -536,6 +548,7 @@ class ShardedDeviceCache(PackedDeviceCache):
         #: wire bytes shipped to each shard by the last session (node
         #: slices + this shard's copy of the replicated delta)
         self.last_shard_bytes = [0] * self.D
+        self._warm = None  # the scatter warm thread of the last full ship
 
     # -- placement helpers ---------------------------------------------
 
@@ -617,6 +630,7 @@ class ShardedDeviceCache(PackedDeviceCache):
         import jax
 
         c, D = self.chunk, self.D
+        self._join_warm()
         if self._layout != layout or self._rep_layout is None:
             rep_layout, node_layout = split_packed_layout(layout, D)
         else:
@@ -658,6 +672,7 @@ class ShardedDeviceCache(PackedDeviceCache):
                 for d in range(D)]
             self._account(crf + cri + D * (cnf + cni),
                           rep_bytes + hnf.nbytes + hni.nbytes, full=True)
+            self._start_scatter_warm()
             return self._assembled(rep_layout, node_layout)
 
         # delta path: diff the split mirrors chunk-wise
@@ -691,12 +706,10 @@ class ShardedDeviceCache(PackedDeviceCache):
                              .any(axis=1))[0]
             if dnf.size:
                 self._dev_node_f[d] = self._apply_keep(
-                    self._dev_node_f[d], dnf, snf[d].reshape(cnf, c),
-                    leading=True)
+                    self._dev_node_f[d], dnf, snf[d].reshape(cnf, c))
             if dni.size:
                 self._dev_node_i[d] = self._apply_keep(
-                    self._dev_node_i[d], dni, sni[d].reshape(cni, c),
-                    leading=True)
+                    self._dev_node_i[d], dni, sni[d].reshape(cni, c))
             chunks += dnf.size + dni.size
             shard_bytes[d] = self._scatter_wire_bytes(dnf, dni)
         if chunks:
@@ -714,16 +727,62 @@ class ShardedDeviceCache(PackedDeviceCache):
         return self._assembled(self._rep_layout, self._node_layout)[2:4]
 
     @staticmethod
-    def _apply_keep(dev, idx, host2d, leading: bool = False):
+    def _apply_keep(dev, idx, host2d):
         """Non-donating dirty-chunk scatter (see _scatter_keep); executes
         on the committed device of ``dev``, so a clean shard receives
-        nothing. ``leading``: dev is a per-device [1, C, chunk] slab."""
+        nothing."""
         k = _pow2_bucket(idx.size)
         pad = np.full(k, idx[0], np.int32)
         pad[:idx.size] = idx.astype(np.int32)
-        if leading:
-            return _scatter_keep(dev[0], pad, host2d[pad])[None]
         return _scatter_keep(dev, pad, host2d[pad])
+
+    def _start_scatter_warm(self) -> None:
+        """Compile the dirty-chunk scatter for every power-of-two chunk
+        count of every resident buffer, on a background thread, right
+        after a full ship: the scatter compiles once per (buffer shape,
+        device, bucket), and a delta session that compiled its own would
+        stall on it. The warm scatters write chunk 0's own bytes into a
+        copy, so the resident buffers are untouched; the next ``update``
+        joins the thread before it scatters."""
+        import threading
+
+        _keep_fn()  # one jit object for both threads: its cache is shared
+        targets = [(self._dev_rep_f, self._host_rep_f),
+                   (self._dev_rep_i, self._host_rep_i)]
+        targets += [(dev, host) for devs, hosts in (
+                        (self._dev_node_f, self._host_node_f),
+                        (self._dev_node_i, self._host_node_i))
+                    for dev, host in zip(devs, hosts)]
+        # not a daemon: the interpreter's exit waits for it rather than
+        # tearing the runtime down under a compile
+        self._warm = threading.Thread(
+            target=self._warm_scatters, args=(targets,),
+            name="arena-scatter-warm", daemon=False)
+        self._warm.start()
+
+    def _warm_scatters(self, targets) -> None:
+        from .precompile import watcher
+
+        watcher.install()
+        watcher.register_background()
+        c = self.chunk
+        try:
+            for dev, host in targets:
+                n = host.size // c
+                k = 1
+                while True:
+                    _scatter_keep(dev, np.zeros(k, np.int32),
+                                  np.broadcast_to(host[:c], (k, c)))
+                    if k >= n:
+                        break
+                    k <<= 1
+        except Exception:  # noqa: BLE001 — a delta compiles what is left
+            log.warning("arena scatter warm failed", exc_info=True)
+
+    def _join_warm(self) -> None:
+        if self._warm is not None:
+            self._warm.join()
+            self._warm = None
 
     def _assembled(self, rep_layout, node_layout):
         """Zero-copy global views over the resident shards: the node

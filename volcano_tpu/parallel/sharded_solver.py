@@ -62,12 +62,12 @@ def arena_mesh(devices=None, axis: str = "n", max_devices: int = 0) -> Mesh:
 
 
 #: static solve flags solve_allocate_sharded_packed2d accepts — a strict
-#: subset of the single-device entries' (no work_conserving/per_node_cap);
-#: the bucket prewarmer filters a session's flag set against this before
-#: warming the sharded variant (ops.precompile.BucketPrewarmer)
+#: subset of the single-device entries' (no per_node_cap); the bucket
+#: prewarmer filters a session's flag set against this before warming the
+#: sharded variant (ops.precompile.BucketPrewarmer)
 PACKED2D_FLAGS = ("max_rounds", "max_gang_iters", "herd_mode",
                   "score_families", "use_queue_cap", "use_drf_order",
-                  "use_hdrf_order", "fused")
+                  "use_hdrf_order", "work_conserving", "fused")
 
 
 @functools.partial(jax.jit, static_argnames=("mesh", "max_rounds",
@@ -75,7 +75,8 @@ PACKED2D_FLAGS = ("max_rounds", "max_gang_iters", "herd_mode",
                                              "score_families",
                                              "use_queue_cap",
                                              "use_drf_order",
-                                             "use_hdrf_order", "fused"))
+                                             "use_hdrf_order",
+                                             "work_conserving", "fused"))
 def solve_allocate_sharded(arrays: Dict[str, jnp.ndarray],
                            score_params: Dict[str, jnp.ndarray],
                            mesh: Mesh,
@@ -86,7 +87,10 @@ def solve_allocate_sharded(arrays: Dict[str, jnp.ndarray],
                            use_queue_cap: bool = False,
                            use_drf_order: bool = False,
                            use_hdrf_order: bool = False,
+                           work_conserving: bool = True,
                            fused: str = "auto") -> SolveResult:
+    """The node-axis-sharded twin of ``ops.solver.solve_allocate``: the
+    same flags (``per_node_cap`` aside) give the same decisions."""
     a = arrays
     T = a["task_init_req"].shape[0]
     N = a["node_idle"].shape[0]
@@ -165,7 +169,7 @@ def solve_allocate_sharded(arrays: Dict[str, jnp.ndarray],
                 * a["node_valid"][:, None].astype(jnp.float32), axis=0)
             total = total_loc if D1 else jax.lax.psum(total_loc, "n")
             Q, deserved, task_queue, q_perm, q_seg_start = queue_cap_state(
-                a, rank, thr, total)
+                a, rank, thr, total, ease_unrequested=work_conserving)
             qalloc0 = a["queue_allocated"]
             # static-sort gathers hoisted out of the round loop (see
             # ops/solver.py — the live-DRF path re-sorts per round)
@@ -442,7 +446,7 @@ def solve_allocate_sharded(arrays: Dict[str, jnp.ndarray],
                   excluded | barred, rounds)
             st = phase_rounds(st, False)
             st = phase_rounds(st, True)
-            if use_queue_cap:
+            if use_queue_cap and work_conserving:
                 # work-conserving overflow (see ops/solver.py phase_rounds)
                 st = phase_rounds(st, False, capped=False)
                 st = phase_rounds(st, True, capped=False)
@@ -527,7 +531,7 @@ def solve_allocate_sharded(arrays: Dict[str, jnp.ndarray],
 @functools.partial(jax.jit, static_argnames=(
     "layout", "mesh", "max_rounds", "max_gang_iters", "herd_mode",
     "score_families", "use_queue_cap", "use_drf_order", "use_hdrf_order",
-    "fused"))
+    "work_conserving", "fused"))
 def solve_allocate_sharded_packed2d(f2d, i2d, layout,
                                     score_params, mesh: Mesh,
                                     max_rounds: int = 64,
@@ -537,6 +541,7 @@ def solve_allocate_sharded_packed2d(f2d, i2d, layout,
                                     use_queue_cap: bool = False,
                                     use_drf_order: bool = False,
                                     use_hdrf_order: bool = False,
+                                    work_conserving: bool = True,
                                     fused: str = "auto") -> SolveResult:
     """Sharded solve over the chunked device-resident buffers kept by
     ops.device_cache.PackedDeviceCache: the unpack slices fuse away on
@@ -553,13 +558,14 @@ def solve_allocate_sharded_packed2d(f2d, i2d, layout,
     return solve_allocate_sharded(arrays, score_params, mesh, max_rounds,
                                   max_gang_iters, herd_mode,
                                   score_families, use_queue_cap,
-                                  use_drf_order, use_hdrf_order, fused)
+                                  use_drf_order, use_hdrf_order,
+                                  work_conserving, fused)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "rep_layout", "node_layout", "mesh", "max_rounds", "max_gang_iters",
     "herd_mode", "score_families", "use_queue_cap", "use_drf_order",
-    "use_hdrf_order", "fused"))
+    "use_hdrf_order", "work_conserving", "fused"))
 def solve_allocate_sharded_arena(f_rep, i_rep, f_node, i_node,
                                  rep_layout, node_layout,
                                  score_params, mesh: Mesh,
@@ -570,6 +576,7 @@ def solve_allocate_sharded_arena(f_rep, i_rep, f_node, i_node,
                                  use_queue_cap: bool = False,
                                  use_drf_order: bool = False,
                                  use_hdrf_order: bool = False,
+                                 work_conserving: bool = True,
                                  fused: str = "auto") -> SolveResult:
     """Sharded solve over the SHARDED device-resident arena
     (ops.device_cache.ShardedDeviceCache): ``f_rep``/``i_rep`` are the
@@ -607,4 +614,5 @@ def solve_allocate_sharded_arena(f_rep, i_rep, f_node, i_node,
     return solve_allocate_sharded(arrays, score_params, mesh, max_rounds,
                                   max_gang_iters, herd_mode,
                                   score_families, use_queue_cap,
-                                  use_drf_order, use_hdrf_order, fused)
+                                  use_drf_order, use_hdrf_order,
+                                  work_conserving, fused)
