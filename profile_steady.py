@@ -148,12 +148,11 @@ def wire_delta_probe(n_pods: int = 2000, flips: int = 4):
         c = RemoteClusterStore(srv.address, delta_watch=delta)
         mirror = {}
 
-        def on_pod(event, obj, old, changed=None, _m=mirror):
+        def on_pod(event, obj, old, _m=mirror):
             if event == "delete":
                 _m.pop(f"{obj.namespace}/{obj.name}", None)
             else:
                 _m[f"{obj.namespace}/{obj.name}"] = obj
-        on_pod.delta_aware = True
         c.watch("pods", on_pod)
         arms[name] = (c, mirror)
     pods = [build_pod("bench", f"wp{i}", "", "Pending",

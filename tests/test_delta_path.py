@@ -39,18 +39,17 @@ def served():
 
 
 def pod_mirror(client, **kw):
-    """A delta-aware dict mirror of the pods stream: key -> pod, plus an
-    event log of (event, phase) for exactly-once assertions."""
+    """A dict mirror of the pods stream: key -> pod, plus an event log of
+    (event, phase) for exactly-once assertions."""
     m, log = {}, []
 
-    def on_pod(event, obj, old, changed=None):
+    def on_pod(event, obj, old):
         key = f"{obj.namespace}/{obj.name}"
         log.append((event, obj.phase))
         if event == "delete":
             m.pop(key, None)
         else:
             m[key] = obj
-    on_pod.delta_aware = True
     client.watch("pods", on_pod)
     return m, log
 

@@ -275,13 +275,12 @@ class TestDeltaViaReplica:
         dc = RemoteClusterStore(rs2.address, delta_watch=True)
         mirror = {}
 
-        def on_pod(event, obj, old, changed=None):
+        def on_pod(event, obj, old):
             key = f"{obj.namespace}/{obj.name}"
             if event == "delete":
                 mirror.pop(key, None)
             else:
                 mirror[key] = obj
-        on_pod.delta_aware = True
         dc.watch("pods", on_pod)
         try:
             for i in range(8):

@@ -242,6 +242,12 @@ class TestChurnMatrix:
                         if ni.node is not None}
                 if real != nodes:
                     return False
+                # a deleted podgroup's delete has landed too: its job
+                # must not linger, podgroup attached, in one arm only
+                if any(job.pod_group is not None
+                       and job.pod_group.name not in pgs
+                       for job in cache.jobs.values()):
+                    return False
                 for name, rv in pgs.items():
                     job = cache.jobs.get(f"churn/{name}")
                     if job is None or job.pod_group is None \

@@ -54,7 +54,13 @@ def get_pod_resource_without_init_containers(pod) -> Resource:
 
 def get_pod_resource_request(pod) -> Resource:
     """Max(sum(containers), max(initContainers)) (k8s launch request)."""
-    r = get_pod_resource_without_init_containers(pod)
+    return _max_init_containers(
+        get_pod_resource_without_init_containers(pod), pod)
+
+
+def _max_init_containers(r: Resource, pod) -> Resource:
+    """``r`` (the containers' sum), maxed in place with each init
+    container's requests."""
     for c in pod.init_containers:
         r.set_max_resource(
             Resource.from_resource_list(container_requests(c)))
@@ -97,7 +103,8 @@ class TaskInfo:
         self.volume_ready = False
         self.pod = pod
         self.resreq = get_pod_resource_without_init_containers(pod)
-        self.init_resreq = get_pod_resource_request(pod)
+        # the same sum, not a second pass over the containers
+        self.init_resreq = _max_init_containers(self.resreq.clone(), pod)
         self.sig_cache = None  # memoized predicate signature (ops.arrays)
         # plain attribute, not a property: pod identity is immutable and
         # the replay/bind waves read key several times per task — the

@@ -28,16 +28,11 @@ from ..metrics.spans import count, span
 
 log = logging.getLogger(__name__)
 
-#: pod fields a delta watch patch may change while staying on the
-#: targeted-update path (apply_pod_delta): none of these move the task
-#: to a different job (annotations), change its identity (name/
-#: namespace/uid), its resource shape (containers/init_containers), or
-#: its owner (scheduler_name) — changes outside this set rebuild the
-#: TaskInfo through the generic update ladder
-_DELTA_FAST_FIELDS = frozenset((
-    "phase", "deletion_timestamp", "node_name", "priority",
-    "resource_version", "container_statuses", "conditions", "labels",
-))
+
+def _same_requests(a, b) -> bool:
+    """Whether pods ``a`` and ``b`` carry the same container requests."""
+    return a is b or (a.containers == b.containers
+                      and a.init_containers == b.init_containers)
 
 
 class DefaultBinder:
@@ -454,37 +449,27 @@ class SchedulerCache:
         if oc is not None:
             oc.feed_event(kind, event, job=job, node=node)
 
-    def _on_pod(self, event, obj, old, changed=None):
-        if obj.scheduler_name == self.scheduler_name:
-            key = job_key_of_pod(obj)
-            self._feed_flatten("pod", event, job=key,
-                               node=obj.node_name or None)
-            if old is not None and old.node_name \
-                    and old.node_name != obj.node_name:
-                self._feed_flatten("pod", event, job=key,
-                                   node=old.node_name)
-        if event == "add":
-            # resync-safe: a watch-resume (or re-list) can replay an add
-            # for a pod this mirror already tracks; treating it as an
-            # update keeps the node/job accounting single-counted instead
-            # of raising out of the delivery (informer AddFunc semantics
-            # on a re-listed object)
-            if self._stored_task(TaskInfo(obj)) is not None:
-                self.update_pod(obj, obj)
-            else:
-                self.add_pod(obj)
-        elif event == "update":
-            # a delta watch stream names the changed fields; when they
-            # fit the targeted path, skip the full TaskInfo rebuild
-            if changed is None or not self.apply_pod_delta(
-                    old, obj, changed):
-                self.update_pod(old, obj)
-        else:
+    def _on_pod(self, event, obj, old):
+        if obj.scheduler_name != self.scheduler_name:
+            return  # another scheduler's pod: nothing here tracks it
+        count("pod_events")
+        key = job_key_of_pod(obj)
+        self._feed_flatten("pod", event, job=key,
+                           node=obj.node_name or None)
+        if old is not None and old.node_name \
+                and old.node_name != obj.node_name:
+            self._feed_flatten("pod", event, job=key, node=old.node_name)
+        if event == "delete":
             self.delete_pod(obj)
-
-    # a delta-capable store passes (event, obj, old, changed_fields) —
-    # detected via getattr on the bound method (client/remote.py)
-    _on_pod.delta_aware = True
+        elif event == "add" and self._stored_task(key, pod_key(obj)) is None:
+            self.add_pod(obj)
+        else:
+            # an update, or a watch-resume (or re-list) replaying an add
+            # for a pod this mirror already tracks: treating the replay as
+            # an update keeps the node/job accounting single-counted
+            # instead of raising out of the delivery (informer AddFunc
+            # semantics on a re-listed object)
+            self.update_pod(obj if old is None else old, obj)
 
     def _on_node(self, event, obj, old):
         # an "add" for an already-known node is a respec in place (no
@@ -553,10 +538,17 @@ class SchedulerCache:
             if ti.status not in (TaskStatus.SUCCEEDED, TaskStatus.FAILED):
                 self.nodes[ti.node_name].add_task(ti)
 
+    @staticmethod
+    def _build_task(pod) -> TaskInfo:
+        """A fresh TaskInfo of ``pod``, counted: the pod handlers derive
+        one when the cache first learns of a pod and re-place it after."""
+        count("pod_task_builds")
+        return TaskInfo(pod)
+
     def add_pod(self, pod) -> None:
         if pod.scheduler_name != self.scheduler_name:
             return
-        self.add_task(TaskInfo(pod))
+        self.add_task(self._build_task(pod))
 
     def delete_task(self, ti: TaskInfo) -> None:
         job_err = node_err = None
@@ -580,80 +572,81 @@ class SchedulerCache:
         if job_err or node_err:
             raise KeyError(f"failed to delete task {ti.key}: {job_err} {node_err}")
 
-    def _stored_task(self, ti: TaskInfo) -> Optional[TaskInfo]:
+    def _stored_task(self, job_key: str, key: str) -> Optional[TaskInfo]:
         """The task as THIS cache knows it. Event objects from a remote
         store are decoded copies, so an update's ``old`` can lag the
         cache's own effector writes (cache.bind set node_name before the
         informer echo arrives); deleting by the stale copy would skip the
         node removal and the re-add would double-place. In-process the
         store shares objects, which masked this."""
-        job = self.jobs.get(ti.job)
+        job = self.jobs.get(job_key)
         if job is None:
             return None
-        return job.tasks.get(ti.key)
+        return job.tasks.get(key)
 
     def update_pod(self, old_pod, new_pod) -> None:
         if new_pod.scheduler_name != self.scheduler_name:
             return
-        old_ti = TaskInfo(old_pod)
-        stored = self._stored_task(old_ti)
+        job_key = job_key_of_pod(new_pod)
+        old_key = job_key if old_pod is new_pod else job_key_of_pod(old_pod)
+        stored = self._stored_task(old_key, pod_key(old_pod))
+        # a pod's requests are fixed at creation: the stored task's resreq
+        # still holds unless the event's or the stored pod's containers
+        # differ from the new ones. Both are checked: a delta stream
+        # patches the stored pod itself in place, so only the event's
+        # pre-patch ``old`` shows its change, and a re-listed add has no
+        # ``old`` but may differ from what the cache stored
+        if stored is not None and old_key == job_key \
+                and stored.uid == new_pod.uid \
+                and _same_requests(old_pod, new_pod) \
+                and _same_requests(stored.pod, new_pod):
+            self._replace_task(stored, new_pod)
+            return
+        # the pod moved jobs (a bare pod gaining its podgroup annotation),
+        # was recreated under its name, or changed its spec: rebuild
         try:
-            self.delete_task(stored if stored is not None else old_ti)
+            self.delete_task(stored if stored is not None
+                             else self._build_task(old_pod))
         except KeyError:
             pass
-        self.add_task(TaskInfo(new_pod))
+        self.add_task(self._build_task(new_pod))
 
-    def apply_pod_delta(self, old_pod, new_pod, changed) -> bool:
-        """Targeted update for a delta-watch column patch: ``changed``
-        names the pod fields the patch touched. When they all fit the
-        safe set, re-place the STORED TaskInfo through the same
-        delete_task/add_task seams the generic path uses — identical
-        index ordering, aggregate arithmetic and node accounting — but
-        without re-deriving a TaskInfo (the resreq parse and status/key
-        derivation are the per-event cost this path exists to kill).
-        Returns False when the caller must run the generic rebuild."""
-        if not _DELTA_FAST_FIELDS.issuperset(changed):
-            return False
-        if new_pod.scheduler_name != self.scheduler_name:
-            return True  # not ours: same early-out as update_pod
-        job = self.jobs.get(job_key_of_pod(new_pod))
-        stored = job.tasks.get(pod_key(new_pod)) \
-            if job is not None else None
-        if stored is None:
-            # bare pod or a task this mirror never added: the generic
-            # ladder owns the odd cases
-            return False
+    def _replace_task(self, stored: TaskInfo, pod) -> None:
+        """Re-place the STORED TaskInfo at ``pod``'s node, status and
+        priority through the same delete_task/add_task seams a rebuild
+        uses — identical index ordering, aggregate arithmetic and node
+        accounting — without re-deriving it: identity and requests are
+        the caller-checked invariants, and every other field is set
+        exactly as a fresh TaskInfo(pod) would set it."""
         try:
             self.delete_task(stored)
         except KeyError:
             pass
-        stored.node_name = new_pod.node_name or ""
-        stored.status = status_of_pod(new_pod)
-        stored.priority = new_pod.priority \
-            if new_pod.priority is not None else 1
-        # reset exactly what a fresh TaskInfo(new_pod) would: the
-        # rebuilt arm of an A/B run must not observe state this arm
-        # carried over
+        stored.node_name = pod.node_name or ""
+        stored.status = status_of_pod(pod)
+        stored.priority = pod.priority if pod.priority is not None else 1
         stored.volume_ready = False
         stored.sig_cache = None
-        stored.pod = new_pod
+        stored.pod = pod
         self.add_task(stored)
-        return True
 
     def delete_pod(self, pod) -> None:
         if pod.scheduler_name != self.scheduler_name:
             return
-        ti = TaskInfo(pod)
-        stored = self._stored_task(ti)
+        job_key = job_key_of_pod(pod)
+        stored = self._stored_task(job_key, pod_key(pod))
         try:
-            self.delete_task(stored if stored is not None else ti)
+            # a bare pod (or one this mirror never added) has no stored
+            # task: a fresh one still names its node for the removal
+            self.delete_task(stored if stored is not None
+                             else self._build_task(pod))
         except KeyError as e:
             log.warning("delete_pod: %s", e)
-        job = self.jobs.get(ti.job)
+        job = self.jobs.get(job_key)
         if job is not None and not job.tasks and job.pod_group is None:
-            del self.jobs[ti.job]
-            self.updater_versions.pop(ti.job, None)
-            self._job_clone_cache.pop(ti.job, None)
+            del self.jobs[job_key]
+            self.updater_versions.pop(job_key, None)
+            self._job_clone_cache.pop(job_key, None)
 
     # -- node handlers ------------------------------------------------------
 

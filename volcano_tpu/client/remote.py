@@ -1279,14 +1279,8 @@ class RemoteClusterStore:
         old = copy.copy(obj)
         for fname, val in sets:
             setattr(obj, fname, val)
-        changed = [fname for fname, _ in sets]
         for fn in subs.get(kind) or ():
-            if getattr(fn, "delta_aware", False):
-                # delta-aware consumers (SchedulerCache._on_pod) take
-                # the changed-field names and skip the full rebuild
-                fn("update", obj, old, changed)
-            else:
-                fn("update", obj, old)
+            fn("update", obj, old)
         t2 = time.perf_counter()
         st = self.delta_stats
         st["events"] += 1

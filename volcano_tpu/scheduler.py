@@ -486,7 +486,7 @@ class Scheduler:
             for pod in list(shadow_binder.bound_pods) \
                     + list(shadow_evictor.evicted_pods):
                 ti = TaskInfo(pod)
-                cache.resync_task(cache._stored_task(ti) or ti)
+                cache.resync_task(cache._stored_task(ti.job, ti.key) or ti)
             cache.process_resync_tasks()
             try:
                 for pg in cache.cluster.list("podgroups"):
