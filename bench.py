@@ -1597,11 +1597,10 @@ def steady_churn():
     """Sustained-churn throughput (the PR-2 acceptance config): M
     back-to-back full scheduling cycles on a running cluster with ~1%
     churn per cycle PLUS one forced compile-bucket crossing mid-run,
-    executed twice — dispatch/collect pipelined and strictly serial —
-    over the identical churn script. Reports pods/sec, p50/p99 session
-    ms, the solve-compile count observed on the session thread after
-    warmup (must be 0: the crossing swaps to the pre-warmed variant),
-    and the pipelined/serial throughput ratio.
+    executed twice over the identical churn script. Reports pods/sec,
+    p50/p99 session ms and the solve-compile count observed on the
+    session thread after warmup (must be 0: the crossing swaps to the
+    pre-warmed variant).
 
     The steady wave is 6 jobs x 5 pods (pending T flattens to bucket 32);
     the crossing wave is 8 jobs x 5 pods (T -> bucket 40, J -> bucket
@@ -1623,7 +1622,7 @@ def steady_churn():
     n_nodes, base_jobs, tpj = 400, 300, 5
     cycles, crossing_at = 20, 12
 
-    def run(pipelined, shared_dcache=None):
+    def run(shared_dcache=None):
         store = ClusterStore()
         cache = SchedulerCache(store)
         cache.binder = FakeBinder()
@@ -1651,7 +1650,7 @@ def steady_churn():
                         {"cpu": str(1 + k % 3), "memory": f"{1 + k % 4}Gi"},
                         f"j{k}"))
 
-        sched = Scheduler(cache, prewarm=True, pipeline_solver=pipelined)
+        sched = Scheduler(cache, prewarm=True)
         # warmup: the base burst (its own bucket) + two steady waves so
         # every steady-shape jit variant is compiled before timing starts
         wave(base_jobs)
@@ -1709,34 +1708,19 @@ def steady_churn():
         }, cache.device_cache
 
     watcher.install()
-    # alternate serial/pipelined twice and keep each mode's best rep: the
-    # first rep pays every compile (solver variants + the background
-    # warms), so a single S-then-P ordering hands the second mode a quiet
-    # machine and the first a contended one
-    serial, dcache = run(pipelined=False)
-    pipelined, dcache = run(pipelined=True, shared_dcache=dcache)
-    serial2, dcache = run(pipelined=False, shared_dcache=dcache)
-    pipelined2, _ = run(pipelined=True, shared_dcache=dcache)
-    reps = {"serial_pods_per_sec_reps":
-            [serial["pods_per_sec"], serial2["pods_per_sec"]],
-            "pipelined_pods_per_sec_reps":
-            [pipelined["pods_per_sec"], pipelined2["pods_per_sec"]]}
-    compiles = (pipelined["session_compiles_after_warmup"]
-                + pipelined2["session_compiles_after_warmup"])
-    if serial2["pods_per_sec"] > serial["pods_per_sec"]:
-        serial = serial2
-    if pipelined2["pods_per_sec"] > pipelined["pods_per_sec"]:
-        pipelined = pipelined2
-    gain = (pipelined["pods_per_sec"] / serial["pods_per_sec"]
-            if serial["pods_per_sec"] else None)
+    # two reps, the better one reported: the first pays every compile
+    # (solver variants + the background warms)
+    first, dcache = run()
+    second, _ = run(shared_dcache=dcache)
+    compiles = (first["session_compiles_after_warmup"]
+                + second["session_compiles_after_warmup"])
     return {
         "cycles": cycles,
         "churn_pods_per_cycle": 30,
         "crossing_wave_pods": 40,
-        "pipelined": pipelined,
-        "serial": serial,
-        **reps,
-        "overlap_gain": round(gain, 3) if gain else None,
+        "steady": max(first, second, key=lambda r: r["pods_per_sec"]),
+        "pods_per_sec_reps": [first["pods_per_sec"],
+                              second["pods_per_sec"]],
         # the acceptance criterion: crossing included, nothing compiled
         # on the session thread once warm
         "zero_session_compiles": compiles == 0,

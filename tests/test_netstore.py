@@ -542,10 +542,13 @@ class TestSlowWatcher:
             assert len(store._listeners["nodes"]) == base + 1
             deadline = time.time() + 10
             i = 0
+            # 64 labels a node: each event frame is a few KB, so the
+            # server's send buffer fills in hundreds of events
+            labels = {f"pad-{k}": "x" * 63 for k in range(64)}
             while len(store._listeners["nodes"]) > base \
                     and time.time() < deadline:
                 store.apply("nodes", build_node(f"n{i % 40}",
-                                                {"cpu": "1"}))
+                                                {"cpu": "1"}, labels))
                 i += 1
                 time.sleep(0.001)
             assert len(store._listeners["nodes"]) == base, \

@@ -67,6 +67,23 @@ def _build_cluster(n_nodes=4, n_jobs=3, tpj=2):
     return store, cache, wave
 
 
+def _arena_scheduler(cache, arena, **kw):
+    """A Scheduler whose allocate solves on ``arena``: "packed" is the
+    default conf's device-resident packed arena, "sharded" the conf's
+    ``mode: sharded`` over a one-device mesh arena."""
+    if arena == "packed":
+        return Scheduler(cache, **kw)
+    import jax
+
+    from volcano_tpu.ops.device_cache import ShardedDeviceCache
+    from volcano_tpu.parallel import make_mesh
+    from volcano_tpu.sim.virtualcluster import build_conf
+
+    cache.sharded_device_cache = ShardedDeviceCache(
+        make_mesh(jax.devices()[:1]))
+    return Scheduler(cache, scheduler_conf=build_conf("sharded"), **kw)
+
+
 # ---------------------------------------------------------------------------
 # circuit breaker state machine
 # ---------------------------------------------------------------------------
@@ -396,31 +413,57 @@ class TestBreakerFallback:
         assert trace == [("closed", "open"), ("open", "half_open"),
                          ("half_open", "closed")]
 
-    def test_garbage_readback_counts_as_device_failure(self, monkeypatch):
+    @pytest.mark.parametrize("arena", ["packed", "sharded"])
+    def test_garbage_readback_counts_as_device_failure(self, arena,
+                                                       monkeypatch):
         """Out-of-range solver output (a sick device returning nonsense
-        without raising) routes through the same containment."""
+        without raising) routes through the same containment, on either
+        arena."""
         store, cache, wave = _build_cluster(n_jobs=2)
-        sched = Scheduler(cache)
-        import volcano_tpu.ops.solver as solver_mod
+        sched = _arena_scheduler(cache, arena)
         import numpy as np
 
-        def garbage(compact):
-            n = np.asarray(compact).shape[0]
-            return (np.full(n, 10 ** 6, np.int32), np.zeros(n, np.int32))
+        if arena == "packed":
+            import volcano_tpu.ops.solver as solver_mod
 
-        monkeypatch.setattr(solver_mod, "decode_compact", garbage)
+            def garbage(compact):
+                n = np.asarray(compact).shape[0]
+                return (np.full(n, 10 ** 6, np.int32),
+                        np.zeros(n, np.int32))
+
+            monkeypatch.setattr(solver_mod, "decode_compact", garbage)
+        else:
+            # the sharded solve packs no compact readback: its collect
+            # reads ``assigned`` itself, so the garbage goes there
+            import volcano_tpu.parallel as par
+
+            real_solve = par.solve_allocate_sharded_arena
+
+            def garbage_solve(*args, **kwargs):
+                res = real_solve(*args, **kwargs)
+                return res._replace(assigned=res.assigned * 0 + 10 ** 6)
+
+            monkeypatch.setattr(par, "solve_allocate_sharded_arena",
+                                garbage_solve)
         sched.run_once()
         assert sched.last_cycle_timing.get("host_fallback") == 1.0
+        assert sched.last_cycle_timing.get("arena_mode") == arena
         assert len(cache.binder.binds) == 4
         # one recorded failure on the breaker
         assert cache.breaker._consecutive_failures == 1
+        # the arena that solved is the one the fault invalidated
+        dc = cache.device_cache if arena == "packed" \
+            else cache.sharded_device_cache
+        assert dc.invalidations == 1
 
 
 class TestDegradedParity:
-    def test_fallback_cycle_binds_match_pure_host_cycle(self):
+    @pytest.mark.parametrize("arena", ["packed", "sharded"])
+    def test_fallback_cycle_binds_match_pure_host_cycle(self, arena):
         """The degradation ladder's first rung must be semantics-free:
         a device-fault cycle that fell back to the host oracle produces
-        bind-for-bind the decisions of a cycle configured host-only."""
+        bind-for-bind the decisions of a cycle configured host-only,
+        whichever arena the device path solves on."""
         host_conf = (
             'actions: "enqueue, allocate, backfill"\n'
             'tiers:\n'
@@ -433,7 +476,8 @@ class TestDegradedParity:
         def run(conf, inject):
             faults.reset()
             store, cache, wave = _build_cluster(n_jobs=4)
-            sched = Scheduler(cache, scheduler_conf=conf)
+            sched = Scheduler(cache, scheduler_conf=conf) if conf \
+                else _arena_scheduler(cache, arena)
             if inject:
                 faults.arm_once("solver_dispatch")
             sched.run_once()

@@ -852,3 +852,55 @@ class TestFusedChoiceParity:
                 == np.asarray(r_on.job_ready)).all()
         a_off, a_on = np.asarray(r_off.assigned), np.asarray(r_on.assigned)
         assert ((a_off >= 0) == (a_on >= 0)).all()
+
+
+def _result(assigned, kind, n_nodes, compact=True, rounds=4):
+    """A SolveResult as the solve entries return it: device arrays, the
+    int16 compact form when ``compact`` (packed for ``n_nodes``)."""
+    import jax.numpy as jnp
+
+    from volcano_tpu.ops.solver import SolveResult, _compact
+
+    a = jnp.asarray(assigned, jnp.int32)
+    k = jnp.asarray(kind, jnp.int32)
+    return SolveResult(
+        assigned=a, kind=k, job_ready=jnp.ones(1, bool),
+        rounds=jnp.int32(rounds),
+        compact=_compact(a, k, n_nodes) if compact else None)
+
+
+class TestCollectAssignment:
+    """ops.solver.collect_assignment: one host collect for every solve
+    entry's result, checked by AllocateAction._check_solver_output."""
+
+    @pytest.mark.parametrize("case", [
+        "compact", "wide_nodes", "sharded", "out_of_range"])
+    def test_collect(self, case):
+        from volcano_tpu.actions.allocate import AllocateAction
+        from volcano_tpu.ops.solver import (
+            COMPACT_KIND_SHIFT, collect_assignment,
+        )
+
+        assigned, kind = [3, -1, 0, 7], [0, -1, 1, 0]
+        n_nodes = 8
+        if case == "wide_nodes":
+            # more nodes than the int16 packing holds: the compact form
+            # is the unavailable sentinel, the int32 arrays are read
+            n_nodes = (1 << COMPACT_KIND_SHIFT) + 8
+            assigned[3] = n_nodes - 1
+        if case == "out_of_range":
+            assigned[0] = n_nodes
+        res = _result(assigned, kind, n_nodes,
+                      compact=case != "sharded")
+        got_a, got_k, rounds = collect_assignment(res, n_nodes)
+        assert rounds == 4 and isinstance(rounds, int)
+        assert isinstance(got_a, np.ndarray)
+        if case == "out_of_range":
+            with pytest.raises(RuntimeError, match="sanity"):
+                AllocateAction._check_solver_output(
+                    got_a, got_k, len(assigned), n_nodes)
+            return
+        np.testing.assert_array_equal(got_a, assigned)
+        np.testing.assert_array_equal(got_k, kind)
+        AllocateAction._check_solver_output(got_a, got_k, len(assigned),
+                                            n_nodes)

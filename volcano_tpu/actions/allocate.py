@@ -16,7 +16,7 @@ Two execution modes:
 from __future__ import annotations
 
 import logging
-from typing import Dict, List, Optional
+from typing import Dict, List
 
 import numpy as np
 
@@ -32,6 +32,9 @@ from ..utils import PriorityQueue
 log = logging.getLogger(__name__)
 
 _UNRESOLVED = object()  # sentinel: _pending_tasks resolves the key itself
+
+#: the solve flags solve_allocate_sequential takes
+_SEQUENTIAL_FLAGS = ("score_families", "use_queue_cap", "work_conserving")
 
 
 def _task_order_key(ssn):
@@ -198,7 +201,8 @@ class AllocateAction(Action):
                         sharded: bool = False) -> None:
         from ..ops import flatten_snapshot, solve_allocate, \
             solve_allocate_sequential
-
+        from ..ops.pipeline import start_readback
+        from ..ops.solver import collect_assignment
         from ..resilience import faults
 
         timing = ssn.solver_options.setdefault("timing", {})
@@ -340,215 +344,65 @@ class AllocateAction(Action):
                 herd = "pack" if params["binpack_weight"] > (
                     params["least_req_weight"]
                     + params["balanced_weight"]) else "spread"
+            # the solve entries' static flags, one set per session
+            flags = dict(herd_mode=herd, score_families=families,
+                         use_queue_cap=use_queue_cap,
+                         use_drf_order=use_drf_order,
+                         use_hdrf_order=use_hdrf_order,
+                         work_conserving=work_conserving)
 
             dc = getattr(ssn, "device_cache", None)
-            sidecar = getattr(ssn, "sidecar", None)
             # which arena a device fault must invalidate: the packed cache by
             # default, the sharded arena when this session dispatched there
             fault_dc = dc
-            rounds = None  # the sharded solve's round count, read back
             try:
                 # device-path circuit-breaker scope: anything that throws out
-                # of the dispatch (XLA runtime error, OOM, dead sidecar, an
-                # injected fault) counts one consecutive device failure and
-                # this session finishes through the host oracle
+                # of the dispatch or the collect (XLA runtime error, OOM,
+                # garbage output, an injected fault) counts one consecutive
+                # device failure and this session finishes through the host
+                # oracle
                 faults.fire("solver_dispatch")
                 if sequential:
                     res = solve_allocate_sequential(
-                        arr.device_dict(), params, score_families=families,
-                        use_queue_cap=use_queue_cap,
-                        work_conserving=work_conserving)
-                elif sharded:
-                    # mode: sharded — the node-axis shard_map solver over the
-                    # SHARDED device-resident arena (ShardedDeviceCache):
-                    # node-axis chunks live per mesh device, task/job chunks
-                    # are replicated once per device, and a steady session
-                    # ships dirty chunks only to the shard(s) owning them
-                    # (a zero-dirty session dispatches straight off the
-                    # resident shards, 0 bytes). At D=1 the mesh degrades to
-                    # the packed arena's shape with a collective-free program;
-                    # multi-chip deployments get the identical code path with
-                    # a wider mesh. The dispatch keeps the packed path's whole
-                    # protection ladder: one re-send on a transport-marked
-                    # error (resilience.transient — a device runtime error is
-                    # never one, so an OOM or Mosaic failure counts against
-                    # the breaker at once), the circuit breaker + host-oracle
-                    # fallback around this block, and the async-readback
-                    # overlap below.
-                    from ..parallel import (
-                        arena_mesh, solve_allocate_sharded_arena,
-                    )
-                    from ..resilience.transient import retry_transient
+                        arr.device_dict(), params,
+                        **{k: flags[k] for k in _SEQUENTIAL_FLAGS})
+                elif sharded or dc is not None:
+                    # the device-resident arena: the packed cache, or with
+                    # mode: sharded the node-axis shards of the shard_map
+                    # solver (ops.device_cache). Either ships only the
+                    # chunks dirtied since the last session and dispatches
+                    # one async solve
+                    arena = self._sharded_arena(ssn) if sharded else dc
+                    fault_dc = arena
                     with span("volcano.allocate.pack"):
                         fbuf, ibuf, layout = arr.packed()
-                    sdc = getattr(ssn, "sharded_device_cache", None)
-                    if sdc is None:
-                        from ..ops.device_cache import ShardedDeviceCache
-                        sdc = ShardedDeviceCache(arena_mesh())
-                        ssn.sharded_device_cache = sdc
-                        if getattr(ssn, "cache", None) is not None:
-                            # persist across sessions: an arena is only an
-                            # arena if it outlives the session that built it
-                            ssn.cache.sharded_device_cache = sdc
-                    fault_dc = sdc
-                    mesh = sdc.mesh
                     with span("volcano.allocate.delta_plan"):
-                        bufs = sdc.update(fbuf, ibuf, layout)
-                        params = sdc.params_device(params)
-                    timing["delta_chunks"] = float(sdc.last_shipped_chunks)
-                    timing["arena_mode"] = "sharded"
-                    timing["arena_bytes_shipped"] = \
-                        float(sdc.last_shipped_bytes)
-                    timing["arena_full_ship"] = float(sdc.last_full_ship)
-                    count("mesh_devices", sdc.D)
-                    count("shard_bytes_max", max(sdc.last_shard_bytes))
-                    count("shard_bytes_total", sum(sdc.last_shard_bytes))
-                    pw = getattr(ssn, "prewarmer", None)
-                    if pw is not None and pw.mesh is None:
-                        # sharded sessions must pre-warm (and persistent-
-                        # cache) the sharded arena variants too, not just
-                        # packed2d
-                        pw.mesh = mesh
-                    # flags snapshot so the bucket prewarmer can predict this
-                    # mode's next-bucket variants
-                    sdc.last_solve_flags = dict(
-                        layout=layout, herd_mode=herd,
-                        score_families=families,
-                        use_queue_cap=use_queue_cap,
-                        use_drf_order=use_drf_order,
-                        use_hdrf_order=use_hdrf_order,
-                        work_conserving=work_conserving)
+                        staged = arena.plan(fbuf, ibuf, layout, params, flags)
+                    arena.record(timing)
                     with span("volcano.allocate.dispatch", "dispatch_ms"):
-                        r = retry_transient(
-                            lambda: solve_allocate_sharded_arena(
-                                *bufs, params, mesh, herd_mode=herd,
-                                score_families=families,
-                                use_queue_cap=use_queue_cap,
-                                use_drf_order=use_drf_order,
-                                use_hdrf_order=use_hdrf_order,
-                                work_conserving=work_conserving),
-                            what="sharded solver dispatch")
-                    # the sharded kernel produces no compact readback:
-                    # assigned/kind/rounds stay DEVICE futures here and
-                    # collect in the res-is-None branch below, after the
-                    # overlap window
-                    assigned = r.assigned
-                    kind = r.kind
-                    rounds = r.rounds
-                    res = None
-                elif sidecar is not None:
-                    # process boundary: ship the packed snapshot to the solver
-                    # sidecar (which owns the TPU) and replay its assignments
-                    fbuf, ibuf, layout = arr.packed()
-                    assigned, kind, _info = sidecar.solve(
-                        fbuf, ibuf, layout, params, herd_mode=herd,
-                        score_families=families, use_queue_cap=use_queue_cap,
-                        use_drf_order=use_drf_order,
-                        use_hdrf_order=use_hdrf_order,
-                        work_conserving=work_conserving)
-                    res = None
-                elif dc is not None:
-                    # device-resident buffers, fused dispatch: the dirty-chunk
-                    # scatter runs INSIDE the solve jit, so a session costs
-                    # exactly one dispatch (scatter+solve) + one compact
-                    # readback. Sessions dirtying more than FUSED_SLOTS chunks
-                    # use the separate scatter + non-fused solve (3
-                    # dispatches, but no extra solve compile variants)
-                    from ..ops.solver import (
-                        solve_allocate_delta, solve_allocate_packed2d,
-                    )
-                    with span("volcano.allocate.pack"):
-                        fbuf, ibuf, layout = arr.packed()
-                    params = dc.params_device(params)
-                    # flags snapshot for diagnostics that re-dispatch the
-                    # same solve variant against the committed buffers
-                    dc.last_solve_flags = dict(
-                        layout=layout, herd_mode=herd, score_families=families,
-                        use_queue_cap=use_queue_cap,
-                        use_drf_order=use_drf_order,
-                        use_hdrf_order=use_hdrf_order,
-                        work_conserving=work_conserving)
-                    dc.last_params = params
-                    with span("volcano.allocate.delta_plan"):
-                        kind_, payload = dc.plan_delta(fbuf, ibuf, layout)
-                    timing["delta_chunks"] = float(dc.last_shipped_chunks)
-                    timing["arena_mode"] = "packed"
-                    timing["arena_bytes_shipped"] = \
-                        float(dc.last_shipped_bytes)
-                    timing["arena_full_ship"] = float(dc.last_full_ship)
-                    with span("volcano.allocate.dispatch", "dispatch_ms"):
-                        if kind_ == "updated":
-                            f2d, i2d = payload
-                            res = solve_allocate_packed2d(
-                                f2d, i2d, layout, params, herd_mode=herd,
-                                score_families=families,
-                                use_queue_cap=use_queue_cap,
-                                use_drf_order=use_drf_order,
-                                use_hdrf_order=use_hdrf_order,
-                                work_conserving=work_conserving)
-                        else:
-                            f2d, i2d, fi, fv, ii, iv = payload
-                            try:
-                                res, new_f, new_i = solve_allocate_delta(
-                                    f2d, i2d, fi, fv, ii, iv, layout, params,
-                                    herd_mode=herd, score_families=families,
-                                    use_queue_cap=use_queue_cap,
-                                    use_drf_order=use_drf_order,
-                                    use_hdrf_order=use_hdrf_order,
-                                    work_conserving=work_conserving)
-                            except Exception:
-                                # donation may have consumed the buffers — but
-                                # the host mirror and the (never-donated)
-                                # pinned params are fine: soft-invalidate so
-                                # the next session re-ships the chunked buffers
-                                # and re-validates the params in place instead
-                                # of rebuilding cold
-                                dc.invalidate()
-                                raise
-                            dc.commit(new_f, new_i)
+                        res = arena.dispatch(staged)
                 else:
-                    res = solve_allocate(
-                        arr.device_dict(), params, herd_mode=herd,
-                        score_families=families, use_queue_cap=use_queue_cap,
-                        use_drf_order=use_drf_order,
-                        use_hdrf_order=use_hdrf_order,
-                        work_conserving=work_conserving)
-            except Exception:
-                log.exception("solver dispatch failed; resetting the device "
-                              "cache and falling back to the host loop")
-                solve.discard()
-                self._device_fault_fallback(ssn, fault_dc, timing, breaker)
-                return
-            # -----------------------------------------------------------------
-            # dispatch/collect split: the jitted solve above is an ASYNC
-            # dispatch (res holds device futures), so the host is free until
-            # the compact readback below actually blocks. Spend that window on
-            # work that previously serialized after the device finished:
-            # replay preparation (the node-name table the Statement replay
-            # indexes), the bucket-prewarm occupancy check (ops.precompile),
-            # and a young-generation gc pass (collection is disabled during
-            # the cycle — see Scheduler.run_once — so this drains the nursery
-            # for free while the device solves). pipeline_solver=False keeps
-            # the strictly serial order for parity testing.
-            # -----------------------------------------------------------------
-            pipelined = bool(getattr(ssn, "pipeline_solver", True))
-            node_names = None
-            statements = None
-            prewarmed = False
-            if pipelined and (res is not None or sharded):
+                    res = solve_allocate(arr.device_dict(), params, **flags)
+                # -------------------------------------------------------------
+                # dispatch/collect split: the jitted solve above is an ASYNC
+                # dispatch (res holds device futures), so the host is free
+                # until the readback below actually blocks. Spend that window
+                # on work that would otherwise serialize after the device
+                # finished: replay preparation (the node-name table the
+                # Statement replay indexes), the bucket-prewarm occupancy
+                # check (ops.precompile), and a young-generation gc pass
+                # (collection is disabled during the cycle — see
+                # Scheduler.run_once — so this drains the nursery for free
+                # while the device solves).
+                # -------------------------------------------------------------
                 with span("volcano.allocate.overlap"):
-                    # previous-phase readback starts NOW: begin the
-                    # device->host result transfer asynchronously so the wire
-                    # RTT overlaps the solve tail and the replay-prep below
-                    # instead of being paid serially when the collect blocks
-                    # (ops.pipeline). The sharded kernel has no compact form;
-                    # its assigned/kind futures prefetch the same way.
-                    from ..ops.pipeline import start_readback
-                    if res is not None:
-                        start_readback(res.compact, res.assigned, res.kind,
-                                       res.rounds)
-                    else:
-                        start_readback(assigned, kind, rounds)
+                    # begin the device->host result transfer now, so the wire
+                    # RTT overlaps the solve tail and the replay prep below
+                    # instead of being paid when the collect blocks (the
+                    # sharded solve has no compact form; its assigned/kind
+                    # futures prefetch the same way)
+                    start_readback(res.compact, res.assigned, res.kind,
+                                   res.rounds)
                     node_names = [n.name for n in arr.nodes_list]
                     # Statement construction is pure (no session registration
                     # until ops are recorded), so the replay's per-job
@@ -556,7 +410,6 @@ class AllocateAction(Action):
                     statements = [ssn.statement(defer_events=True)
                                   for _ in job_order]
                     self._observe_prewarm(ssn, arr, fault_dc)
-                    prewarmed = True
                     import jax
                     if jax.default_backend() != "cpu":
                         # young-gen GC only when the solve runs on a real
@@ -566,68 +419,24 @@ class AllocateAction(Action):
                         # the cycle
                         import gc
                         gc.collect(0)
-            if res is not None:
-                # one int16 readback instead of two int32 ones: half the
-                # device->host bytes on the session's critical path (the
-                # sidecar path already returned host arrays)
-                from ..ops.solver import COMPACT_KIND_SHIFT, decode_compact
-                try:
-                    with span("volcano.allocate.readback", "readback_ms"):
-                        if arr.N <= (1 << COMPACT_KIND_SHIFT):
-                            assigned, kind = decode_compact(res.compact)
-                        else:  # >16k nodes: node index overflows int16 packing
-                            assigned = np.asarray(res.assigned)
-                            kind = np.asarray(res.kind)
-                        self._check_solver_output(assigned, kind,
-                                                  len(tasks_in_order),
-                                                  len(arr.nodes_list))
-                        # the compact readback's transfer carried the round
-                        # count
-                        count("solve_rounds", int(res.rounds))
-                except Exception:
-                    # async-collect failure: the error surfaces HERE, after a
-                    # donated-buffer dispatch already commit()ed what are now
-                    # poisoned device buffers — drop the device cache so the
-                    # next session re-ships in full instead of solving on (or
-                    # scattering into) invalid buffers, and finish THIS
-                    # session through the host oracle so a device fault costs
-                    # one slow cycle, not a scheduling gap
-                    log.exception("solver collect failed; resetting device "
-                                  "cache and falling back to the host loop")
-                    solve.discard()
-                    self._device_fault_fallback(ssn, fault_dc, timing, breaker)
-                    return
-                if not pipelined:
-                    # serial mode still pre-warms (after the readback), so
-                    # turning the overlap off doesn't also disable the
-                    # compile-stall protection
-                    self._observe_prewarm(ssn, arr, dc)
-            else:
-                # sharded/sidecar path: block on the assigned/kind readback
-                # (the sidecar already returned host arrays; the sharded
-                # overlap window above began the async device->host transfer,
-                # so this collect pays only the remaining tail)
-                try:
-                    with span("volcano.allocate.readback", "readback_ms"):
-                        assigned = np.asarray(assigned)
-                        kind = np.asarray(kind)
-                        self._check_solver_output(assigned, kind,
-                                                  len(tasks_in_order),
-                                                  len(arr.nodes_list))
-                        if rounds is not None:
-                            count("solve_rounds", int(rounds))
-                except Exception:
-                    log.exception("sharded/sidecar solver output failed "
-                                  "validation; falling back to the host loop")
-                    solve.discard()
-                    self._device_fault_fallback(ssn, fault_dc, timing, breaker)
-                    return
-                if not prewarmed:
-                    # the sidecar (and serial sharded) path skipped the
-                    # overlap window above, so the occupancy check runs here
-                    # — a sharded session's bucket crossing must pre-warm its
-                    # own (sharded) variants
-                    self._observe_prewarm(ssn, arr, fault_dc)
+                with span("volcano.allocate.readback", "readback_ms"):
+                    assigned, kind, rounds = collect_assignment(res, arr.N)
+                    self._check_solver_output(assigned, kind,
+                                              len(tasks_in_order),
+                                              len(arr.nodes_list))
+                    count("solve_rounds", rounds)
+            except Exception:
+                # a collect failure surfaces after a donated-buffer dispatch
+                # already commit()ed what are now poisoned device buffers:
+                # invalidate the arena so the next session re-ships in full,
+                # and finish THIS session through the host oracle so a device
+                # fault costs one slow cycle, not a scheduling gap
+                log.exception("solver dispatch or collect failed; "
+                              "invalidating the device arena and falling "
+                              "back to the host loop")
+                solve.discard()
+                self._device_fault_fallback(ssn, fault_dc, timing, breaker)
+                return
             if breaker is not None:
                 # a full dispatch+collect round-trip with sane output: the
                 # device path is healthy (closes a half-open breaker)
@@ -649,7 +458,7 @@ class AllocateAction(Action):
                 flush_bulk_commit
             acc = begin_bulk_commit(ssn)
             try:
-                self._replay(ssn, arr, job_order, assigned, kind, node_names,
+                self._replay(ssn, job_order, assigned, kind, node_names,
                              statements)
             finally:
                 # exception-safe: jobs already committed into the window MUST
@@ -693,6 +502,27 @@ class AllocateAction(Action):
                 f"[-1, {n_nodes}) or non-boolean pipeline flag)")
 
     @staticmethod
+    def _sharded_arena(ssn):
+        """The node-axis sharded arena (ops.device_cache.ShardedDeviceCache)
+        over the mesh of this host's devices, built by the first sharded
+        session and kept on the cache: an arena is only an arena if it
+        outlives the session that built it."""
+        sdc = getattr(ssn, "sharded_device_cache", None)
+        if sdc is None:
+            from ..ops.device_cache import ShardedDeviceCache
+            from ..parallel import arena_mesh
+            sdc = ShardedDeviceCache(arena_mesh())
+            ssn.sharded_device_cache = sdc
+            if getattr(ssn, "cache", None) is not None:
+                ssn.cache.sharded_device_cache = sdc
+        pw = getattr(ssn, "prewarmer", None)
+        if pw is not None and pw.mesh is None:
+            # sharded sessions pre-warm (and persistent-cache) the sharded
+            # variants too, not just packed2d
+            pw.mesh = sdc.mesh
+        return sdc
+
+    @staticmethod
     def _observe_prewarm(ssn, arr, dc) -> None:
         """Feed the bucket prewarmer (ops.precompile.BucketPrewarmer) the
         live occupancy; a trigger only spawns a daemon thread, so this is
@@ -705,18 +535,12 @@ class AllocateAction(Action):
         except Exception:  # noqa: BLE001 — prewarm is advisory
             log.exception("bucket prewarm observe failed")
 
-    def _replay(self, ssn, arr, job_order, assigned, kind,
-                node_names: Optional[List[str]] = None,
-                statements: Optional[List] = None) -> None:
+    def _replay(self, ssn, job_order, assigned, kind,
+                node_names: List[str], statements: List) -> None:
         # node-name table + per-job statements: prepped in the
-        # dispatch/collect overlap window when the pipeline is on,
-        # rebuilt here otherwise
-        if node_names is None:
-            node_names = [n.name for n in arr.nodes_list]
+        # dispatch/collect overlap window
         idx = 0
-        for j, (job, tasks) in enumerate(job_order):
-            stmt = statements[j] if statements is not None \
-                else ssn.statement(defer_events=True)
+        for (job, tasks), stmt in zip(job_order, statements):
             pairs = []
             for task in tasks:
                 t_idx = idx

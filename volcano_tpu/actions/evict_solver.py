@@ -250,7 +250,6 @@ def run_evict_solver(ssn, mode: str, skip_jobs=()):
             (varrays["job_req"], varrays["job_acct"],
              varrays["job_count"]) = uniform
         vnp = {k: np.asarray(v) for k, v in varrays.items()}
-    sidecar = getattr(ssn, "sidecar", None)
     timing = ssn.solver_options.setdefault("timing", {})
     try:
         # breaker scope: a throwing evict dispatch/collect (or an injected
@@ -260,36 +259,28 @@ def run_evict_solver(ssn, mode: str, skip_jobs=()):
         with span(f"volcano.{mode}.solve",
                   "preempt_solve_ms" if preempt else None):
             faults.fire("evict_dispatch")
-            if sidecar is not None:
-                # process boundary: evict solves ship to the solver process
-                # too (job_req in the victim dict selects the fast path)
-                assigned, evicted_by = sidecar.solve_evict(
-                    arr.device_dict(), vnp, params, score_families=families,
+            if uniform is not None:
+                # gang fast path: one solve step per JOB
+                # (solve_evict_uniform)
+                from ..ops.evict import solve_evict_uniform
+                res = solve_evict_uniform(
+                    arr.device_dict(), vnp, params,
+                    score_families=families,
+                    require_freed_covers=False, stop_at_need=True)
+            else:
+                res = solve_evict(
+                    arr.device_dict(), vnp, params,
+                    score_families=families,
                     require_freed_covers=not preempt,
                     allow_revert=preempt, stop_at_need=preempt)
-            else:
-                if uniform is not None:
-                    # gang fast path: one solve step per JOB
-                    # (solve_evict_uniform)
-                    from ..ops.evict import solve_evict_uniform
-                    res = solve_evict_uniform(
-                        arr.device_dict(), vnp, params,
-                        score_families=families,
-                        require_freed_covers=False, stop_at_need=True)
-                else:
-                    res = solve_evict(
-                        arr.device_dict(), vnp, params,
-                        score_families=families,
-                        require_freed_covers=not preempt,
-                        allow_revert=preempt, stop_at_need=preempt)
-                from ..ops.evict import decode_evict_compact
-                try:
-                    # one int16 readback carries both outputs
-                    assigned, evicted_by = decode_evict_compact(
-                        res.compact, arr.task_init_req.shape[0])
-                except ValueError:  # >32k nodes/jobs: indices overflow
-                    assigned = np.asarray(res.assigned)
-                    evicted_by = np.asarray(res.evicted_by)
+            from ..ops.evict import decode_evict_compact
+            try:
+                # one int16 readback carries both outputs
+                assigned, evicted_by = decode_evict_compact(
+                    res.compact, arr.task_init_req.shape[0])
+            except ValueError:  # >32k nodes/jobs: indices overflow
+                assigned = np.asarray(res.assigned)
+                evicted_by = np.asarray(res.evicted_by)
     except Exception:
         log.exception("%s device solve failed; degrading to the host "
                       "loop for this cycle", mode)

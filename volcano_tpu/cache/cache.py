@@ -343,15 +343,9 @@ class SchedulerCache:
         # sharded_byte_budget bytes per device (0 = never auto-shard)
         self.solver_mode = None
         self.sharded_byte_budget = 0
-        # optional solver-sidecar client (parallel.sidecar.SidecarSolver):
-        # when set, allocate ships snapshots to the solver process instead
-        # of running the kernel in-process
-        self.sidecar = None
         # compile-and-dispatch pipeline (ops.precompile): the Scheduler
-        # installs a BucketPrewarmer here when enabled; pipeline_solver
-        # gates the allocate action's dispatch/collect overlap
+        # installs a BucketPrewarmer here when enabled
         self.prewarmer = None
-        self.pipeline_solver = True
         # device-path circuit breaker (resilience.CircuitBreaker): the
         # Scheduler installs one; sessions read it for the device -> host
         # oracle degradation ladder in allocate/preempt/reclaim

@@ -20,8 +20,9 @@ per-kind EventJournal replays exactly the missed events (client-go's
 reflector re-watch at a ResourceVersion), or refuses with ResumeGapError
 when its bounded window no longer covers them — the client then falls
 back to its crash-only path. Frame size is capped so a corrupt or
-hostile peer cannot drive unbounded allocation (same rule as the solver
-sidecar, parallel/sidecar.py:35-53).
+hostile peer cannot drive unbounded allocation: a frame whose length
+prefix exceeds MAX_FRAME_BYTES is refused before its payload is read,
+and a sender refuses to write one.
 """
 
 from __future__ import annotations

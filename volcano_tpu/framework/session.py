@@ -143,12 +143,8 @@ class Session:
                                             None)
         self.solver_mode = getattr(cache, "solver_mode", None)
         self.sharded_byte_budget = getattr(cache, "sharded_byte_budget", 0)
-        self.sidecar = getattr(cache, "sidecar", None)
-        # compile-and-dispatch pipeline knobs (ops.precompile): background
-        # bucket pre-warm and the allocate action's dispatch/collect
-        # overlap (False = strictly serial solve for parity testing)
+        # background bucket pre-warm (ops.precompile)
         self.prewarmer = getattr(cache, "prewarmer", None)
-        self.pipeline_solver = getattr(cache, "pipeline_solver", True)
         # resilience seams: the device-path circuit breaker (installed on
         # the cache by the Scheduler; consumed by allocate/evict_solver
         # for the device -> host-oracle degradation ladder), plus the

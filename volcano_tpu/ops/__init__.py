@@ -11,8 +11,7 @@ from .arrays import (  # noqa: F401
 from .ordering import OrderCache  # noqa: F401
 
 _LAZY = ("SolveResult", "fits_matrix", "score_matrix", "solve_allocate",
-         "solve_allocate_sequential", "solve_allocate_packed",
-         "solve_allocate_packed2d")
+         "solve_allocate_sequential", "solve_allocate_packed2d")
 _LAZY_EVICT = ("EvictResult", "solve_evict")
 _LAZY_DEVCACHE = ("PackedDeviceCache", "ShardedDeviceCache",
                   "split_packed_layout")

@@ -231,7 +231,7 @@ class SnapshotArrays:
         """Pack the solver arrays into one f32 buffer + one i32 buffer so the
         per-session host->device transfer is two puts instead of ~20 (the
         per-transfer overhead dominates at small sizes). Returns (fbuf,
-        ibuf, layout); feed to solve_allocate_packed.
+        ibuf, layout); the device arenas (ops.device_cache) take them.
         """
         d = self.device_dict()
         fparts, iparts, layout = [], [], []

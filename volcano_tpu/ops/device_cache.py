@@ -115,6 +115,9 @@ class PackedDeviceCache:
         self.invalidations = 0          # soft resets (collect failures)
         self.params_repins = 0          # device params re-uploaded
         self.total_shipped_bytes = 0
+        # the last plan's pinned params and flags (with its layout)
+        self.last_params: Optional[dict] = None
+        self.last_solve_flags: Optional[dict] = None
 
     # -- arena introspection -------------------------------------------
 
@@ -363,6 +366,50 @@ class PackedDeviceCache:
         """Store the buffers returned by solve_allocate_delta (the inputs
         were donated and are now invalid)."""
         self._dev_f, self._dev_i = f2d, i2d
+
+    # -- the allocate solve over this arena: plan, then dispatch ---------
+
+    #: the turn record's ``arena_mode`` for a session this arena served
+    MODE = "packed"
+
+    def plan(self, fbuf: np.ndarray, ibuf: np.ndarray, layout,
+             params: dict, flags: dict):
+        """Stage one session's solve: pin the score params and diff the
+        snapshot against the resident buffers. ``flags`` are the solve
+        entries' static flags; with ``layout`` they become
+        ``last_solve_flags``, which the bucket prewarmer reads. Returns
+        what ``dispatch`` takes."""
+        params = self.last_params = self.params_device(params)
+        self.last_solve_flags = dict(layout=layout, **flags)
+        return self.plan_delta(fbuf, ibuf, layout), layout, params, flags
+
+    def dispatch(self, staged):
+        """Dispatch the staged solve asynchronously: the fused delta
+        scatter + solve (one dispatch, donating the resident buffers) or,
+        when ``plan_delta`` already applied the scatters, packed2d. A
+        throwing donated dispatch may have consumed the buffers, so it
+        invalidates the arena (the host mirror and the pinned params stay
+        for the next session's re-ship)."""
+        from .solver import solve_allocate_delta, solve_allocate_packed2d
+
+        (kind, payload), layout, params, flags = staged
+        if kind == "updated":
+            return solve_allocate_packed2d(*payload, layout, params, **flags)
+        try:
+            res, new_f, new_i = solve_allocate_delta(
+                *payload, layout, params, **flags)
+        except Exception:
+            self.invalidate()
+            raise
+        self.commit(new_f, new_i)
+        return res
+
+    def record(self, timing: dict) -> None:
+        """The last plan's shipping, as the turn record's arena keys."""
+        timing["delta_chunks"] = float(self.last_shipped_chunks)
+        timing["arena_mode"] = self.MODE
+        timing["arena_bytes_shipped"] = float(self.last_shipped_bytes)
+        timing["arena_full_ship"] = float(self.last_full_ship)
 
     # ------------------------------------------------------------------
     # device-resident score params: the per-session params dict is a few
@@ -719,6 +766,39 @@ class ShardedDeviceCache(PackedDeviceCache):
             int(b + (rep_bytes if chunks else 0)) for b in shard_bytes]
         self._account(chunks, rep_bytes + sum(shard_bytes), full=False)
         return self._assembled(rep_layout, node_layout)
+
+    # -- the allocate solve over this arena: plan, then dispatch ---------
+
+    MODE = "sharded"
+
+    def plan(self, fbuf: np.ndarray, ibuf: np.ndarray, layout,
+             params: dict, flags: dict):
+        bufs = self.update(fbuf, ibuf, layout)
+        params = self.last_params = self.params_device(params)
+        self.last_solve_flags = dict(layout=layout, **flags)
+        return bufs, params, flags
+
+    def dispatch(self, staged):
+        """Dispatch the node-axis ``shard_map`` solve over the resident
+        shards, re-sent once on a transport-marked error
+        (resilience.transient; a device runtime error is never one, so it
+        reaches the breaker at once)."""
+        from ..parallel import solve_allocate_sharded_arena
+        from ..resilience.transient import retry_transient
+
+        bufs, params, flags = staged
+        return retry_transient(
+            lambda: solve_allocate_sharded_arena(*bufs, params, self.mesh,
+                                                 **flags),
+            what="sharded solver dispatch")
+
+    def record(self, timing: dict) -> None:
+        from ..metrics.spans import count
+
+        super().record(timing)
+        count("mesh_devices", self.D)
+        count("shard_bytes_max", max(self.last_shard_bytes))
+        count("shard_bytes_total", sum(self.last_shard_bytes))
 
     def resident_node_arrays(self):
         """The resident node-axis state as the two global ``[D, C, chunk]``

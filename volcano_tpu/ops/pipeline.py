@@ -31,7 +31,7 @@ and results come back strictly FIFO. Callers whose session s+1 inputs
 depend on session s's *results* (the scheduler's allocate action: binds
 feed the next snapshot) must keep collect inside the cycle and only get
 the start_readback overlap; callers with exogenous inputs (the bench's
-churn script, trace replay, the solver sidecar) get the full three-phase
+churn script, trace replay) get the full three-phase
 overlap. Bind-for-bind identity of both shapes against the serial path
 is asserted by tests/test_arena.py.
 """

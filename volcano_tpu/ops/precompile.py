@@ -243,7 +243,7 @@ def solver_cache_size() -> int:
     from . import solver as _s
 
     fns = [_s.solve_allocate, _s.solve_allocate_sequential,
-           _s.solve_allocate_packed, _s.solve_allocate_packed2d,
+           _s.solve_allocate_packed2d,
            _s.solve_allocate_delta]
     try:
         # the sharded entry counts too: sharded-mode sessions dispatch it

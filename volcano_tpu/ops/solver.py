@@ -81,7 +81,6 @@ def _compact(assigned, kind, n_nodes: int):
 
 def decode_compact(compact):
     """host-side: compact int16 -> (assigned int32, kind int32)."""
-    import numpy as np
     c = np.asarray(compact).astype(np.int32)
     if c.size and c[0] == COMPACT_UNAVAILABLE:
         raise ValueError(
@@ -91,6 +90,19 @@ def decode_compact(compact):
     kind = np.where(none, -1, c >> COMPACT_KIND_SHIFT)
     assigned = np.where(none, -1, c & ((1 << COMPACT_KIND_SHIFT) - 1))
     return assigned, kind
+
+
+def collect_assignment(res, n_nodes: int):
+    """host-side: block on any allocate solve's result -> (assigned,
+    kind, rounds). One int16 readback of ``compact`` where the solve
+    packed one and ``n_nodes`` fits its node index; otherwise the int32
+    ``assigned``/``kind`` (the sharded solve packs none, and more than
+    ``1 << COMPACT_KIND_SHIFT`` nodes overflow the packing)."""
+    if res.compact is not None and n_nodes <= (1 << COMPACT_KIND_SHIFT):
+        assigned, kind = decode_compact(res.compact)
+    else:
+        assigned, kind = np.asarray(res.assigned), np.asarray(res.kind)
+    return assigned, kind, int(res.rounds)
 
 
 # ---------------------------------------------------------------------------
@@ -1151,27 +1163,3 @@ def solve_allocate_delta(f2d, i2d, f_idx, f_vals, i_idx, i_vals, layout,
                          use_queue_cap, use_drf_order, use_hdrf_order,
                          work_conserving)
     return res, f2d, i2d
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "layout", "max_rounds", "max_gang_iters", "per_node_cap", "herd_mode",
-    "score_families", "use_queue_cap", "use_drf_order", "use_hdrf_order",
-    "work_conserving"))
-def solve_allocate_packed(fbuf, ibuf, layout,
-                          score_params: Dict[str, jnp.ndarray],
-                          max_rounds: int = 64,
-                          max_gang_iters: int = 12,
-                          per_node_cap: int = 0,
-                          herd_mode: str = "pack",
-                          score_families: Tuple[str, ...] = ("binpack",),
-                          use_queue_cap: bool = False,
-                          use_drf_order: bool = False,
-                          use_hdrf_order: bool = False,
-                          work_conserving: bool = True) -> SolveResult:
-    """solve_allocate over buffers produced by SnapshotArrays.packed():
-    the unpack is free on device (slices fuse), the transfer is 2 puts."""
-    arrays = _unpack(fbuf, ibuf, layout)
-    return solve_allocate(arrays, score_params, max_rounds, max_gang_iters,
-                          per_node_cap, herd_mode, score_families,
-                          use_queue_cap, use_drf_order, use_hdrf_order,
-                          work_conserving)

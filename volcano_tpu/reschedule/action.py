@@ -225,10 +225,7 @@ class RescheduleAction(Action):
     def _solve(self, ssn, state: _State, arr):
         from ..actions.allocate import build_score_inputs
         from ..ops.device_cache import PackedDeviceCache
-        from ..ops.solver import (
-            COMPACT_KIND_SHIFT, decode_compact, solve_allocate_delta,
-            solve_allocate_packed2d,
-        )
+        from ..ops.solver import collect_assignment
 
         params, families = build_score_inputs(ssn, arr)
         if float(params["binpack_weight"]) == 0.0:
@@ -243,29 +240,11 @@ class RescheduleAction(Action):
         dc = state.device_cache
         faults.fire("reschedule_dispatch")
         fbuf, ibuf, layout = arr.packed()
-        params = dc.params_device(params)
-        kind_, payload = dc.plan_delta(fbuf, ibuf, layout)
-        kwargs = dict(herd_mode="pack", score_families=families,
-                      use_queue_cap=False, use_drf_order=False,
-                      use_hdrf_order=False, work_conserving=True)
-        if kind_ == "updated":
-            f2d, i2d = payload
-            res = solve_allocate_packed2d(f2d, i2d, layout, params,
-                                          **kwargs)
-        else:
-            f2d, i2d, fi, fv, ii, iv = payload
-            try:
-                res, new_f, new_i = solve_allocate_delta(
-                    f2d, i2d, fi, fv, ii, iv, layout, params, **kwargs)
-            except Exception:
-                dc.invalidate()  # donation may have consumed the buffers
-                raise
-            dc.commit(new_f, new_i)
-        if arr.N <= (1 << COMPACT_KIND_SHIFT):
-            assigned, kind = decode_compact(res.compact)
-        else:
-            assigned = np.asarray(res.assigned)
-            kind = np.asarray(res.kind)
+        flags = dict(herd_mode="pack", score_families=families,
+                     use_queue_cap=False, use_drf_order=False,
+                     use_hdrf_order=False, work_conserving=True)
+        res = dc.dispatch(dc.plan(fbuf, ibuf, layout, params, flags))
+        assigned, kind, _ = collect_assignment(res, arr.N)
         from ..actions.allocate import AllocateAction
         AllocateAction._check_solver_output(
             assigned, kind, arr.T, len(arr.nodes_list))

@@ -1,7 +1,7 @@
 """Transient-failure classification + one-shot retry.
 
-A call that crosses a wire (the remote-store client, a solver sidecar's
-socket) fails in two distinct ways: *transient* transport hiccups — a
+A call that crosses a wire (the remote-store client, a multi-device
+dispatch) fails in two distinct ways: *transient* transport hiccups — a
 half-closed socket, a deadline — that succeed when simply re-sent, and
 *real* faults that must count against the circuit breaker and degrade to
 the host oracle. A device runtime error on the locally attached chip (an

@@ -18,9 +18,8 @@ where an operator's crash tooling collects it) and raises
 The abandoned thread is daemonic and eventually dies with its blocking
 call; until then it may still read session state — the epoch guard is
 what keeps it from *writing through* to the cluster. True isolation
-needs a process boundary (the solver sidecar provides one for the
-biggest hang source, the device dispatch); this watchdog covers the
-in-process rest.
+would need a process boundary; the scheduler process owns the chip and
+runs the device dispatch in-process, so this watchdog covers all of it.
 
 Without a deadline the scheduler runs actions inline exactly as before —
 the watchdog costs nothing unless asked for.

@@ -33,7 +33,6 @@ class Scheduler:
                  percentage_of_nodes_to_find: int = 100,
                  compile_cache_dir: Optional[str] = None,
                  prewarm: bool = False,
-                 pipeline_solver: bool = True,
                  action_deadline_s: Optional[float] = None,
                  breaker_failures: int = 3,
                  breaker_cooldown_s: float = 30.0,
@@ -103,15 +102,12 @@ class Scheduler:
         # compile-and-dispatch pipeline (ops.precompile): persistent
         # on-disk XLA executable cache (explicit dir or
         # $JAX_COMPILATION_CACHE_DIR; off otherwise — the entry points
-        # configure their fixed default before building a Scheduler),
-        # background next-bucket pre-warm,
-        # and the allocate action's dispatch/collect overlap. All three
-        # are pure-latency features — scheduling decisions are identical
-        # with them on or off (tests/test_precompile.py parity).
+        # configure their fixed default before building a Scheduler) and
+        # background next-bucket pre-warm. Both are pure-latency features:
+        # scheduling decisions are identical with them on or off.
         from .ops import precompile as _pc
         self.compile_cache_dir = _pc.configure_compilation_cache(
             compile_cache_dir)
-        cache.pipeline_solver = bool(pipeline_solver)
         if prewarm and getattr(cache, "prewarmer", None) is None:
             cache.prewarmer = _pc.BucketPrewarmer()
         if prewarm or self.compile_cache_dir:

@@ -7,11 +7,11 @@ plays the API server, and around it run
 
 - the admission chain (in-process interceptors + optional TLS server),
 - the controller manager (job/queue/podgroup/kubelet-standin/gc),
-- the scheduler loop (solver on the local chip or via the solver sidecar),
+- the scheduler loop (the solver on the chip this process owns),
 - the metrics endpoint (/metrics, /healthz, /debug/stacks).
 
 ``python -m volcano_tpu.standalone [--conf scheduler.yaml] [--period 1.0]
-[--serve-webhooks] [--sidecar /path/to.sock] [--metrics-port 8080]``
+[--serve-webhooks] [--metrics-port 8080]``
 
 Jobs are submitted with the in-process CLI against the same store when
 embedding, or by pointing --jobs-dir at a directory of job YAMLs (each
@@ -35,7 +35,6 @@ log = logging.getLogger(__name__)
 class Standalone:
     def __init__(self, scheduler_conf: Optional[str] = None,
                  period: float = 1.0, serve_webhooks_tls: bool = False,
-                 sidecar_path: Optional[str] = None,
                  metrics_port: int = 0,
                  async_effectors: bool = True,
                  serve_store: Optional[str] = None,
@@ -48,7 +47,6 @@ class Standalone:
                  leader_elect: bool = False,
                  compile_cache_dir: Optional[str] = None,
                  prewarm: bool = False,
-                 pipeline_solver: bool = True,
                  pipeline_effects: bool = False,
                  action_deadline_s: Optional[float] = None,
                  breaker_failures: int = 3,
@@ -284,9 +282,6 @@ class Standalone:
                    for pc in wl.priority_class_objects()]
                 + [("nodes", node) for node in wl.node_objects()
                    if self.store.try_get("nodes", node.name) is None])
-        if sidecar_path:
-            from .parallel.sidecar import SidecarSolver
-            self.cache.sidecar = SidecarSolver(sidecar_path)
         self.cache.run()
         # controller traffic rides the CONTROL admission lane: when the
         # store is a remote client (shard-procs mode) the LaneStore view
@@ -320,7 +315,6 @@ class Standalone:
             self.cache, scheduler_conf=scheduler_conf, period=period,
             percentage_of_nodes_to_find=percentage_of_nodes_to_find,
             compile_cache_dir=compile_cache_dir, prewarm=prewarm,
-            pipeline_solver=pipeline_solver,
             action_deadline_s=action_deadline_s,
             breaker_failures=breaker_failures,
             breaker_cooldown_s=breaker_cooldown_s,
@@ -489,7 +483,6 @@ def main(argv=None) -> int:
     ap.add_argument("--period", type=float, default=1.0)
     ap.add_argument("--serve-webhooks", action="store_true",
                     help="also serve admission over TLS")
-    ap.add_argument("--sidecar", help="solver sidecar socket path")
     ap.add_argument("--metrics-port", type=int, default=8080)
     ap.add_argument("--jobs-dir", help="apply every .yaml job in this dir")
     ap.add_argument("--webhook-client-ca", metavar="CA_PEM",
@@ -622,9 +615,6 @@ def main(argv=None) -> int:
                     help="compile the next compile-bucket's solver "
                          "variants on a background thread when occupancy "
                          "nears the current bucket")
-    ap.add_argument("--serial-solver", action="store_true",
-                    help="disable the allocate dispatch/collect overlap "
-                         "(debug/parity; decisions are identical)")
     ap.add_argument("--pipeline-effects", action="store_true",
                     help="overlap async bind writes with the next "
                          "control-plane turn instead of draining between "
@@ -713,7 +703,6 @@ def main(argv=None) -> int:
         args.compile_cache_dir, default_dir=precompile.ENTRY_POINT_CACHE_DIR)
     sa = Standalone(scheduler_conf=conf, period=args.period,
                     serve_webhooks_tls=args.serve_webhooks,
-                    sidecar_path=args.sidecar,
                     metrics_port=args.metrics_port,
                     serve_store=args.serve_store,
                     webhook_client_ca=args.webhook_client_ca,
@@ -724,7 +713,6 @@ def main(argv=None) -> int:
                     leader_elect=args.leader_elect,
                     compile_cache_dir=args.compile_cache_dir,
                     prewarm=args.prewarm,
-                    pipeline_solver=not args.serial_solver,
                     pipeline_effects=args.pipeline_effects,
                     action_deadline_s=args.action_deadline,
                     breaker_failures=args.breaker_failures,
