@@ -1,11 +1,15 @@
 """Session/Statement/tier-dispatch tests."""
 
+import time
+
 import numpy as np
 import pytest
 
 from volcano_tpu.api import TaskStatus
 from volcano_tpu.cache import FakeBinder, FakeEvictor, SchedulerCache
-from volcano_tpu.client import ClusterStore
+from volcano_tpu.client import (
+    ClusterStore, FencedStore, RemoteClusterStore, ShardedClusterStore,
+)
 from volcano_tpu.conf import PluginOption, Tier
 from volcano_tpu.framework import (
     Arguments, EventHandler, Plugin, ValidateResult, close_session,
@@ -656,3 +660,114 @@ class TestJobUpdaterDirtySkip:
         sched.run_once()  # the job stays unready; conditions must re-post
         pod = store.get("pods", "big-0", "ns")
         assert any(c.get("type") == "PodScheduled" for c in pod.conditions)
+
+
+class _WireStore(ClusterStore):
+    """An in-memory store declared to write across a process boundary, as
+    RemoteClusterStore does: the job updater fans out over its pool."""
+
+    crosses_process = True
+
+
+class TestJobUpdaterStoreKind:
+    """Session close makes its status writes in the scheduler's thread when
+    the store is in this process, and keeps the 16-thread pool for a store
+    reached over a wire; both write the same statuses and conditions."""
+
+    @staticmethod
+    def _close(store, monkeypatch, synced=lambda cache: True):
+        """One turn over 6 two-pod gangs on a 4-cpu node: two bind, four
+        stay unready and get Unschedulable conditions, so all six are
+        dirty at close. Returns the statuses, the pods' PodScheduled
+        conditions, the turn record and the pool's creations."""
+        from volcano_tpu.framework import job_updater
+        from volcano_tpu.scheduler import Scheduler
+
+        pools = []
+        real = job_updater._shared_pool
+
+        def counted_pool():
+            pools.append(1)
+            return real()
+
+        monkeypatch.setattr(job_updater, "_shared_pool", counted_pool)
+        cache = SchedulerCache(store)
+        cache.evictor = FakeEvictor()
+        cache.run()
+        store.create("nodes", build_node("n1", {"cpu": "4", "memory": "16Gi"}))
+        for j in range(6):
+            store.create("podgroups", build_pod_group(f"j{j}", "ns",
+                                                      min_member=2))
+            for i in range(2):
+                store.create("pods", build_pod(
+                    "ns", f"j{j}-{i}", "", "Pending",
+                    {"cpu": "1", "memory": "1Gi"}, f"j{j}"))
+        deadline = time.monotonic() + 20.0
+        while not synced(cache) and time.monotonic() < deadline:
+            time.sleep(0.01)
+        sched = Scheduler(cache)
+        sched.run_once()
+        statuses = {pg.name: pg.status.fingerprint()
+                    for pg in store.list("podgroups")}
+        conditions = {p.name: [c for c in p.conditions
+                               if c.get("type") == "PodScheduled"]
+                      for p in store.list("pods")}
+        return statuses, conditions, sched.last_cycle_timing, len(pools)
+
+    def test_close_writes_the_same_status_on_either_path(self, monkeypatch):
+        statuses, conditions, timing, pools = self._close(ClusterStore(),
+                                                          monkeypatch)
+        wire = self._close(_WireStore(), monkeypatch)
+        assert (statuses, conditions) == wire[:2]
+        unready = [n for n, c in conditions.items() if c]
+        assert len(unready) == 8, "four gangs stay unschedulable"
+        assert all(c[0]["reason"] == "Unschedulable"
+                   for c in conditions.values() if c)
+
+        for turn in (timing, wire[2]):
+            assert turn["updater_jobs"] == 6
+            assert "volcano.session.close.update" in turn
+            assert turn["close_update_ms"] > 0.0
+        assert timing["updater_inline"] == timing["updater_jobs"]
+        assert pools == 0, "an in-process close never makes the pool"
+        assert wire[2]["updater_inline"] == 0
+        assert wire[3] == 1
+
+    def test_remote_store_writes_through_the_pool(self, monkeypatch):
+        """Through a real RemoteClusterStore the same close takes the pool
+        and writes what the in-process close writes."""
+        from volcano_tpu.client import StoreServer
+
+        local = self._close(ClusterStore(), monkeypatch)
+        server = StoreServer(ClusterStore()).start()
+        remote = RemoteClusterStore(f"127.0.0.1:{server.port}")
+        try:
+            statuses, conditions, timing, pools = self._close(
+                remote, monkeypatch, synced=lambda cache: len(cache.nodes) == 1
+                and sum(len(j.tasks) == 2 and j.pod_group is not None
+                        for j in cache.jobs.values()) == 6)
+        finally:
+            remote.close()
+            server.stop()
+        assert (statuses, conditions) == local[:2]
+        assert timing["updater_jobs"] == 6
+        assert timing["updater_inline"] == 0
+        assert pools == 1
+
+    @pytest.mark.parametrize("store,expected", [
+        (lambda: ClusterStore(), False),
+        (lambda: FencedStore(ClusterStore(), lambda: None), False),
+        (lambda: ShardedClusterStore(2), False),
+        (lambda: FencedStore(_WireStore(), lambda: None), True),
+        (lambda: RemoteClusterStore.__new__(RemoteClusterStore), True),
+    ], ids=["store", "fenced_store", "sharded_store", "fenced_wire_store",
+            "remote_store"])
+    def test_store_kind_is_read_through_the_status_updater(self, store,
+                                                           expected):
+        from types import SimpleNamespace
+
+        from volcano_tpu.framework.job_updater import writes_cross_process
+
+        cache = SimpleNamespace(
+            status_updater=SimpleNamespace(cluster=store()))
+        assert writes_cross_process(cache) is expected

@@ -111,6 +111,9 @@ class RemoteClusterStore:
       entirely; each stream resumes against its own worker's journal.
     """
 
+    #: every write is a round trip to the server (ClusterStore.crosses_process)
+    crosses_process = True
+
     def __init__(self, address: str, connect_timeout: float = 5.0,
                  token: Optional[str] = None,
                  on_watch_failure: Optional[Callable[[], None]] = None,

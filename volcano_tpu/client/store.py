@@ -87,10 +87,14 @@ def _key(obj) -> str:
 class ClusterStore:
     """Typed object buckets + watch listeners. Writes serialize under one
     reentrant lock: the normal control flow is single-threaded (ordering
-    deterministic, informer-delta semantics testable), but the job-updater
-    fan-out and async effectors may write concurrently — each write
-    (admission + mutation + listener delivery) is atomic under the lock,
-    like one API-server request."""
+    deterministic, informer-delta semantics testable), but async effectors
+    may write concurrently — each write (admission + mutation + listener
+    delivery) is atomic under the lock, like one API-server request."""
+
+    #: whether a write leaves this process (a network or IPC round trip).
+    #: The job updater overlaps such writes on its pool and makes the
+    #: others in the calling thread (framework/job_updater.py).
+    crosses_process = False
 
     def __init__(self):
         import threading
@@ -400,5 +404,6 @@ class FencedStore:
         return self._store.bulk_apply(items, fencing=self._token())
 
     def __getattr__(self, name):
-        # reads (get/try_get/list/watch/locked/...) forward unfenced
+        # reads (get/try_get/list/watch/locked/...) forward unfenced, and
+        # so does the wrapped store's crosses_process
         return getattr(self._store, name)

@@ -7,6 +7,7 @@ import time
 from typing import List
 
 from ..conf import Tier
+from ..metrics.spans import span
 from .arguments import Arguments
 from .job_updater import JobUpdater
 from .registry import get_plugin_builder
@@ -58,8 +59,8 @@ def close_session(ssn: Session) -> None:
         except Exception:
             log.exception("decision recorder observe_session failed")
 
-    ju = JobUpdater(ssn)
-    ju.update_all()
+    with span("volcano.session.close.update", "close_update_ms"):
+        JobUpdater(ssn).update_all()
 
     ssn.jobs = {}
     ssn.nodes = {}
