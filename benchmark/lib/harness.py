@@ -5,6 +5,12 @@ metric readers and the reference.
 Nothing here knows a cell by name. A cell is an entry of ``BENCHMARK.json``;
 its configuration is ``configs/<config>.json``, its traffic
 ``traffic/<traffic>.json`` and each metric ``metrics/<name>.py``.
+
+A configuration's ``nodes`` is one pool or a list of pools, each
+``{count, cpu, memory, pods, <extended resource>: <quantity>, ...}``; its
+pod templates may ask for extended resources too. A traffic file's
+``mode`` picks the loop: ``closed`` (the default, ``run_closed``) or
+``steady`` (``run_steady``).
 """
 
 from __future__ import annotations
@@ -205,20 +211,52 @@ def off_device(timing: dict) -> bool:
 # the cluster
 # ---------------------------------------------------------------------------
 
+def _extended(name: str) -> bool:
+    """An extended resource (``nvidia.com/gpu``): a name with a domain,
+    which Kubernetes requires in a container's limits as in its
+    requests."""
+    return "/" in name
+
+
 def _job_object(d: gen.JobDraw):
     from volcano_tpu.models import Job, JobSpec, TaskSpec
 
+    container = {"name": "c", "requests": dict(d.requests)}
+    limits = {r: q for r, q in d.requests.items() if _extended(r)}
+    if limits:
+        container["limits"] = limits
     task = TaskSpec(name="task", replicas=d.size, template={"spec": {
-        "containers": [{"name": "c", "requests": {
-            "cpu": d.cpu, "memory": d.memory}}]}})
+        "containers": [container]}})
     return Job(name=d.name, namespace="default", spec=JobSpec(
         min_available=d.min_available, tasks=[task], queue=d.queue,
         priority_class_name=d.priority_class))
 
 
+_POOL_SHAPE = ("count", "cpu", "memory", "pods")
+
+
+def node_pools(config: dict) -> List[dict]:
+    """The configuration's node pools: ``nodes`` is one pool or a list."""
+    n = config["nodes"]
+    return list(n) if isinstance(n, list) else [n]
+
+
+def resources(config: dict) -> Tuple[str, ...]:
+    """The configuration's resource names (``reference.resource_names``):
+    every extended resource a pool has or a pod template asks for."""
+    ext = {r for pool in node_pools(config) for r in pool
+           if r not in _POOL_SHAPE}
+    ext |= {r for tpl in config["pods"].values() for r in tpl
+            if r != "priority_class"}
+    return ref.resource_names(ext)
+
+
 class Cluster:
     """The configuration's cluster behind one ``Standalone`` control plane
-    (in-process store, admission, controllers, scheduler, effectors)."""
+    (in-process store, admission, controllers, scheduler, effectors).
+    Nodes are ``n0``, ``n1``, ... with one index across the pools in
+    their order; ``nodes`` holds each one's size vector over
+    ``resources``."""
 
     def __init__(self, config: dict, watcher: Watcher):
         from volcano_tpu.controllers import KubeletStandin
@@ -239,16 +277,20 @@ class Cluster:
         for name, weight in config.get("queues", []):
             self.store.create("queues", Queue(name=name,
                                               spec=QueueSpec(weight=weight)))
-        n = config["nodes"]
-        self.nodes: Dict[str, Tuple[float, float, float]] = {}
-        for i in range(int(n["count"])):
-            rl = {"cpu": str(n["cpu"]), "memory": n["memory"],
-                  "pods": str(n["pods"])}
-            self.store.create("nodes", Node(name=f"n{i}", allocatable=rl,
-                                            capacity=dict(rl)))
-            self.nodes[f"n{i}"] = (ref.parse_quantity(n["cpu"]),
-                                   ref.parse_quantity(n["memory"]),
-                                   float(n["pods"]))
+        self.resources = resources(config)
+        self.nodes: Dict[str, Tuple[float, ...]] = {}
+        for pool in node_pools(config):
+            rl = {"cpu": str(pool["cpu"]), "memory": pool["memory"],
+                  "pods": str(pool["pods"])}
+            rl.update((r, str(q)) for r, q in pool.items()
+                      if r not in _POOL_SHAPE)
+            size = tuple(ref.parse_quantity(rl.get(r, 0))
+                         for r in self.resources)
+            for _ in range(int(pool["count"])):
+                name = f"n{len(self.nodes)}"
+                self.store.create("nodes", Node(
+                    name=name, allocatable=dict(rl), capacity=dict(rl)))
+                self.nodes[name] = size
         self.store.watch("pods", watcher.on_pod, replay=False)
         self.turns: List[Turn] = []
         self._free = {n: list(c) for n, c in self.nodes.items()}
@@ -296,26 +338,26 @@ class Cluster:
         error."""
         names = list(self.nodes)
         free = self._free
+        dims = range(len(self.resources))
         for d in jobs:
-            req = (ref.parse_quantity(d.cpu), ref.parse_quantity(d.memory),
-                   1.0)
+            req = ref.request_vector(d.requests, self.resources)
             placed = []
             for i in range(d.size):
                 for step in range(len(names)):
                     node = names[(self._k + step) % len(names)]
                     f = free[node]
-                    if all(f[r] >= req[r] for r in range(3)):
+                    if all(f[r] >= req[r] for r in dims):
                         break
                 else:
                     break
-                for r in range(3):
+                for r in dims:
                     f[r] -= req[r]
                 placed.append(node)
                 self._k = (self._k + step + (1 if stripe else 0)) \
                     % len(names)
             if len(placed) < d.size:
                 for node in placed:
-                    for r in range(3):
+                    for r in dims:
                         free[node][r] += req[r]
                 if not fill:
                     raise CellError(f"prefill does not fit: {d.name}")
@@ -432,8 +474,9 @@ class Profiler:
 def _facts(cluster: Cluster, jobs: List[gen.JobDraw], measured: set
            ) -> Dict[str, ref.JobFacts]:
     prio = cluster.config.get("priority_classes", {})
-    return {d.name: ref.JobFacts(d.min_available, ref.parse_quantity(d.cpu),
-                                 ref.parse_quantity(d.memory),
+    return {d.name: ref.JobFacts(d.min_available,
+                                 ref.request_vector(d.requests,
+                                                    cluster.resources),
                                  int(prio.get(d.priority_class, 0)),
                                  d.name in measured)
             for d in jobs}
@@ -537,6 +580,91 @@ def run_closed(cluster: Cluster, traffic: dict, seed: int, seconds: float,
     return run, facts
 
 
+def run_steady(cluster: Cluster, traffic: dict, seed: int, seconds: float,
+               watcher: Watcher, prof: Profiler, t_proc0: float
+               ) -> Tuple[Run, Dict[str, ref.JobFacts]]:
+    """A full cluster under churn. Before every turn the generator submits
+    the stream's next jobs (``steady_block``) until ``backlog_jobs`` of
+    those submitted have not started. A job runs ``lifetime_turns`` turns,
+    counted from the first turn end after the watcher saw it start, and
+    then finishes (``Cluster.finish``). The window opens at the end of
+    turn ``lead_in_turns`` and closes at the first turn end at or after
+    ``seconds``; the jobs submitted in it are measured. Then turns go on
+    with no submissions, jobs still finishing, until every measured job
+    has started or ``drain_s`` has passed."""
+    log = watcher.log
+    pods = cluster.config["pods"]
+    pre = _prefill(cluster, seed, log)
+    drain = float(traffic["drain_s"])
+    backlog = int(traffic["backlog_jobs"])
+    lead_turns = int(traffic["lead_in_turns"])
+    stream: List[gen.JobDraw] = []       # the block being submitted
+    all_jobs: List[gen.JobDraw] = []
+    waiting: Dict[str, gen.JobDraw] = {}  # submitted, not yet started
+    ends: Dict[int, List[gen.JobDraw]] = {}  # turn -> jobs that end there
+    measured = set()
+    refused = set()
+    block = turns = finished = 0
+    w0 = w1 = None
+    binds = binds0 = compiles = c0 = 0
+    window_turns: List[Turn] = []
+    while True:
+        if w1 is None:
+            with prof.span("bench.submit"):
+                while len(waiting) < backlog:
+                    if not stream:
+                        stream = gen.steady_block(traffic, seed, block,
+                                                  pods)[::-1]
+                        block += 1
+                    d = stream.pop()
+                    watcher.min_of[d.name] = d.min_available
+                    all_jobs.append(d)
+                    if w0 is not None:
+                        measured.add(d.name)
+                    if cluster.submit(d):
+                        waiting[d.name] = d
+                    else:
+                        refused.add(d.name)
+        tr = cluster.turn(log)
+        turns += 1
+        if w0 is not None and w1 is None:
+            window_turns.append(tr)
+        for name in [n for n in waiting if n in watcher.started]:
+            d = waiting.pop(name)
+            ends.setdefault(turns + d.lifetime_turns, []).append(d)
+        with prof.span("bench.finish"):
+            for d in ends.pop(turns, ()):
+                cluster.finish(d, log)
+                if w0 is not None and w1 is None:
+                    finished += 1
+        if w0 is None:
+            if turns >= lead_turns:
+                w0 = clock()
+                binds0 = watcher.binds
+                c0 = _compiles()
+                prof.start()
+        elif w1 is None and clock() - w0 >= seconds:
+            w1 = clock()
+            binds = watcher.binds - binds0
+            compiles = _compiles() - c0
+            prof.stop()
+            backlog_at_close = len(waiting)
+        if w1 is not None and (not any(n in measured for n in waiting)
+                               or clock() - w1 > drain):
+            break
+    failed = sum(1 for name in measured
+                 if name not in watcher.started or name in refused)
+    run = Run(seconds=w1 - w0, setup_s=w0 - t_proc0, turns=window_turns,
+              binds=binds, attempted=len(measured), failed=failed,
+              compiles=compiles)
+    run.extra.update(jobs_finished=finished, backlog_at_close=backlog_at_close,
+                     refused=len(refused))
+    return run, _facts(cluster, pre + all_jobs, measured)
+
+
+LOOPS = {"closed": run_closed, "steady": run_steady}
+
+
 def _compiles() -> int:
     from volcano_tpu.ops.precompile import watcher
 
@@ -571,6 +699,10 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
     where to save the raw trace, and a compile cache other than the
     checkout's ``.jax_cache``."""
     cell = cell or load_cell(workload)
+    mode = cell.traffic.get("mode", "closed")
+    if mode not in LOOPS:
+        raise CellError(f"traffic mode {mode!r} is not one of {list(LOOPS)}")
+    loop = LOOPS[mode]
     dev = device_info(cell.chips, require_chip)
     from volcano_tpu.ops import precompile
 
@@ -589,8 +721,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool,
             install_spans(cluster)
         if plant is not None:
             plant(cluster)
-        run, facts = run_closed(cluster, cell.traffic, seed, seconds,
-                                watcher, prof, t_proc0)
+        run, facts = loop(cluster, cell.traffic, seed, seconds, watcher,
+                          prof, t_proc0)
         final = [(p.name, p.node_name) for p in cluster.store.list("pods")
                  if p.node_name and p.deletion_timestamp is None]
         peak = cluster.device_peak()

@@ -8,12 +8,18 @@ the configuration and traffic files, and the events are what a watcher of
 the store saw (binds, deletion marks, deletions), in order, with the
 harness's own turn and job-deletion markers between them.
 
+A node's size and a job's per-pod request are each one vector over the
+configuration's resource names (``resource_names``): cpu, memory, pod
+slots, then the extended resources (``nvidia.com/gpu``, ...) in sorted
+order. A pod takes one slot.
+
 Each count it returns is compared with its limit (0: the comparison is
 exact):
 
-- ``over_capacity``: binds after which a node's pods request more cpu,
-  memory or pod slots than the node has, or that name no known node,
-  plus the nodes over their allocatable in the store at the end;
+- ``over_capacity``: binds after which a node's pods request more of some
+  resource (cpu, memory, pod slots, an extended resource) than the node
+  has, or that name no known node, plus the nodes over their allocatable
+  in the store at the end;
 - ``gang_partial``: (turn end, job) pairs at which a live job had some but
   fewer than ``minAvailable`` pods bound, evicted or not;
 - ``double_bind``: binds of a pod that was already bound elsewhere;
@@ -29,7 +35,7 @@ exact):
 
 from __future__ import annotations
 
-from typing import Dict, Iterable, List, Sequence, Tuple
+from typing import Dict, Iterable, List, Mapping, Sequence, Tuple
 
 # event kinds in the log (kept small: the watcher appends in the window)
 ADD, BIND, MARK, DELETE, TURN, JOBDEL = range(6)
@@ -50,15 +56,34 @@ def parse_quantity(q) -> float:
     return float(s)
 
 
+BASE_RESOURCES = ("cpu", "memory", "pods")
+
+
+def resource_names(extended: Iterable[str]) -> Tuple[str, ...]:
+    """The axes of every vector: cpu, memory, pods, then the extended
+    resources in sorted order."""
+    return BASE_RESOURCES + tuple(sorted(set(extended)
+                                         - set(BASE_RESOURCES)))
+
+
+def request_vector(requests: Mapping[str, object],
+                   names: Sequence[str]) -> Tuple[float, ...]:
+    """A pod's request over ``names``: its one pod slot, and 0 for a
+    resource it does not ask for."""
+    return tuple(1.0 if n == "pods" else parse_quantity(requests.get(n, 0))
+                 for n in names)
+
+
 class JobFacts:
-    """What the reference knows of a job, from the traffic file."""
+    """What the reference knows of a job, from the traffic file: ``req``
+    is one pod's request vector (``request_vector``)."""
 
     __slots__ = ("min_available", "req", "priority", "measured")
 
-    def __init__(self, min_available: int, cpu: float, mem: float,
+    def __init__(self, min_available: int, req: Sequence[float],
                  priority: int, measured: bool):
         self.min_available = min_available
-        self.req = (cpu, mem, 1.0)
+        self.req = tuple(req)
         self.priority = priority
         self.measured = measured
 
@@ -68,12 +93,16 @@ def job_of(pod_name: str) -> str:
     return pod_name.rsplit("-", 2)[0]
 
 
-def check(nodes: Dict[str, Tuple[float, float, float]],
+def _over(u: Sequence[float], cap: Sequence[float]) -> bool:
+    return any(x > c * (1 + 1e-9) for x, c in zip(u, cap))
+
+
+def check(nodes: Dict[str, Tuple[float, ...]],
           jobs: Dict[str, JobFacts],
           log: Sequence[tuple]) -> Dict[str, int]:
-    """Replay ``log`` against ``nodes`` (name -> cpu, memory, pods) and
+    """Replay ``log`` against ``nodes`` (name -> size vector) and
     ``jobs``; return the count of each broken guarantee."""
-    used = {n: [0.0, 0.0, 0.0] for n in nodes}
+    used = {n: [0.0] * len(c) for n, c in nodes.items()}
     pods: Dict[str, list] = {}          # name -> [node or "", marked]
     bound: Dict[str, int] = {}          # job -> pods bound, not marked
     started = set()
@@ -84,7 +113,7 @@ def check(nodes: Dict[str, Tuple[float, float, float]],
     # priority there: (priority, request); and the requests of pods marked
     # for deletion but not yet gone, still in ``used``
     victims: Dict[str, List[tuple]] = {}
-    leaving = {n: [0.0, 0.0, 0.0] for n in nodes}
+    leaving = {n: [0.0] * len(c) for n, c in nodes.items()}
     out = {"over_capacity": 0, "gang_partial": 0, "double_bind": 0,
            "evict_priority": 0, "over_evicted": 0, "never_started": 0}
 
@@ -94,8 +123,8 @@ def check(nodes: Dict[str, Tuple[float, float, float]],
     def release(node: str, req) -> None:
         u = used.get(node)
         if u is not None:
-            for i in range(3):
-                u[i] -= req[i]
+            for i, r in enumerate(req):
+                u[i] -= r
 
     for ev in log:
         kind = ev[0]
@@ -142,18 +171,18 @@ def check(nodes: Dict[str, Tuple[float, float, float]],
                 out["over_capacity"] += 1
             else:
                 cap = nodes[node]
-                for i in range(3):
-                    u[i] += facts.req[i]
-                if any(u[i] > cap[i] * (1 + 1e-9) for i in range(3)):
+                for i, r in enumerate(facts.req):
+                    u[i] += r
+                if _over(u, cap):
                     out["over_capacity"] += 1
                 freed = [v for v in victims.get(node, ())
                          if v[0] < facts.priority]
                 if freed:
                     victims[node] = [v for v in victims[node]
                                      if v[0] >= facts.priority]
-                    live = [u[i] - leaving[node][i] for i in range(3)]
-                    if any(all(live[i] + v[1][i] <= cap[i] * (1 + 1e-9)
-                               for i in range(3)) for v in freed):
+                    live = [x - y for x, y in zip(u, leaving[node])]
+                    if any(not _over([x + r for x, r in zip(live, v[1])],
+                                     cap) for v in freed):
                         out["over_evicted"] += 1
             if not st[1]:
                 c = bound[job] = bound.get(job, 0) + 1
@@ -169,8 +198,8 @@ def check(nodes: Dict[str, Tuple[float, float, float]],
                 touched.add(job)
                 lv = leaving.get(st[0])
                 if lv is not None:
-                    for i in range(3):
-                        lv[i] += facts.req[i]
+                    for i, r in enumerate(facts.req):
+                        lv[i] += r
                 if job not in deleted_jobs:
                     victims.setdefault(st[0], []).append(
                         (facts.priority, facts.req))
@@ -184,8 +213,8 @@ def check(nodes: Dict[str, Tuple[float, float, float]],
                 release(st[0], facts.req)
                 lv = leaving.get(st[0])
                 if st[1] and lv is not None:
-                    for i in range(3):
-                        lv[i] -= facts.req[i]
+                    for i, r in enumerate(facts.req):
+                        lv[i] -= r
                 if not st[1]:
                     bound[job] -= 1
                     touched.add(job)
@@ -197,7 +226,7 @@ def check(nodes: Dict[str, Tuple[float, float, float]],
     return out
 
 
-def over_capacity_now(nodes: Dict[str, Tuple[float, float, float]],
+def over_capacity_now(nodes: Dict[str, Tuple[float, ...]],
                       jobs: Dict[str, JobFacts],
                       bound: Iterable[Tuple[str, str]]) -> int:
     """Nodes over their allocatable in the store as it stands at the end
@@ -212,12 +241,11 @@ def over_capacity_now(nodes: Dict[str, Tuple[float, float, float]],
         if node not in nodes:
             out += 1
             continue
-        u = used.setdefault(node, [0.0, 0.0, 0.0])
-        for i in range(3):
-            u[i] += facts.req[i]
+        u = used.setdefault(node, [0.0] * len(nodes[node]))
+        for i, r in enumerate(facts.req):
+            u[i] += r
     for node, u in used.items():
-        cap = nodes[node]
-        if any(u[i] > cap[i] * (1 + 1e-9) for i in range(3)):
+        if _over(u, nodes[node]):
             out += 1
     return out
 

@@ -17,7 +17,7 @@ NODES = {"n0": (4.0, 8.0 * 2 ** 30, 110.0), "n1": (4.0, 8.0 * 2 ** 30, 110.0)}
 
 
 def facts(min_available=2, cpu=1.0, prio=0, measured=True):
-    return ref.JobFacts(min_available, cpu, 2.0 ** 30, prio, measured)
+    return ref.JobFacts(min_available, (cpu, 2.0 ** 30, 1.0), prio, measured)
 
 
 def bind(name, node, t=0.0):
@@ -121,9 +121,9 @@ def _preempt_log(victims):
 
 
 def _preempt_jobs():
-    jobs = {f"lo{i}": ref.JobFacts(1, 0.9, 2.0 ** 29, 0, False)
+    jobs = {f"lo{i}": ref.JobFacts(1, (0.9, 2.0 ** 29, 1.0), 0, False)
             for i in range(4)}
-    jobs["hi"] = ref.JobFacts(1, 3.0, 2.0 ** 29, 10, True)
+    jobs["hi"] = ref.JobFacts(1, (3.0, 2.0 ** 29, 1.0), 10, True)
     return jobs
 
 
@@ -178,15 +178,19 @@ MIX = {"wave_jobs": 6, "queues": ["q0"],
 def test_waves_are_independent_of_history():
     w = gen.wave(MIX, 3, 4, PODS)
     assert [j.name for j in w][:2] == ["w4-0", "w4-1"]
-    assert sorted(j.cpu for j in w) == ["100m"] * 3 + ["3"] * 3
-    assert [j.cpu for j in w] == [j.cpu for j in gen.wave(MIX, 3, 4, PODS)]
-    assert {j.priority_class for j in w if j.cpu == "3"} == {"high"}
+    cpu = [j.requests["cpu"] for j in w]
+    assert sorted(cpu) == ["100m"] * 3 + ["3"] * 3
+    assert cpu == [j.requests["cpu"] for j in gen.wave(MIX, 3, 4, PODS)]
+    assert {j.priority_class for j in w if j.requests["cpu"] == "3"} \
+        == {"high"}
+    assert all(set(j.requests) == {"cpu", "memory"} for j in w)
 
 
 @pytest.mark.parametrize("seed", [1, 2 ** 31 + 7, 9_999_999_999])
 def test_every_seed_gets_the_same_work(seed):
     def key(js):
-        return sorted((j.size, j.cpu, j.memory, j.min_available) for j in js)
+        return sorted((j.size, j.requests["cpu"], j.requests["memory"],
+                       j.min_available) for j in js)
 
     base, other = gen.wave(MIX, 0, 0, PODS), gen.wave(MIX, seed, 0, PODS)
     assert key(base) == key(other)
