@@ -284,20 +284,13 @@ def headline(n_nodes=2000, n_jobs=1000, tpj=10):
     def one_session(jobs_s, tasks_s, grouped_s=None, drf=False):
         # fused dispatch: scatter+solve in ONE device call, then one
         # compact readback — 2 round-trips total per session (deltas over
-        # FUSED_SLOTS chunks fall back to scatter + non-fused solve)
-        from volcano_tpu.ops.solver import solve_allocate_packed2d
+        # FUSED_SLOTS chunks re-ship the buffers whole first)
         arr = flatten_snapshot(jobs_s, nodes, tasks_s, cache=fcache,
                                queues=queues, grouped=grouped_s)
         fill_queue_demand(arr, jobs_s, demand_cache)
         fbuf, ibuf, layout = arr.packed()
         params = _params(arr)
-        kind, payload = dcache.plan_delta(fbuf, ibuf, layout)
-        if kind == "updated":
-            f2d, i2d = payload
-            return solve_allocate_packed2d(f2d, i2d, layout, params,
-                                           use_queue_cap=True,
-                                           use_drf_order=drf)
-        f2d, i2d, fi, fv, ii, iv = payload
+        f2d, i2d, fi, fv, ii, iv = dcache.plan_delta(fbuf, ibuf, layout)
         res, nf, ni = solve_allocate_delta(
             f2d, i2d, fi, fv, ii, iv, layout, params,
             use_queue_cap=True, use_drf_order=drf)
@@ -426,18 +419,11 @@ def headline(n_nodes=2000, n_jobs=1000, tpj=10):
     sess0 = dcache.sessions
     hit0 = dcache.delta_sessions
 
-    def make_session(kind, payload, layout, params_dev):
+    def make_session(payload, layout, params_dev):
         def dispatch():
-            if kind == "updated":
-                f2d, i2d = payload
-                r = solve_allocate_packed2d(
-                    f2d, i2d, layout, params_dev, use_queue_cap=True)
-            else:
-                f2d, i2d, fi, fv, ii, iv = payload
-                r, nf, ni = solve_allocate_delta(
-                    f2d, i2d, fi, fv, ii, iv, layout, params_dev,
-                    use_queue_cap=True)
-                dcache.commit(nf, ni)
+            r, nf, ni = solve_allocate_delta(
+                *payload, layout, params_dev, use_queue_cap=True)
+            dcache.commit(nf, ni)
             start_readback(r.compact)
             return r
 
@@ -454,11 +440,11 @@ def headline(n_nodes=2000, n_jobs=1000, tpj=10):
         fill_queue_demand(arr, jobs_s, demand_cache)
         fbuf, ibuf, layout = arr.packed()
         refs.append((fbuf.copy(), ibuf.copy(), layout, len(tasks_s)))
-        kind, payload = dcache.plan_delta(fbuf, ibuf, layout)
+        payload = dcache.plan_delta(fbuf, ibuf, layout)
         pbytes.append(dcache.last_shipped_bytes)
         pchunks.append(dcache.last_shipped_chunks)
         params_dev = dcache.params_device(params)
-        pipe.submit(i, *make_session(kind, payload, layout, params_dev))
+        pipe.submit(i, *make_session(payload, layout, params_dev))
     tickets = pipe.drain(timeout=600)
     pipe_wall_ms = (time.perf_counter() - t_pipe0) * 1e3
     overlap_pairs = pipe.overlap_pairs()

@@ -175,20 +175,15 @@ class TestArenaByteIdentity:
 
 class TestArenaInvalidate:
     def _session(self, dc, jobs, nodes, tasks):
-        from volcano_tpu.ops.solver import (
-            solve_allocate_delta, solve_allocate_packed2d,
-        )
+        from volcano_tpu.ops.solver import solve_allocate_delta
 
         arr = flatten_snapshot(jobs, nodes, tasks)
         fbuf, ibuf, layout = arr.packed()
         params = dc.params_device(_score_params(arr))
-        kind, payload = dc.plan_delta(fbuf, ibuf, layout)
-        if kind == "updated":
-            res = solve_allocate_packed2d(*payload, layout, params, **FLAGS)
-        else:
-            res, nf, ni = solve_allocate_delta(
-                *payload[:2], *payload[2:], layout, params, **FLAGS)
-            dc.commit(nf, ni)
+        payload = dc.plan_delta(fbuf, ibuf, layout)
+        res, nf, ni = solve_allocate_delta(*payload, layout, params,
+                                           **FLAGS)
+        dc.commit(nf, ni)
         return np.asarray(res.compact)
 
     def test_invalidate_keeps_params_and_reships_once(self):
@@ -239,10 +234,15 @@ class TestArenaInvalidate:
         arr = flatten_snapshot(jobs, nodes, tasks)
         fbuf, ibuf, layout = arr.packed()
         dc.plan_delta(fbuf, ibuf, layout)
-        kind, payload = dc.plan_delta(fbuf, ibuf, layout)
-        assert kind == "updated"          # resident buffers, no upload
-        assert dc.last_shipped_bytes == 0
+        f2d, i2d, fi, fv, ii, iv = dc.plan_delta(fbuf, ibuf, layout)
+        # resident buffers, no upload: a no-op delta rewrites chunk 0
+        # with its own bytes
+        assert f2d is dc._dev_f and i2d is dc._dev_i
+        assert not fi.any() and not ii.any()
+        assert (fv == dc._host_f[:dc.chunk]).all()
+        assert (iv == dc._host_i[:dc.chunk]).all()
         assert dc.last_shipped_chunks == 0
+        assert not dc.last_full_ship
         assert dc.arena_hit_rate == 0.5
 
 
@@ -259,9 +259,7 @@ class TestPipelineOverlapSmoke:
         bench's steady-state measurement rides)."""
         from volcano_tpu.ops import SessionPipeline
         from volcano_tpu.ops.pipeline import start_readback
-        from volcano_tpu.ops.solver import (
-            solve_allocate_delta, solve_allocate_packed2d,
-        )
+        from volcano_tpu.ops.solver import solve_allocate_delta
 
         dc = PackedDeviceCache()
         pipe = SessionPipeline(depth=2)
@@ -271,16 +269,12 @@ class TestPipelineOverlapSmoke:
             arr = flatten_snapshot(jobs, nodes, tasks)
             fbuf, ibuf, layout = arr.packed()
             params = dc.params_device(_score_params(arr))
-            kind, payload = dc.plan_delta(fbuf, ibuf, layout)
+            payload = dc.plan_delta(fbuf, ibuf, layout)
 
             def dispatch():
-                if kind == "updated":
-                    r = solve_allocate_packed2d(*payload, layout, params,
-                                                **FLAGS)
-                else:
-                    r, nf, ni = solve_allocate_delta(
-                        *payload[:2], *payload[2:], layout, params, **FLAGS)
-                    dc.commit(nf, ni)
+                r, nf, ni = solve_allocate_delta(*payload, layout, params,
+                                                 **FLAGS)
+                dc.commit(nf, ni)
                 start_readback(r.compact)
                 return r
 

@@ -42,6 +42,16 @@ PLACEMENT_SLACK = 0.65
 CASES = 40
 
 
+#: the GPU corpus: nodes of 8 whole GPUs (a third, scalar resource
+#: dimension) and more gangs than the solver's gang iterations
+#: (``max_gang_iters`` 12), oversubscribed several times over
+GPU_T, GPU_N, GPU_J, GPU_R = 512, 16, 40, 3
+GPU_CASES = 12
+#: gang sizes, in the proportions of the GPU cell's traffic
+GANGS = [1, 2, 4, 8, 16, 32]
+GANG_P = np.array([40, 20, 15, 12, 8, 5]) / 100.0
+
+
 def random_problem(rng):
     n_nodes = int(rng.integers(2, N + 1))
     n_jobs = int(rng.integers(1, 12))
@@ -128,20 +138,80 @@ def random_problem(rng):
     return arrays
 
 
-def params_for(mode):
+def random_gpu_problem(rng):
+    """Empty 8-GPU nodes (64 cpu, 512Gi) and 13 to 39 gangs of 1 to 32
+    pods of 1, 2, 4 or 8 GPUs with 2 cpu and 16Gi per GPU, minAvailable =
+    replicas: gangs that cannot all fit and revert under contention."""
+    T_, N_, J_, R_ = GPU_T, GPU_N, GPU_J, GPU_R
+    n_nodes = int(rng.integers(2, N_ + 1))
+    n_jobs = int(rng.integers(13, J_))
+    gib = float(1 << 30)
+    idle = np.zeros((N_, R_), np.float32)
+    idle[:n_nodes] = (64000.0, 512 * gib, 8000.0)
+    task_job = np.full(T_, J_ - 1, np.int32)
+    init_req = np.zeros((T_, R_), np.float32)
+    valid = np.zeros(T_, bool)
+    job_min = np.zeros(J_, np.int32)
+    job_valid = np.zeros(J_, bool)
+    job_queue = np.zeros(J_, np.int32)
+    off = 0
+    for j in range(n_jobs):
+        k = min(int(rng.choice(GANGS, p=GANG_P)), T_ - off)
+        if k == 0:
+            break
+        g = float(rng.choice([1, 2, 4, 8]))
+        init_req[off:off + k] = (2000.0 * g, 16 * gib * g, 1000.0 * g)
+        task_job[off:off + k] = j
+        valid[off:off + k] = True
+        job_min[j] = k
+        job_valid[j] = True
+        job_queue[j] = j % 3
+        off += k
+    qreq = np.zeros((Q, R_), np.float32)
+    for j in range(n_jobs):
+        qreq[job_queue[j]] += init_req[task_job == j].sum(axis=0)
+    qw = np.zeros(Q, np.float32)
+    qw[:3] = (1.0, 2.0, 3.0)
+    return {
+        "node_idle": idle,
+        "node_extra_future": np.zeros((N_, R_), np.float32),
+        "node_used": np.zeros((N_, R_), np.float32),
+        "node_alloc": np.where(idle > 0, idle, 1.0).astype(np.float32),
+        "node_npods": np.zeros(N_, np.int32),
+        "node_max_pods": np.full(N_, 110, np.int32),
+        "node_valid": np.arange(N_) < n_nodes,
+        "sig_masks": np.arange(N_)[None, :] < n_nodes,
+        "task_init_req": init_req, "task_req": init_req.copy(),
+        "task_job": task_job, "task_rank": np.arange(T_, dtype=np.int32),
+        "task_sig": np.zeros(T_, np.int32),
+        "task_counts_ready": valid.copy(), "task_valid": valid,
+        "job_min": job_min, "job_ready_base": np.zeros(J_, np.int32),
+        "job_queue": job_queue, "job_valid": job_valid,
+        "queue_weight": qw,
+        "queue_capability": np.full((Q, R_), np.inf, np.float32),
+        "queue_allocated": np.zeros((Q, R_), np.float32),
+        "queue_request": qreq,
+        "thresholds": np.array([10.0, 1.0, 10.0], np.float32),
+        "scalar_dim_mask": np.array([False, False, True]),
+        "job_drf_allocated": np.zeros((J_, R_), np.float32),
+        "drf_total": idle[:n_nodes].sum(axis=0),
+    }
+
+
+def params_for(mode, r=R, n=N):
     if mode == "pack":
         return {"binpack_weight": np.float32(1.0),
-                "binpack_res_weights": np.ones(R, np.float32),
+                "binpack_res_weights": np.ones(r, np.float32),
                 "least_req_weight": np.float32(0.0),
                 "most_req_weight": np.float32(0.0),
                 "balanced_weight": np.float32(0.0),
-                "node_static": np.zeros(N, np.float32)}, ("binpack",)
+                "node_static": np.zeros(n, np.float32)}, ("binpack",)
     return {"binpack_weight": np.float32(0.0),
-            "binpack_res_weights": np.ones(R, np.float32),
+            "binpack_res_weights": np.ones(r, np.float32),
             "least_req_weight": np.float32(1.0),
             "most_req_weight": np.float32(0.0),
             "balanced_weight": np.float32(0.0),
-            "node_static": np.zeros(N, np.float32)}, ("kube",)
+            "node_static": np.zeros(n, np.float32)}, ("kube",)
 
 
 def check_invariants(a, res, label):
@@ -154,8 +224,8 @@ def check_invariants(a, res, label):
     placed = assigned >= 0
     assert a["node_valid"][assigned[placed]].all(), label
     # per-node capacity
-    alloc_used = np.zeros((N, R), np.float32)
-    pipe_used = np.zeros((N, R), np.float32)
+    alloc_used = np.zeros(a["node_idle"].shape, np.float32)
+    pipe_used = np.zeros(a["node_idle"].shape, np.float32)
     for i in np.nonzero(placed)[0]:
         if kind[i] == 0:
             alloc_used[assigned[i]] += a["task_req"][i]
@@ -172,7 +242,7 @@ def check_invariants(a, res, label):
     assert (pipe_used <= a["node_idle"] + a["node_extra_future"]
             + thr).all(), f"{label}: pipelines exceed total future idle"
     # gang atomicity + job_ready consistency
-    for j in range(J):
+    for j in range(a["job_min"].shape[0]):
         if not a["job_valid"][j]:
             continue
         mask = (a["task_job"] == j) & placed & (kind == 0)
@@ -214,6 +284,38 @@ def test_contended_parity(herd, queue_cap):
     # reference greedy per config (observed floor 0.972 on pack/no-cap;
     # summed across all four herd/queue-cap configs it places MORE than
     # the reference, ratio ~1.035)
+    assert total_rounds >= total_seq * 0.95, (total_rounds, total_seq)
+
+
+@pytest.mark.parametrize("drf", [False, True])
+@pytest.mark.parametrize("herd", ["pack", "spread"])
+@pytest.mark.parametrize("queue_cap", [False, True])
+def test_contended_parity_gpu(herd, queue_cap, drf):
+    """The GPU corpus (random_gpu_problem): a scalar dimension live and more
+    gangs than max_gang_iters. The hard invariants hold; without DRF the
+    rounds solver keeps PLACEMENT_SLACK of the sequential oracle on every
+    case (DRF reorders by design, see test_drf_order_invariants); and it
+    never places nothing where the oracle places something."""
+    rng = np.random.default_rng(20260805 + (herd == "pack") + 2 * queue_cap
+                                + 4 * drf)
+    params, families = params_for(herd, GPU_R, GPU_N)
+    total_rounds = total_seq = 0
+    for case in range(GPU_CASES):
+        a = random_gpu_problem(rng)
+        r1 = solve_allocate(a, params, herd_mode=herd,
+                            score_families=families,
+                            use_queue_cap=queue_cap, use_drf_order=drf)
+        r2 = solve_allocate_sequential(a, params, score_families=families,
+                                       use_queue_cap=queue_cap,
+                                       overflow_pass=queue_cap)
+        label = f"gpu/{herd}/q{queue_cap}/drf{drf}/#{case}"
+        p1 = check_invariants(a, r1, "rounds/" + label)
+        p2 = check_invariants(a, r2, "seq/" + label)
+        total_rounds += p1
+        total_seq += p2
+        assert p1 > 0 or p2 == 0, (label, p1, p2)
+        if not drf:
+            assert p1 >= PLACEMENT_SLACK * p2, (label, p1, p2)
     assert total_rounds >= total_seq * 0.95, (total_rounds, total_seq)
 
 

@@ -171,10 +171,9 @@ class TestBucketPrewarm:
             arr = flatten_snapshot(jobs, nodes, tasks)
             fbuf, ibuf, layout = arr.packed()
             params = dc.params_device(_score_params(arr))
-            kind, payload = dc.plan_delta(fbuf, ibuf, layout)
-            assert kind == "fused"
-            res, nf, ni = solve_allocate_delta(
-                *payload[:2], *payload[2:], layout, params, **FLAGS)
+            payload = dc.plan_delta(fbuf, ibuf, layout)
+            res, nf, ni = solve_allocate_delta(*payload, layout, params,
+                                               **FLAGS)
             dc.commit(nf, ni)
             np.asarray(res.compact)
             dc.last_solve_flags = dict(layout=layout, **FLAGS)
@@ -208,6 +207,108 @@ class TestBucketPrewarm:
         dc.last_solve_flags = dict(layout=layout, **FLAGS)
         pw = pc.BucketPrewarmer()
         assert not pw.observe(arr, dc)
+
+
+class TestSteadyChurn:
+    """A steady session dispatches a finite set of variants: the flatten
+    pads the task and job axes up to the arena's floor (the largest it has
+    planned), and the packed arena dispatches one executable per layout,
+    whatever the number of dirty chunks."""
+
+    @staticmethod
+    def _session(dc, jobs, nodes, tasks_by_job, k):
+        keep = list(jobs)[:k]
+        arr = flatten_snapshot({u: jobs[u] for u in keep}, nodes,
+                               [t for u in keep for t in tasks_by_job[u]],
+                               floor=dc.dims_floor)
+        fbuf, ibuf, layout = arr.packed()
+        res = dc.dispatch(dc.plan(fbuf, ibuf, layout, _score_params(arr),
+                                  FLAGS))
+        np.asarray(res.compact)
+        return arr
+
+    @staticmethod
+    def _problem(n_jobs, tpj):
+        jobs, nodes, tasks = _mini_problem(5, n_jobs, tpj)
+        by_job = {}
+        for t in tasks:
+            by_job.setdefault(t.job, []).append(t)
+        return jobs, nodes, by_job
+
+    def test_warm_churn_compiles_nothing(self):
+        assert pc.watcher.install()
+        jobs, nodes, by_job = self._problem(12, 5)
+        # 8-float chunks: a new job mix dirties more chunks than the
+        # fused slots hold, a repeated one none
+        dc = PackedDeviceCache(chunk=8)
+        warm = self._session(dc, jobs, nodes, by_job, 12)   # T 60, J 13
+        assert (warm.T, warm.J) == (bucket(60), bucket(13))
+        self._session(dc, jobs, nodes, by_job, 7)
+        self._session(dc, jobs, nodes, by_job, 7)
+        c0, _ = pc.watcher.counts()
+        sz0 = pc.solver_cache_size()
+        full, fused = set(), set()
+        # natural buckets T 16..64 and J 8..14, down and up across edges
+        for k in (3, 12, 12, 2, 9, 9, 12, 4, 10, 1, 12):
+            arr = self._session(dc, jobs, nodes, by_job, k)
+            assert (arr.T, arr.J) == (warm.T, warm.J)
+            assert bucket(5 * k) <= arr.T and bucket(k + 1) <= arr.J
+            (full if dc.last_full_ship else fused).add(
+                dc.last_shipped_chunks)
+        assert full and fused and 0 in fused
+        c1, _ = pc.watcher.counts()
+        assert c1 == c0, "a warm churn session compiled"
+        if sz0 >= 0:
+            assert pc.solver_cache_size() == sz0
+
+    def test_closed_waves_keep_their_variant(self):
+        jobs, nodes, by_job = self._problem(6, 4)
+        dc = PackedDeviceCache()
+        for _ in range(3):
+            arr = self._session(dc, jobs, nodes, by_job, 6)
+            assert (arr.T, arr.J) == (bucket(24), bucket(7))
+        assert dc.dims_floor == {"T": bucket(24), "J": bucket(7)}
+
+    def test_prewarm_predicts_the_floors_growth(self, monkeypatch):
+        jobs, nodes, by_job = self._problem(12, 4)
+        dc = PackedDeviceCache()
+        arr = self._session(dc, jobs, nodes, by_job, 10)   # T 40 of 40
+        pw = pc.BucketPrewarmer()
+        seen = []
+        monkeypatch.setattr(pw, "_warm_many",
+                            lambda work, chunk, flags: seen.extend(work))
+        assert pw.observe(arr, dc)
+        pw.wait(60)
+        # past the floor the flatten pads to a power of two at least twice
+        # the floor, not to the next bucket (T 48, J 14): that is the
+        # variant worth warming
+        assert {d["T"] for _k, _l, d in seen} == {arr.T, 128}
+        assert {d["J"] for _k, _l, d in seen} == {arr.J, 32}
+
+    def test_grow_ahead_compiles_the_growth_off_thread(self):
+        assert pc.watcher.install()
+        jobs, nodes, by_job = self._problem(12, 5)
+        dc = PackedDeviceCache()
+        first = self._session(dc, jobs, nodes, by_job, 6).T  # T 30 of 32
+        assert dc.grow_ahead()
+        dc.grow_thread.join(300)
+        grown = dc.dims_floor["T"]
+        assert first == 32 and grown == 64
+        assert not dc.grow_ahead(), "grows ahead once per arena"
+        c0, _ = pc.watcher.counts()
+        for k in (6, 7, 3):                  # J stays at bucket(8)
+            assert self._session(dc, jobs, nodes, by_job, k).T == grown
+        assert pc.watcher.counts()[0] == c0, "the grown layout compiled " \
+            "on the session thread"
+
+    def test_outgrowing_the_floor_pads_to_a_power_of_two(self):
+        jobs, nodes, by_job = self._problem(12, 5)
+        dc = PackedDeviceCache()
+        self._session(dc, jobs, nodes, by_job, 4)          # T 20
+        arr = self._session(dc, jobs, nodes, by_job, 9)    # T 45
+        assert arr.T == 64 and bucket(45) == 48
+        assert self._session(dc, jobs, nodes, by_job, 12).T == 64
+        assert self._session(dc, jobs, nodes, by_job, 2).T == 64
 
 
 def _build_cluster(n_nodes=4, n_jobs=3, tpj=2, async_effectors=False):
@@ -314,6 +415,36 @@ class TestCollectFailureFallback:
         assert len(cache.binder.binds) == 8
         assert dc.last_full_ship
         assert "host_fallback" not in sched.last_cycle_timing
+
+
+class TestBacklogGrowth:
+    def test_only_a_backlog_grows_the_arena_ahead(self):
+        """The allocate action asks the packed arena to grow ahead when a
+        solve leaves a task waiting, and not while every wave fits."""
+        from volcano_tpu.scheduler import Scheduler
+
+        store, cache, wave = _build_cluster(n_nodes=1, n_jobs=3)
+        sched = Scheduler(cache)
+        dc = cache.device_cache
+        for k in (3, 4):
+            sched.run_once()
+            assert dc.grow_thread is None
+            wave(k)
+        sched.run_once()
+        assert len(cache.binder.binds) == 10 and dc.grow_thread is None
+        for k in range(5, 10):       # 20 pods of 1 cpu on 16 cpu
+            wave(k)
+        sched.run_once()
+        assert len(cache.binder.binds) == 16
+        assert dc.grow_thread is not None
+        floor = dc.dims_floor["T"]
+        dc.grow_thread.join(300)
+        assert dc.dims_floor["T"] == 2 * floor
+        wave(10)
+        sched.run_once()
+        assert dc._layout is not None
+        assert dict((k, s) for k, _a, _o, _z, s in dc._layout)[
+            "task_valid"][0] == dc.dims_floor["T"]
 
 
 class TestPersistentCacheConfig:

@@ -174,7 +174,8 @@ SPANS = (
 COUNTERS = ("pods_created", "binds_written", "evictions_written",
             "solve_rounds", "evict_scan_steps", "evict_claimers",
             "victim_rows", "victim_rows_masked", "pod_wait_ms_sum",
-            "pod_wait_n", "pod_events", "pod_task_builds")
+            "pod_wait_n", "pod_events", "pod_task_builds",
+            "round_cap_hits", "task_rows", "scalar_task_rows")
 LEGACY = ("open_ms", "order_ms", "flatten_ms", "dispatch_ms", "readback_ms",
           "replay_ms", "solve_ms", "preempt_ms", "preempt_solve_ms",
           "total_ms")

@@ -202,6 +202,7 @@ class AllocateAction(Action):
         from ..ops import flatten_snapshot, solve_allocate, \
             solve_allocate_sequential
         from ..ops.pipeline import start_readback
+        from ..ops.precompile import watcher
         from ..ops.solver import collect_assignment
         from ..resilience import faults
 
@@ -253,11 +254,19 @@ class AllocateAction(Action):
                 # clones; those deltas never reached the event ledger, so the
                 # event-sourced fast path must re-diff this cycle
                 fc.suppress_event_path("session_mutations")
+            # the device-resident arena this session dispatches to (the
+            # packed cache, or with mode: sharded the node-axis shards):
+            # its executables set the padding floor
+            arena = None
+            if not sequential:
+                arena = self._sharded_arena(ssn) if sharded \
+                    else getattr(ssn, "device_cache", None)
             with span("volcano.allocate.flatten.snapshot") as snap:
                 arr = flatten_snapshot(
                     {j.uid: j for j, _ in job_order}, ssn.nodes,
                     tasks_in_order, queues=ssn.queues, cache=fc,
-                    grouped=job_order)
+                    grouped=job_order,
+                    floor=arena.dims_floor if arena is not None else None)
             fs_ms = snap.ms
             if fc is not None:
                 # the event -> incremental -> cold ladder made observable:
@@ -351,10 +360,9 @@ class AllocateAction(Action):
                          use_hdrf_order=use_hdrf_order,
                          work_conserving=work_conserving)
 
-            dc = getattr(ssn, "device_cache", None)
             # which arena a device fault must invalidate: the packed cache by
             # default, the sharded arena when this session dispatched there
-            fault_dc = dc
+            fault_dc = getattr(ssn, "device_cache", None)
             try:
                 # device-path circuit-breaker scope: anything that throws out
                 # of the dispatch or the collect (XLA runtime error, OOM,
@@ -366,13 +374,12 @@ class AllocateAction(Action):
                     res = solve_allocate_sequential(
                         arr.device_dict(), params,
                         **{k: flags[k] for k in _SEQUENTIAL_FLAGS})
-                elif sharded or dc is not None:
+                elif arena is not None:
                     # the device-resident arena: the packed cache, or with
                     # mode: sharded the node-axis shards of the shard_map
                     # solver (ops.device_cache). Either ships only the
                     # chunks dirtied since the last session and dispatches
                     # one async solve
-                    arena = self._sharded_arena(ssn) if sharded else dc
                     fault_dc = arena
                     with span("volcano.allocate.pack"):
                         fbuf, ibuf, layout = arr.packed()
@@ -380,7 +387,13 @@ class AllocateAction(Action):
                         staged = arena.plan(fbuf, ibuf, layout, params, flags)
                     arena.record(timing)
                     with span("volcano.allocate.dispatch", "dispatch_ms"):
+                        c0 = watcher.counts()[0]
                         res = arena.dispatch(staged)
+                        if watcher.installed:
+                            # a dispatch that compiled, or loaded from the
+                            # persistent cache, found its executable cold
+                            count("variant_misses",
+                                  float(watcher.counts()[0] > c0))
                 else:
                     res = solve_allocate(arr.device_dict(), params, **flags)
                 # -------------------------------------------------------------
@@ -402,7 +415,7 @@ class AllocateAction(Action):
                     # sharded solve has no compact form; its assigned/kind
                     # futures prefetch the same way)
                     start_readback(res.compact, res.assigned, res.kind,
-                                   res.rounds)
+                                   res.rounds, res.cap_hits)
                     node_names = [n.name for n in arr.nodes_list]
                     # Statement construction is pure (no session registration
                     # until ops are recorded), so the replay's per-job
@@ -425,6 +438,7 @@ class AllocateAction(Action):
                                               len(tasks_in_order),
                                               len(arr.nodes_list))
                     count("solve_rounds", rounds)
+                    count("round_cap_hits", int(res.cap_hits))
             except Exception:
                 # a collect failure surfaces after a donated-buffer dispatch
                 # already commit()ed what are now poisoned device buffers:
@@ -441,6 +455,12 @@ class AllocateAction(Action):
                 # a full dispatch+collect round-trip with sane output: the
                 # device path is healthy (closes a half-open breaker)
                 breaker.record_success()
+            if not sharded and arena is not None \
+                    and arena.grow_thread is None \
+                    and (assigned[:len(tasks_in_order)] < 0).any():
+                # a backlog outlasts this solve: the packed arena compiles
+                # its task axis's growth off this thread
+                arena.grow_ahead()
 
         with span("volcano.allocate.replay", "replay_ms"):
             # replay through the Statement boundary in job order; events fire

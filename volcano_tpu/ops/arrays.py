@@ -26,6 +26,7 @@ from ..api import (
     JobInfo, NodeInfo, NodePhase, Resource, ResourceVocab, TaskInfo,
     TaskStatus, MIN_MEMORY, MIN_MILLI_CPU, MIN_MILLI_SCALAR,
 )
+from ..metrics.spans import count, span
 
 #: compile-bucket sizes: quarter-steps between powers of two, floor 8 —
 #: keeps the number of distinct compiled shapes logarithmic in cluster size
@@ -39,6 +40,19 @@ def bucket(n: int, floor: int = 8) -> int:
                 return cand
         b *= 2
     return b
+
+
+def _floored(padded: int, n: int, floor: int) -> int:
+    """A padded axis against a compiled floor (flatten_snapshot's
+    ``floor``): the floor where the bucket fits under it, else a power of
+    two at or above both ``n`` and twice the floor: an axis that has
+    outgrown its size tends to keep growing, and each growth compiles."""
+    if not floor:
+        return padded
+    if padded <= floor:
+        return floor
+    return max(1 << (max(n, 1) - 1).bit_length(),
+               1 << (2 * floor - 1).bit_length())
 
 
 def _match_node_selector(selector: Dict[str, str], node) -> bool:
@@ -528,7 +542,7 @@ class FlattenCache:
         k = len(tasks)
         # bulk cpu/mem extraction: one list-comprehension + np.array beats
         # 2k per-task to_vector calls ~5x (the all-cold burst flatten is
-        # this loop); scalar resources overlay the rare rows after
+        # this loop); scalar resources overlay their rows after
         init = np.zeros((k, R), dtype=np.float32)
         req = np.zeros((k, R), dtype=np.float32)
         init[:, :2] = np.array(
@@ -538,19 +552,25 @@ class FlattenCache:
             [(t.resreq.milli_cpu, t.resreq.memory)
              for t in tasks], dtype=np.float32).reshape(k, 2)
         any_scalar = np.zeros(k, dtype=bool)
-        for i, t in enumerate(tasks):
-            if t.init_resreq.scalars or t.resreq.scalars:
-                for name, v in t.init_resreq.scalars.items():
-                    if v >= MIN_MILLI_SCALAR:
-                        # vocab-independent, like Resource.is_empty
-                        any_scalar[i] = True
-                    idx = vocab.index(name)
-                    if idx is not None:
-                        init[i, idx] = v
-                for name, v in t.resreq.scalars.items():
-                    idx = vocab.index(name)
-                    if idx is not None:
-                        req[i, idx] = v
+        scalar_rows = [i for i, t in enumerate(tasks)
+                       if t.init_resreq.scalars or t.resreq.scalars]
+        count("task_rows", k)
+        count("scalar_task_rows", len(scalar_rows))
+        if scalar_rows:
+            with span("volcano.allocate.flatten.scalar"):
+                for i in scalar_rows:
+                    t = tasks[i]
+                    for name, v in t.init_resreq.scalars.items():
+                        if v >= MIN_MILLI_SCALAR:
+                            # vocab-independent, like Resource.is_empty
+                            any_scalar[i] = True
+                        idx = vocab.index(name)
+                        if idx is not None:
+                            init[i, idx] = v
+                    for name, v in t.resreq.scalars.items():
+                        idx = vocab.index(name)
+                        if idx is not None:
+                            req[i, idx] = v
         # not is_empty(): the api.resource thresholds
         counts = ((init[:, 0] >= MIN_MILLI_CPU)
                   | (init[:, 1] >= MIN_MEMORY) | any_scalar)
@@ -614,6 +634,7 @@ def flatten_snapshot(
     queues: Optional[Dict[str, object]] = None,
     cache: Optional[FlattenCache] = None,
     grouped: Optional[List[tuple]] = None,
+    floor: Optional[Dict[str, int]] = None,
 ) -> SnapshotArrays:
     """Flatten session state into padded arrays.
 
@@ -625,6 +646,15 @@ def flatten_snapshot(
     Pass a persistent ``cache`` (the SchedulerCache owns one) to make the
     per-session flatten incremental: unchanged jobs reuse their cached task
     blocks, unchanged nodes their rows.
+
+    ``floor`` ({"T": .., "J": ..}) holds the task and job axes at or
+    above padded sizes a compiled solve already has (the device arena's
+    ``dims_floor``): a snapshot that shrinks below them pads up to them
+    and reuses that executable instead of compiling a smaller one, and
+    one that outgrows them pads to a power of two at least twice the
+    floor, so a backlog that keeps growing compiles once or twice, not at
+    every bucket (the packed arena compiles that growth off the scheduler's
+    thread once a backlog outlasts a solve: PackedDeviceCache.grow_ahead).
 
     NOTE: with a persistent cache the returned arrays alias cache-owned
     buffers that the NEXT flatten call may rewrite in place — they are valid
@@ -797,6 +827,9 @@ def flatten_snapshot(
     # +1 guarantees a padded (invalid) job slot: padded tasks point there so
     # the sequential solver's job-boundary logic never revisits a real job
     J = bucket(nJ + 1)
+    if floor:
+        T = _floored(T, n_tasks, floor.get("T", 0))
+        J = _floored(J, nJ + 1, floor.get("J", 0))
     shape_key = (R, T, J)
 
     arr = SnapshotArrays(vocab=vocab)

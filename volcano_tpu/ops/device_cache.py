@@ -33,7 +33,8 @@ bench's bytes-shipped-per-session artifact fields.
 from __future__ import annotations
 
 import logging
-from typing import Optional, Tuple
+import threading
+from typing import Dict, Optional, Tuple
 
 import numpy as np
 
@@ -118,6 +119,15 @@ class PackedDeviceCache:
         # the last plan's pinned params and flags (with its layout)
         self.last_params: Optional[dict] = None
         self.last_solve_flags: Optional[dict] = None
+        #: the largest padded task and job axes planned so far: the
+        #: flatten pads up to them (flatten_snapshot's ``floor``), so the
+        #: executables an arena dispatches only ever grow — a snapshot
+        #: that shrinks reuses a compiled solve, and a churning cluster
+        #: settles on a finite set of variants
+        self.dims_floor: Dict[str, int] = {}
+        self._floor_lock = threading.Lock()
+        #: grow_ahead's thread, once started
+        self.grow_thread: Optional[threading.Thread] = None
 
     # -- arena introspection -------------------------------------------
 
@@ -167,19 +177,25 @@ class PackedDeviceCache:
 
     def _full_ship(self, fbuf, ibuf, layout, cf: int, ci: int):
         """(Re)establish the host mirror and device buffers wholesale."""
-        import jax
-
         c = self.chunk
         hf = np.zeros(cf * c, np.float32)
         hf[:fbuf.size] = fbuf
         hi = np.zeros(ci * c, np.int32)
         hi[:ibuf.size] = ibuf
         self._host_f, self._host_i = hf, hi
-        self._dev_f = jax.device_put(hf.reshape(cf, c))
-        self._dev_i = jax.device_put(hi.reshape(ci, c))
         self._layout = layout
-        self.last_shipped_chunks = cf + ci
-        self._account(cf + ci, hf.nbytes + hi.nbytes, full=True)
+        self._ship_mirror()
+
+    def _ship_mirror(self) -> None:
+        """device_put the host mirror whole as the resident buffers."""
+        import jax
+
+        c = self.chunk
+        hf, hi = self._host_f, self._host_i
+        self._dev_f = jax.device_put(hf.reshape(-1, c))
+        self._dev_i = jax.device_put(hi.reshape(-1, c))
+        self._account((hf.size + hi.size) // c, hf.nbytes + hi.nbytes,
+                      full=True)
 
     def _account(self, chunks: int, wire_bytes: int, full: bool) -> None:
         self.sessions += 1
@@ -282,28 +298,26 @@ class PackedDeviceCache:
     #: fixed delta-slot count for the fused dispatch: the chunk-index
     #: shape is part of the fused solve's jit signature, so EVERY distinct
     #: size would compile another full-solve executable (~tens of seconds
-    #: each on TPU). One fixed size = exactly one fused variant; sessions
-    #: dirtying more chunks fall back to the separate-scatter path (still
-    #: zero new solve compiles — packed2d is its own single variant).
+    #: each on TPU). One fixed size = exactly one fused variant; a session
+    #: dirtying more chunks re-ships the buffers whole instead.
     FUSED_SLOTS = 16
 
     def plan_delta(self, fbuf: np.ndarray, ibuf: np.ndarray, layout):
         """Diff against the host mirror WITHOUT dispatching the solve.
 
-        Returns (kind, payload):
-        - ("fused", (f2d, i2d, f_idx, f_vals, i_idx, i_vals)) — at most
-          FUSED_SLOTS dirty chunks: feed solve_allocate_delta, which
-          scatters inside the solve dispatch; the caller must commit()
-          the returned (donated) buffers, and on a dispatch failure call
-          invalidate() so the next session re-ships the chunked buffers
-          in full (reset() only if the host mirror itself is suspect).
-        - ("updated", (f2d, i2d)) — more dirty chunks than FUSED_SLOTS:
-          the scatters were applied here (reusing the diff already
-          computed), feed the non-fused solve_allocate_packed2d.
-
-        On the first call (or a layout change) the full buffers are
-        device_put and a no-op fused delta (chunk 0 rewritten with
-        identical bytes) is returned, so the caller has one code path.
+        Returns the arguments of solve_allocate_delta, (f2d, i2d, f_idx,
+        f_vals, i_idx, i_vals): every session of a layout dispatches that
+        one executable, which scatters the dirty chunks inside the solve.
+        At most FUSED_SLOTS dirty chunks ride in its fixed slots. An
+        unchanged snapshot, the first call, a layout change, or more dirty
+        chunks than FUSED_SLOTS send a no-op delta (chunk 0 rewritten with
+        its own bytes); the last three device_put both buffers whole first,
+        which compiles nothing (a scatter padded to the dirty count
+        compiled once per count, and a second solve entry once per
+        layout). The caller must commit() the returned (donated) buffers,
+        and on a dispatch failure call invalidate() so the next session
+        re-ships the chunked buffers in full (reset() only if the host
+        mirror itself is suspect).
         """
         c = self.chunk
         cf = -(-max(fbuf.size, 1) // c)
@@ -311,48 +325,28 @@ class PackedDeviceCache:
         k = self.FUSED_SLOTS
         if self._needs_full_ship(layout, cf, ci):
             self._full_ship(fbuf, ibuf, layout, cf, ci)
-            zero = np.zeros(k, np.int32)
-            return "fused", (
-                self._dev_f, self._dev_i,
+        else:
+            f2, i2, df, di = self._diff(fbuf, ibuf, cf, ci)
+            self._commit_mirror(f2, i2)
+            if int(df.size) > k or int(di.size) > k:
+                self._ship_mirror()
+            else:
+                f_idx = self._pad_idx(df, k)
+                i_idx = self._pad_idx(di, k)
+                fv = f2.reshape(cf, c)[f_idx]
+                iv = i2.reshape(ci, c)[i_idx]
+                # fused wire cost: both value blocks always ship k chunks
+                # (the fixed jit signature), plus the two index vectors
+                self._account(df.size + di.size,
+                              fv.nbytes + iv.nbytes + f_idx.nbytes
+                              + i_idx.nbytes, full=False)
+                return self._dev_f, self._dev_i, f_idx, fv, i_idx, iv
+        zero = np.zeros(k, np.int32)
+        return (self._dev_f, self._dev_i,
                 zero, np.broadcast_to(
                     self._host_f.reshape(cf, c)[0], (k, c)).copy(),
                 zero, np.broadcast_to(
                     self._host_i.reshape(ci, c)[0], (k, c)).copy())
-
-        f2, i2, df, di = self._diff(fbuf, ibuf, cf, ci)
-        if df.size == 0 and di.size == 0:
-            # unchanged snapshot: solve straight off the resident buffers
-            # (non-donating packed2d) — zero wire bytes instead of a
-            # no-op fused payload of FUSED_SLOTS chunks
-            self._scratch_f, self._scratch_i = f2, i2
-            self._account(0, 0, full=False)
-            return "updated", (self._dev_f, self._dev_i)
-        if int(df.size) > k or int(di.size) > k:
-            # too many dirty chunks for the fused variant: apply the
-            # scatters now (reusing this diff) and let the caller run the
-            # non-fused solve
-            try:
-                new_f = self._apply(self._dev_f, df, f2.reshape(cf, c))
-                new_i = self._apply(self._dev_i, di, i2.reshape(ci, c))
-            except Exception:
-                self.reset()
-                raise
-            self._dev_f, self._dev_i = new_f, new_i
-            self._commit_mirror(f2, i2)
-            self._account(df.size + di.size,
-                          self._scatter_wire_bytes(df, di), full=False)
-            return "updated", (self._dev_f, self._dev_i)
-        f_idx = self._pad_idx(df, k)
-        i_idx = self._pad_idx(di, k)
-        fv = f2.reshape(cf, c)[f_idx]
-        iv = i2.reshape(ci, c)[i_idx]
-        self._commit_mirror(f2, i2)
-        # fused wire cost: both value blocks always ship k chunks (the
-        # fixed jit signature), plus the two index vectors
-        self._account(df.size + di.size,
-                      fv.nbytes + iv.nbytes + f_idx.nbytes + i_idx.nbytes,
-                      full=False)
-        return "fused", (self._dev_f, self._dev_i, f_idx, fv, i_idx, iv)
 
     @staticmethod
     def _pad_idx(idx: np.ndarray, k: int) -> np.ndarray:
@@ -381,20 +375,72 @@ class PackedDeviceCache:
         what ``dispatch`` takes."""
         params = self.last_params = self.params_device(params)
         self.last_solve_flags = dict(layout=layout, **flags)
+        self._raise_floor(layout)
         return self.plan_delta(fbuf, ibuf, layout), layout, params, flags
+
+    def _raise_floor(self, layout) -> None:
+        shapes = {k: shape for k, _kind, _off, _size, shape in layout}
+        with self._floor_lock:
+            for dim, key in (("T", "task_valid"), ("J", "job_valid")):
+                n = int(shapes[key][0])
+                if n > self.dims_floor.get(dim, 0):
+                    self.dims_floor[dim] = n
+
+    def grow_ahead(self) -> bool:
+        """Called when a solve of this arena left tasks waiting. A backlog
+        that outlasts a solve churns, and its mix drifts toward the gangs
+        that wait longest, the largest: its task count climbs past the
+        first layout's within tens of turns. Once per arena, this compiles
+        the task axis's growth past the floor (flatten_snapshot's
+        ``floor``: a power of two at least twice it) on a thread of its
+        own, and raises the floor to it once compiled. The one growth a
+        backlog needs then lands in its first turns, off the scheduler's
+        thread, rather than at whichever later turn the backlog outgrows
+        its first size. Returns True when it started."""
+        from .arrays import _floored
+        from .precompile import (
+            SOLVE_FLAG_NAMES, layout_dims, predict_next_layout,
+            warm_delta_solve, watcher,
+        )
+
+        if self.grow_thread is not None or self._layout is None:
+            return False
+        dims = layout_dims(self._layout)
+        if dims is None:
+            return False  # hdrf layout: nothing to predict
+        t = self.dims_floor.get("T", dims["T"])
+        dims = dict(dims, T=_floored(t + 1, t + 1, t))
+        layout = predict_next_layout(self._layout, dims)
+        flags = {k: v for k, v in (self.last_solve_flags or {}).items()
+                 if k in SOLVE_FLAG_NAMES}
+
+        def grow():
+            watcher.install()
+            watcher.register_background()
+            try:
+                warm_delta_solve(layout, dims, self.chunk, flags)
+            except Exception:  # noqa: BLE001 — the growth then compiles
+                log.warning("arena growth warm failed for %s", dims,
+                            exc_info=True)
+                return
+            with self._floor_lock:
+                if dims["T"] > self.dims_floor.get("T", 0):
+                    self.dims_floor["T"] = dims["T"]
+
+        # not a daemon: an interpreter that exits mid-compile aborts
+        self.grow_thread = threading.Thread(target=grow, name="arena-grow")
+        self.grow_thread.start()
+        return True
 
     def dispatch(self, staged):
         """Dispatch the staged solve asynchronously: the fused delta
-        scatter + solve (one dispatch, donating the resident buffers) or,
-        when ``plan_delta`` already applied the scatters, packed2d. A
+        scatter + solve, one dispatch donating the resident buffers. A
         throwing donated dispatch may have consumed the buffers, so it
         invalidates the arena (the host mirror and the pinned params stay
         for the next session's re-ship)."""
-        from .solver import solve_allocate_delta, solve_allocate_packed2d
+        from .solver import solve_allocate_delta
 
-        (kind, payload), layout, params, flags = staged
-        if kind == "updated":
-            return solve_allocate_packed2d(*payload, layout, params, **flags)
+        payload, layout, params, flags = staged
         try:
             res, new_f, new_i = solve_allocate_delta(
                 *payload, layout, params, **flags)
@@ -776,6 +822,7 @@ class ShardedDeviceCache(PackedDeviceCache):
         bufs = self.update(fbuf, ibuf, layout)
         params = self.last_params = self.params_device(params)
         self.last_solve_flags = dict(layout=layout, **flags)
+        self._raise_floor(layout)
         return bufs, params, flags
 
     def dispatch(self, staged):

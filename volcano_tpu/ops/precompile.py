@@ -195,6 +195,10 @@ class CompileWatcher:
 
     # -- accounting views -------------------------------------------------
 
+    @property
+    def installed(self) -> bool:
+        return self._installed
+
     def register_background(self, ident: Optional[int] = None) -> None:
         with self._lock:
             self._background.add(
@@ -387,6 +391,33 @@ def dummy_score_params(dims: Dict[str, int]) -> Dict[str, np.ndarray]:
     }
 
 
+def warm_delta_solve(layout, dims: Dict[str, int], chunk: int,
+                     flags: dict) -> None:
+    """Trace and compile ``solve_allocate_delta``, the one executable the
+    packed arena dispatches per layout, for ``layout`` (padded sizes
+    ``dims``) against dummy buffers, so that the first session at that
+    layout finds it compiled. Blocks; run it on a background thread."""
+    import jax
+
+    from .device_cache import PackedDeviceCache
+    from .solver import solve_allocate_delta
+
+    # device_put everything exactly like the real dispatch path
+    # (PackedDeviceCache._full_ship / params_device): a committed device
+    # array and a host np.ndarray key DIFFERENT jit cache entries, so a
+    # numpy-fed warm would compile a variant the session never dispatches
+    params = {k: jax.device_put(v)
+              for k, v in dummy_score_params(dims).items()}
+    f2d, i2d = dummy_packed_buffers(layout, chunk)
+    k = PackedDeviceCache.FUSED_SLOTS
+    zero = np.zeros(k, np.int32)
+    res, _nf, _ni = solve_allocate_delta(
+        jax.device_put(f2d), jax.device_put(i2d), zero,
+        np.zeros((k, chunk), np.float32), zero,
+        np.zeros((k, chunk), np.int32), layout, params, **flags)
+    res.compact.block_until_ready()
+
+
 # ---------------------------------------------------------------------------
 # background bucket pre-warm
 # ---------------------------------------------------------------------------
@@ -405,19 +436,18 @@ class BucketPrewarmer:
     the dispatch/collect overlap window (zero critical-path cost: it only
     compares integers and maybe spawns a thread). When any of live
     T/N/J reaches ``threshold`` of its current bucket, the next bucket's
-    layout is predicted and ``solve_allocate_packed2d`` +
-    ``solve_allocate_delta`` (and, with a ``mesh``, the sharded packed2d
-    entry) are traced+compiled against dummy buffers off-thread. Each
+    layout is predicted and ``solve_allocate_delta``, the one executable
+    the packed arena dispatches per layout (and, with a ``mesh``, the
+    sharded entries), is traced+compiled against dummy buffers
+    off-thread. Each
     (dims, flags) combination warms at most once per process; the
     persistent compilation cache makes the warm a disk-cache
     deserialization after the first process ever to cross that bucket.
     """
 
-    def __init__(self, threshold: float = 0.8, mesh=None,
-                 warm_delta: bool = True):
+    def __init__(self, threshold: float = 0.8, mesh=None):
         self.threshold = threshold
         self.mesh = mesh
-        self.warm_delta = warm_delta
         self._lock = threading.Lock()
         self._started: Dict[tuple, str] = {}   # key -> status
         self._threads: list = []
@@ -430,7 +460,7 @@ class BucketPrewarmer:
         """Check occupancy against the current buckets; spawn a warm for
         the next-bucket variant when warranted. Returns True when a warm
         was scheduled."""
-        from .arrays import bucket
+        from .arrays import _floored, bucket
 
         layout = getattr(dc, "_layout", None)
         if layout is None:
@@ -462,6 +492,10 @@ class BucketPrewarmer:
         # first, so whichever combination the cluster lands on is covered
         # (≤7 combos, each deduped per process and disk-cached thereafter)
         fkey = tuple(sorted((k, v) for k, v in flags.items()))
+        # the flatten grows an axis past the arena's floor to a power of
+        # two at least twice the floor (flatten_snapshot's ``floor``),
+        # else to the next bucket
+        floor = getattr(dc, "dims_floor", {})
         work = []
         subsets = sorted(
             (s for m in range(1, 1 << len(crossed))
@@ -470,7 +504,8 @@ class BucketPrewarmer:
         for sub in subsets:
             nxt = dict(dims)
             for name in sub:
-                nxt[name] = bucket(dims[name] + 1)
+                nxt[name] = _floored(bucket(dims[name] + 1), dims[name] + 1,
+                                     floor.get(name, 0))
             key = (tuple(sorted(nxt.items())), fkey)
             with self._lock:
                 if key in self._started:
@@ -504,33 +539,7 @@ class BucketPrewarmer:
         try:
             import jax
 
-            from .device_cache import PackedDeviceCache
-            from .solver import solve_allocate_delta, solve_allocate_packed2d
-
-            # device_put everything exactly like the real dispatch path
-            # (PackedDeviceCache._full_ship / params_device): a committed
-            # device array and a host np.ndarray key DIFFERENT jit cache
-            # entries, so a numpy-fed warm would compile a variant the
-            # session never dispatches
-            params = {k2: jax.device_put(v)
-                      for k2, v in dummy_score_params(dims).items()}
-
-            def bufs():
-                f2d, i2d = dummy_packed_buffers(layout, chunk)
-                return jax.device_put(f2d), jax.device_put(i2d)
-
-            r = solve_allocate_packed2d(*bufs(), layout, params, **flags)
-            r.compact.block_until_ready()
-            if self.warm_delta:
-                # the fused dirty-chunk variant donates its buffers: give
-                # it its own set
-                k = PackedDeviceCache.FUSED_SLOTS
-                zero = np.zeros(k, np.int32)
-                res, nf, ni = solve_allocate_delta(
-                    *bufs(), zero, np.zeros((k, chunk), np.float32),
-                    zero, np.zeros((k, chunk), np.int32), layout, params,
-                    **flags)
-                res.compact.block_until_ready()
+            warm_delta_solve(layout, dims, chunk, flags)
             if self.mesh is not None:
                 from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -540,8 +549,12 @@ class BucketPrewarmer:
                 )
                 sflags = {k2: v for k2, v in flags.items()
                           if k2 in PACKED2D_FLAGS}
+                params = {k2: jax.device_put(v)
+                          for k2, v in dummy_score_params(dims).items()}
                 rs = solve_allocate_sharded_packed2d(
-                    *bufs(), layout, params, self.mesh, **sflags)
+                    *(jax.device_put(b)
+                      for b in dummy_packed_buffers(layout, chunk)),
+                    layout, params, self.mesh, **sflags)
                 rs.assigned.block_until_ready()
                 # the sharded ARENA variant too: a sharded session's
                 # bucket crossing dispatches this entry against the

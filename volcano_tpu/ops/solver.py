@@ -57,11 +57,13 @@ class SolveResult(NamedTuple):
     assigned: jnp.ndarray   # [T] int32 node index or -1
     kind: jnp.ndarray       # [T] int32: 0 = allocate, 1 = pipeline, -1 = none
     job_ready: jnp.ndarray  # [J] bool: job committed (gang-satisfied)
-    rounds: jnp.ndarray     # [] int32 diagnostic
+    rounds: jnp.ndarray     # [] int32 diagnostic: rounds of all phases
     compact: jnp.ndarray = None  # [T] int16: node | (kind << 14), -1 = none
                                  # — the wire-cheap readback (decode with
                                  # decode_compact); assigned/kind stay for
                                  # in-kernel consumers and tests
+    cap_hits: jnp.ndarray = None  # [] int32: admission phases stopped by
+                                  # their round budget while still admitting
 
 
 COMPACT_KIND_SHIFT = 14        # node index < 2^14; kind bit above it
@@ -431,6 +433,61 @@ def _waterfall_choice(eligible, node_score, fit_req, avail, npods,
     return target.astype(jnp.int32)
 
 
+def _spill_choice(spill, node_score, fit_req, avail, npods, max_pods,
+                  thr, scalar_mask):
+    """A second waterfall target for the tasks whose first target cannot
+    take them (``spill``): the herd's requests, summed in position order,
+    laid over the nodes' free room in score order, dimension by dimension
+    (and pod slots), so that each task lands on the first node whose
+    room, with that of the nodes before it, covers its running sum.
+
+    The first waterfall counts slots of the herd's mean request, which
+    fits a herd of one shape; a herd of mixed shapes (1- to 8-GPU pods on
+    nodes with 3 to 8 GPUs free) misses its targets, and every miss falls
+    back to the task's personal argmax, the same node for the whole herd:
+    one node filled a round. Prefix admission stays exact: a target this
+    overestimates only waits for the next round."""
+    N = avail.shape[0]
+    order = jnp.argsort(-node_score)                                # [N]
+    room = jnp.maximum(avail[order] + thr[None, :], 0.0)           # [N,R]
+    pods_room = jnp.maximum(max_pods - npods, 0)[order].astype(jnp.float32)
+    cum_room = jnp.cumsum(room, axis=0)                             # [N,R]
+    cum_pods = jnp.cumsum(pods_room)
+    req = fit_req * spill[:, None]
+    # a scalar dimension the task asks <= 10 milli of is not checked
+    req = jnp.where(scalar_mask[None, :] & (req <= 10.0), 0.0, req)
+    need = jnp.cumsum(req, axis=0)                                  # [T,R]
+    need_pods = jnp.cumsum(spill.astype(jnp.float32))
+    k = jnp.searchsorted(cum_pods, need_pods, side="left")
+    for r in range(avail.shape[1]):
+        k_r = jnp.searchsorted(cum_room[:, r], need[:, r], side="left")
+        k = jnp.maximum(k, jnp.where(req[:, r] > 0.0, k_r, 0))
+    return order[jnp.clip(k, 0, N - 1)].astype(jnp.int32)
+
+
+def _gang_slots(a, room, pods_room, thr, scalar_mask):
+    """[J] how many of each job's smallest counting request the free
+    ``room`` [N,R] (and ``pods_room`` [N]) holds, summed over the nodes:
+    with fewer than the tasks a job still needs, its gang cannot reach
+    minAvailable even alone (no predicate is checked, so this may count
+    too many, never too few). The sharded solver sums it over shards."""
+    J = a["job_min"].shape[0]
+    counting = a["task_valid"] & a["task_counts_ready"]
+    req = jax.ops.segment_min(
+        jnp.where(counting[:, None], a["task_init_req"], jnp.inf),
+        a["task_job"], num_segments=J)                              # [J,R]
+    # a dimension the job asks nothing of (or <= 10 milli of a scalar)
+    # does not bind
+    free = (req <= 0.0) | (scalar_mask[None, :] & (req <= 10.0))
+    per = jnp.floor((room[None, :, :] + thr[None, None, :])
+                    / jnp.where(free, 1.0, req)[:, None, :])      # [J,N,R]
+    per = jnp.where(free[:, None, :], jnp.inf, per)
+    slots = jnp.minimum(jnp.min(per, axis=-1),
+                        pods_room[None, :].astype(jnp.float32))
+    return jnp.sum(jnp.clip(slots, 0.0, float(a["task_valid"].shape[0])),
+                   axis=1)
+
+
 def _admission_round(eligible, feas, score, fit_req, acct_req, avail,
                      rank, thr, scalar_mask, npods, max_pods,
                      per_node_cap: int = 0, herd_mode: str = "pack"):
@@ -447,7 +504,14 @@ def _admission_round(eligible, feas, score, fit_req, acct_req, avail,
                                    npods, max_pods, thr, scalar_mask,
                                    herd_mode)
         t_ok = jnp.take_along_axis(feas, target[:, None], axis=1)[:, 0]
-        choice = jnp.where(t_ok, target, personal)
+        miss = ~t_ok & jnp.any(feas, axis=1)
+        spill = jax.lax.cond(
+            jnp.any(miss),
+            lambda: _spill_choice(miss, node_score, fit_req, avail, npods,
+                                  max_pods, thr, scalar_mask),
+            lambda: target)
+        s_ok = jnp.take_along_axis(feas, spill[:, None], axis=1)[:, 0]
+        choice = jnp.where(t_ok, target, jnp.where(s_ok, spill, personal))
     else:
         choice = personal
     has = jnp.take_along_axis(feas, choice[:, None], axis=1)[:, 0]
@@ -491,8 +555,15 @@ def _admission_round_fused(eligible, a, avail, used_now, sig_feas, sig_i8,
                                npods, max_pods, thr, scalar_mask,
                                herd_mode)
     t_ok = feas_point(target)
+    miss = ~t_ok & has_any & eligible
+    spill = jax.lax.cond(
+        jnp.any(miss),
+        lambda: _spill_choice(miss, node_score, fit_req, avail, npods,
+                              max_pods, thr, scalar_mask),
+        lambda: target)
     choice = jnp.where(t_ok, target,
-                       jnp.where(has_any, personal, -1))
+                       jnp.where(feas_point(spill), spill,
+                                 jnp.where(has_any, personal, -1)))
     return _admit_prefix(choice, fit_req, acct_req, avail, rank, thr,
                          scalar_mask, npods, max_pods, 0)
 
@@ -639,24 +710,37 @@ def solve_allocate(arrays: Dict[str, jnp.ndarray],
         jobres0 = jnp.zeros((1, a["node_idle"].shape[1]), jnp.float32)
         drf_rank = drf_cap = None
 
-    def phase_rounds(st, use_future: bool, capped: bool = True, gate=None):
+    def phase_rounds(st, use_future: bool, solo, capped: bool = True,
+                     gate=None):
         """Run admission rounds to fixpoint against idle (allocate) or
         future-idle (pipeline). st: 9-tuple carry (idle, pipe, npods,
-        qalloc, jobres, assigned, kind, excluded, rounds). capped=False is
+        qalloc, jobres, assigned, kind, excluded, rounds); ``rounds`` is
+        the solve's total. Every call has a budget of its own of
+        ``max_rounds`` rounds: with one budget for the whole solve, the
+        parallel phases of a contended solve (partial gangs placed, then
+        reverted) spent it all and left no round for the deferred jobs'
+        solo retries, so every job reverted, turn after turn. Returns
+        (st, hit), hit 1 where the phase stopped at its budget while its
+        last round still admitted. capped=False is
         the work-conserving overflow pass: fair-share deserved caps are
         relaxed (hard capability quotas still bind) so capacity no
         competing queue wants is not stranded. This deliberately improves
         on the reference, whose any-dim overused check
         (proportion.go:245 `!allocated.LessEqual(deserved)`) strands the
-        same capacity — the host path reproduces that faithfully."""
+        same capacity — the host path reproduces that faithfully.
+        ``solo`` [J] marks the deferred job retrying alone this gang
+        iteration: DRF's progressive filling does not cap it, so it can
+        take its whole gang in a round rather than one task of its
+        largest request a round."""
+        solo_t = solo[a["task_job"]]
 
         def cond(s):
-            changed, rounds = s[-1], s[-2]
-            return changed & (rounds < max_rounds)
+            changed, phase_r = s[-1], s[-2]
+            return changed & (phase_r < max_rounds)
 
         def body(s):
             (idle, pipe, npods, qalloc, jobres, assigned, kind, excluded,
-             rounds, _) = s
+             rounds, phase_r, _) = s
             avail = (idle + a["node_extra_future"] - pipe) if use_future else idle
             eligible = (a["task_valid"] & (assigned < 0)
                         & ~excluded[a["task_job"]])
@@ -687,11 +771,12 @@ def solve_allocate(arrays: Dict[str, jnp.ndarray],
                                             thr, scalar_mask) & sig_feas
                         placeable = jnp.any(
                             feas0 & pods_ok_v[None, :], axis=1)
-                    r_rank, eligible = hdrf_rank_cap(
-                        eligible & placeable, jobres)
+                    eligible = eligible & placeable
+                    r_rank, capped_elig = hdrf_rank_cap(eligible, jobres)
                 else:
                     r_rank = drf_rank(jobres)
-                    eligible = drf_cap(eligible, jobres)
+                    capped_elig = drf_cap(eligible, jobres)
+                eligible = capped_elig | (eligible & solo_t)
             else:
                 r_rank = rank
             if use_queue_cap:
@@ -746,7 +831,7 @@ def solve_allocate(arrays: Dict[str, jnp.ndarray],
                 idle = idle - debit
                 npods = npods + pod_inc
             return (idle, pipe, npods, qalloc, jobres, assigned, kind,
-                    excluded, rounds + 1, jnp.any(got))
+                    excluded, rounds + 1, phase_r + 1, jnp.any(got))
 
         # skip the phase outright when no task is still eligible (e.g. the
         # pipeline phase after everything allocated): one [T] reduction
@@ -757,8 +842,10 @@ def solve_allocate(arrays: Dict[str, jnp.ndarray],
                                & ~excluded0[a["task_job"]])
         if gate is not None:
             any_eligible = any_eligible & gate
-        out = jax.lax.while_loop(cond, body, st + (any_eligible,))
-        return out[:-1]
+        out = jax.lax.while_loop(cond, body,
+                                 st + (jnp.int32(0), any_eligible))
+        hit = (out[-1] & (out[-2] >= max_rounds)).astype(jnp.int32)
+        return out[:-2], hit
 
     # job order position for the gang-exclusion tie-break: first valid
     # task's rank (static snapshot order)
@@ -770,20 +857,35 @@ def solve_allocate(arrays: Dict[str, jnp.ndarray],
 
     def gang_body(s):
         (idle, pipe, npods, qalloc, jobres, assigned, kind, excluded,
-         rounds, _, it, revert_count, deferred, processed) = s
+         rounds, cap_hits, _, it, revert_count, deferred, processed) = s
         # deferred-retry queue: jobs that reverted twice in the parallel
         # phases sit out while the best-ranked of them retries ALONE —
         # the batched equivalent of the sequential reference, where the
         # earliest discarded gang gets first claim on capacity later
         # discards free. One deferred job resolves per iteration.
         unproc = deferred & ~processed & ~excluded
+        # a deferred gang the free room cannot hold even alone would
+        # only revert: it is excluded without spending an iteration, so
+        # the retry goes to the best-ranked one that may fit
+        room = jnp.maximum(idle, idle + a["node_extra_future"] - pipe)
+        need = a["job_min"] - a["job_ready_base"]
+        hopeless = jax.lax.cond(
+            jnp.any(unproc),
+            lambda: unproc & (_gang_slots(
+                a, room, a["node_max_pods"] - npods, thr, scalar_mask)
+                < need),
+            lambda: unproc & False)
+        excluded = excluded | hopeless
+        unproc = unproc & ~hopeless
         cur = jnp.argmin(jnp.where(unproc, job_first_rank, BIG_KEY))
         solo = unproc & (jnp.arange(J) == cur)
         barred = deferred & ~solo
         st = (idle, pipe, npods, qalloc, jobres, assigned, kind,
               excluded | barred, rounds)
-        st = phase_rounds(st, use_future=False)
-        st = phase_rounds(st, use_future=True, gate=has_future)
+        st, hit = phase_rounds(st, False, solo)
+        cap_hits = cap_hits + hit
+        st, hit = phase_rounds(st, True, solo, gate=has_future)
+        cap_hits = cap_hits + hit
         if use_queue_cap and work_conserving:
             # work-conserving overflow: leftovers no competing queue could
             # take under its cap go to whoever still wants them — run only
@@ -797,9 +899,11 @@ def solve_allocate(arrays: Dict[str, jnp.ndarray],
             if use_drf_order:
                 # rank-dependent mask: no cheap exactness argument, keep
                 # the phases (their own any-eligible check still applies)
-                st = phase_rounds(st, use_future=False, capped=False)
-                st = phase_rounds(st, use_future=True, capped=False,
-                                  gate=has_future)
+                st, hit = phase_rounds(st, False, solo, capped=False)
+                cap_hits = cap_hits + hit
+                st, hit = phase_rounds(st, True, solo, capped=False,
+                                       gate=has_future)
+                cap_hits = cap_hits + hit
             else:
                 (_i, _p, _n, qalloc_c, _j, assigned_c, _k, excl_c,
                  _r) = st
@@ -810,10 +914,12 @@ def solve_allocate(arrays: Dict[str, jnp.ndarray],
                     rem, task_queue, a["task_req"], qrem_now, thr,
                     scalar_mask, q_perm, q_seg_start, qs_q, qs_req)
                 capped_out = jnp.any(rem & ~elig_capped)
-                st = phase_rounds(st, use_future=False, capped=False,
-                                  gate=capped_out)
-                st = phase_rounds(st, use_future=True, capped=False,
-                                  gate=capped_out & has_future)
+                st, hit = phase_rounds(st, False, solo, capped=False,
+                                       gate=capped_out)
+                cap_hits = cap_hits + hit
+                st, hit = phase_rounds(st, True, solo, capped=False,
+                                       gate=capped_out & has_future)
+                cap_hits = cap_hits + hit
         (idle, pipe, npods, qalloc, jobres, assigned, kind, _masked,
          rounds) = st
 
@@ -868,12 +974,14 @@ def solve_allocate(arrays: Dict[str, jnp.ndarray],
         any_more = jnp.any(revert_job) | jnp.any(
             deferred & ~processed & ~excluded)
         return (idle, pipe, npods, qalloc, jobres, assigned, kind, excluded,
-                rounds, any_more, it + 1, revert_count, deferred, processed)
+                rounds, cap_hits, any_more, it + 1, revert_count, deferred,
+                processed)
 
     init = (a["node_idle"], jnp.zeros_like(a["node_idle"]), a["node_npods"],
             qalloc0, jobres0,
             jnp.full((T,), -1, jnp.int32), jnp.full((T,), -1, jnp.int32),
-            ~a["job_valid"], jnp.int32(0), jnp.bool_(True), jnp.int32(0),
+            ~a["job_valid"], jnp.int32(0), jnp.int32(0), jnp.bool_(True),
+            jnp.int32(0),
             jnp.zeros(J, jnp.int32), jnp.zeros(J, dtype=bool),
             jnp.zeros(J, dtype=bool))
     # bounded gang fixpoint: rerun phases while any job got reverted (its
@@ -882,7 +990,7 @@ def solve_allocate(arrays: Dict[str, jnp.ndarray],
     s = jax.lax.while_loop(
         lambda s: s[-5] & (s[-4] < max_gang_iters), gang_body, init)
 
-    (idle, pipe, npods, _, _, assigned, kind, excluded, rounds,
+    (idle, pipe, npods, _, _, assigned, kind, excluded, rounds, cap_hits,
      _, _, _, _, _) = s
     alloc_counts = jax.ops.segment_sum(
         ((assigned >= 0) & (kind == 0)).astype(jnp.int32) * counts_ready,
@@ -890,7 +998,8 @@ def solve_allocate(arrays: Dict[str, jnp.ndarray],
     job_ready = ((a["job_ready_base"] + alloc_counts) >= a["job_min"]) \
         & a["job_valid"]
     return SolveResult(assigned=assigned, kind=kind, job_ready=job_ready,
-                       rounds=rounds, compact=_compact(assigned, kind, N))
+                       rounds=rounds, compact=_compact(assigned, kind, N),
+                       cap_hits=cap_hits)
 
 
 # ---------------------------------------------------------------------------
@@ -1076,7 +1185,8 @@ def solve_allocate_sequential(arrays: Dict[str, jnp.ndarray],
     job_ready = ((a["job_ready_base"] + alloc_counts) >= a["job_min"]) \
         & a["job_valid"]
     return SolveResult(assigned=assigned, kind=kind, job_ready=job_ready,
-                       rounds=jnp.int32(T), compact=_compact(assigned, kind, N))
+                       rounds=jnp.int32(T), compact=_compact(assigned, kind, N),
+                       cap_hits=jnp.int32(0))
 
 
 # ---------------------------------------------------------------------------

@@ -27,7 +27,8 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.solver import (
-    NEG, BIG_KEY, SolveResult, _queue_cap_mask, _segment_prefix,
+    NEG, BIG_KEY, SolveResult, _gang_slots, _queue_cap_mask,
+    _segment_prefix, _spill_choice,
     drf_state, fits_matrix, le_fits, queue_cap_state, score_matrix,
 )
 
@@ -297,7 +298,36 @@ def solve_allocate_sharded(arrays: Dict[str, jnp.ndarray],
                         axis=1)[:, 0] & mine
                 t_ok = t_ok_loc if D1 else (
                     jax.lax.psum(t_ok_loc.astype(jnp.int32), "n") > 0)
-                choice = jnp.where(t_ok, target, personal)
+                # a second target for the herd's misses, over the
+                # gathered free room (ops/solver.py _spill_choice); a
+                # round without a miss skips it (the mask is replicated,
+                # so every shard takes the same branch)
+                miss = ~t_ok & has_any & eligible
+
+                def spill_target():
+                    room, npods_g, maxp_g = avail, npods, a["node_max_pods"]
+                    if not D1:
+                        room, npods_g, maxp_g = (
+                            jax.lax.all_gather(v, "n", tiled=True)
+                            for v in (room, npods_g, maxp_g))
+                    return _spill_choice(miss, node_score,
+                                         a["task_init_req"], room, npods_g,
+                                         maxp_g, thr, scalar_mask)
+
+                spill = jax.lax.cond(jnp.any(miss), spill_target,
+                                     lambda: target)
+                s_loc = spill - my_base
+                s_mine = (s_loc >= 0) & (s_loc < n_loc)
+                if feas is None:
+                    s_ok_loc = feas_at(eligible, avail, npods, s_loc, s_mine)
+                else:
+                    s_ok_loc = jnp.take_along_axis(
+                        feas, jnp.clip(s_loc, 0, n_loc - 1)[:, None],
+                        axis=1)[:, 0] & s_mine
+                s_ok = s_ok_loc if D1 else (
+                    jax.lax.psum(s_ok_loc.astype(jnp.int32), "n") > 0)
+                choice = jnp.where(t_ok, target,
+                                   jnp.where(s_ok, spill, personal))
             else:
                 choice = personal
             return choice
@@ -339,13 +369,17 @@ def solve_allocate_sharded(arrays: Dict[str, jnp.ndarray],
                 new_assign = jax.lax.pmax(new_assign, "n")        # [T]
             return new_assign, debit, pod_inc
 
-        def phase_rounds(st, use_future, capped=True):
+        def phase_rounds(st, use_future, solo, capped=True):
+            # a round budget per phase, (st, hit) back, and the solo
+            # retry uncapped by DRF, as in ops/solver.py phase_rounds
+            solo_t = solo[a["task_job"]]
+
             def cond(s):
                 return s[-1] & (s[-2] < max_rounds)
 
             def body(s):
                 (idle, pipe, npods, qalloc, jobres, assigned, kind,
-                 excluded, rounds, _) = s
+                 excluded, rounds, phase_r, _) = s
                 avail = (idle + a["node_extra_future"] - pipe) if use_future \
                     else idle
                 eligible = (a["task_valid"] & (assigned < 0)
@@ -380,11 +414,12 @@ def solve_allocate_sharded(arrays: Dict[str, jnp.ndarray],
                             placeable = any_loc if D1 else (
                                 jax.lax.psum(any_loc.astype(jnp.int32),
                                              "n") > 0)
-                        r_rank, eligible = hdrf_rank_cap(
-                            eligible & placeable, jobres)
+                        eligible = eligible & placeable
+                        r_rank, capped_elig = hdrf_rank_cap(eligible, jobres)
                     else:
                         r_rank = drf_rank(jobres)
-                        eligible = drf_cap(eligible, jobres)
+                        capped_elig = drf_cap(eligible, jobres)
+                    eligible = capped_elig | (eligible & solo_t)
                 else:
                     r_rank = rank
                 if use_queue_cap:
@@ -423,10 +458,12 @@ def solve_allocate_sharded(arrays: Dict[str, jnp.ndarray],
                     idle = idle - debit
                     npods = npods + pod_inc
                 return (idle, pipe, npods, qalloc, jobres, assigned, kind,
-                        excluded, rounds + 1, jnp.any(got))
+                        excluded, rounds + 1, phase_r + 1, jnp.any(got))
 
-            out = jax.lax.while_loop(cond, body, st + (jnp.bool_(True),))
-            return out[:-1]
+            out = jax.lax.while_loop(cond, body,
+                                     st + (jnp.int32(0), jnp.bool_(True)))
+            hit = (out[-1] & (out[-2] >= max_rounds)).astype(jnp.int32)
+            return out[:-2], hit
 
         # job order position for the gang-exclusion tie-break (replicated)
         job_first_rank = jnp.full((J,), T, jnp.int32).at[a["task_job"]].min(
@@ -434,22 +471,39 @@ def solve_allocate_sharded(arrays: Dict[str, jnp.ndarray],
 
         def gang_body(s):
             (idle, pipe, npods, qalloc, jobres, assigned, kind, excluded,
-             rounds, _, it, revert_count, deferred, processed) = s
+             rounds, cap_hits, _, it, revert_count, deferred,
+             processed) = s
             # deferred-retry queue, replicated math (see ops/solver.py
             # gang_body): doubly-reverted jobs retry one at a time in rank
             # order while the rest sit out
             unproc = deferred & ~processed & ~excluded
+            # a deferred gang that cannot fit alone is excluded first
+            # (ops/solver.py gang_body); slots summed over the shards
+            room = jnp.maximum(idle, idle + a["node_extra_future"] - pipe)
+
+            def hopeless_jobs():
+                slots = _gang_slots(a, room, a["node_max_pods"] - npods,
+                                    thr, scalar_mask)
+                if not D1:
+                    slots = jax.lax.psum(slots, "n")
+                return unproc & (slots < a["job_min"] - a["job_ready_base"])
+
+            hopeless = jax.lax.cond(jnp.any(unproc), hopeless_jobs,
+                                    lambda: unproc & False)
+            excluded = excluded | hopeless
+            unproc = unproc & ~hopeless
             cur = jnp.argmin(jnp.where(unproc, job_first_rank, BIG_KEY))
             solo = unproc & (jnp.arange(J) == cur)
             barred = deferred & ~solo
             st = (idle, pipe, npods, qalloc, jobres, assigned, kind,
                   excluded | barred, rounds)
-            st = phase_rounds(st, False)
-            st = phase_rounds(st, True)
+            phases = [(False, True), (True, True)]
             if use_queue_cap and work_conserving:
                 # work-conserving overflow (see ops/solver.py phase_rounds)
-                st = phase_rounds(st, False, capped=False)
-                st = phase_rounds(st, True, capped=False)
+                phases += [(False, False), (True, False)]
+            for use_future, capped in phases:
+                st, hit = phase_rounds(st, use_future, solo, capped=capped)
+                cap_hits = cap_hits + hit
             (idle, pipe, npods, qalloc, jobres, assigned, kind, _masked,
              rounds) = st
             alloc_counts = jax.ops.segment_sum(
@@ -495,37 +549,37 @@ def solve_allocate_sharded(arrays: Dict[str, jnp.ndarray],
             any_more = jnp.any(revert_job) | jnp.any(
                 deferred & ~processed & ~excluded)
             return (idle, pipe, npods, qalloc, jobres, assigned, kind,
-                    excluded, rounds, any_more, it + 1,
+                    excluded, rounds, cap_hits, any_more, it + 1,
                     revert_count, deferred, processed)
 
         init = (a["node_idle"], jnp.zeros_like(a["node_idle"]),
                 a["node_npods"], qalloc0, jobres0,
                 jnp.full((T,), -1, jnp.int32),
                 jnp.full((T,), -1, jnp.int32), ~a["job_valid"],
-                jnp.int32(0), jnp.bool_(True), jnp.int32(0),
+                jnp.int32(0), jnp.int32(0), jnp.bool_(True), jnp.int32(0),
                 jnp.zeros(J, jnp.int32), jnp.zeros(J, dtype=bool),
                 jnp.zeros(J, dtype=bool))
         s = jax.lax.while_loop(
             lambda s: s[-5] & (s[-4] < max_gang_iters), gang_body, init)
         (idle, pipe, npods, _, _, assigned, kind, excluded, rounds,
-         _, _, _, _, _) = s
+         cap_hits, _, _, _, _, _) = s
         alloc_counts = jax.ops.segment_sum(
             ((assigned >= 0) & (kind == 0)).astype(jnp.int32) * counts_ready,
             a["task_job"], num_segments=J)
         job_ready = ((a["job_ready_base"] + alloc_counts) >= a["job_min"]) \
             & a["job_valid"]
-        return assigned, kind, job_ready, rounds
+        return assigned, kind, job_ready, rounds, cap_hits
 
     mapped = shard_map(
         kernel, mesh=mesh,
         in_specs=(in_specs, params_spec),
-        out_specs=(P(), P(), P(), P()))
+        out_specs=(P(), P(), P(), P(), P()))
     # device_dict may carry extra arrays (queue fairness) this kernel
     # doesn't consume; keep the pytree congruent with in_specs
-    assigned, kind, job_ready, rounds = mapped(
+    assigned, kind, job_ready, rounds, cap_hits = mapped(
         {k: a[k] for k in in_specs}, dict(score_params))
     return SolveResult(assigned=assigned, kind=kind, job_ready=job_ready,
-                       rounds=rounds)
+                       rounds=rounds, cap_hits=cap_hits)
 
 
 @functools.partial(jax.jit, static_argnames=(
